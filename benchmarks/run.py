@@ -16,6 +16,10 @@ per-module wall time and status, whatever dict payload each module's
 module (``repro.kernels.profiling.launch_registry`` — trace-time records,
 so a module only shows the launches whose geometry it traced first).
 
+JAX's persistent compilation cache is on for the run: in
+``JAX_COMPILATION_CACHE_DIR`` when that is set, else ``<repo>/.jax_cache``
+(``repro.launch.compile_cache``).
+
 Set ``REPRO_BENCH_TINY=1`` to shrink problem sizes in the modules that
 support it (CI smoke: exercises the harness without paper-scale runs).
 """
@@ -72,6 +76,9 @@ def _jsonable(o):
 
 def main(argv=None) -> None:
     argv = sys.argv[1:] if argv is None else argv
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     json_path = None
     if "--json" in argv:
         i = argv.index("--json")

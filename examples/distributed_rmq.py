@@ -3,16 +3,18 @@ query batches with per-segment hierarchies + a min all-reduce.
 
     PYTHONPATH=src python examples/distributed_rmq.py
 
-On this CPU container the mesh uses 8 fake devices (set before jax
-import); on a real pod the same code runs on the production mesh from
-repro.launch.mesh.  This is the piece that removes the paper's single-GPU
+The mesh has one segment per device that exists: the chips of a TPU
+host, or — under ``JAX_PLATFORMS=cpu`` — 8 host devices forced before
+jax is imported.  This is the piece that removes the paper's single-GPU
 memory ceiling: capacity scales linearly in devices, communication per
 batch is one all-reduce(min) of (batch,) floats — independent of n.
 """
 
 import os
 
-os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+    os.environ.setdefault(
+        "XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 
 import numpy as np
 import jax
@@ -22,11 +24,10 @@ from repro.core.distributed import DistributedRMQ
 
 
 def main():
-    from repro.launch.mesh import make_test_mesh
-
-    mesh = make_test_mesh((2, 4), ("data", "model"))
+    segments = len(jax.devices())
+    mesh = jax.make_mesh((1, segments), ("data", "model"))
     rng = np.random.default_rng(0)
-    n = 1 << 22  # 4M elements across 4 segments
+    n = 1 << 22  # 4M elements, one segment per device
     x = rng.random(n, dtype=np.float32)
 
     d = DistributedRMQ.build(x, mesh, segment_axis="model",

@@ -64,9 +64,9 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import protocol as px
 from repro.core.hierarchy import pos_dtype_for
 from repro.core.plan import HierarchyPlan, make_plan

@@ -2,18 +2,25 @@
 
 Paper §4.1/§5.6: "a group of g adjacent threads reduces a chunk of c
 adjacent entries via warp reductions to a single summary".  The TPU
-realization tiles the level through VMEM: each program DMAs a
-``(TILE_OUT * c,)`` contiguous slice HBM→VMEM, reshapes it to
-``(TILE_OUT, c)`` (sublane × lane when c is a multiple of 128), and
-reduces along the chunk axis on the VPU — ``TILE_OUT`` chunk reductions
-per program instead of the GPU's one-warp-per-chunk.
+realization tiles the level through VMEM: each program DMAs a block of
+``rows_out * c`` chunks HBM→VMEM and reduces every chunk on the VPU —
+``rows_out * c`` chunk reductions per program instead of the GPU's
+one-warp-per-chunk.
 
-Layout notes:
-* ``c`` ≥ 128 keeps the reduction axis on lanes; the reshape is free
-  because the slice is contiguous.
-* ``TILE_OUT * c * 4`` bytes is the VMEM working set per program
-  (default 512 * 128 * 4 = 256 KiB, well under the ~16 MiB budget, big
-  enough to amortize DMA setup).
+Layout: a level arrives as ``(chunks, c)``, one chunk per row, and its
+chunk minima leave lane-dense as ``(chunks / c, c)``.  Each ``(c, c)``
+slab of rows is transposed on the XLU so that a sublane min puts chunk
+``j``'s minimum in lane ``j`` — the TPU tiles 1-D arrays in runs of 1024
+entries, so neither a ``(tile_out * c,)`` input slice nor a ``(tile_out,)``
+output block of a 1-D level compiles.  A grid step covers ``rows_out``
+output rows: 8 (one f32 sublane tile) or the whole level when it is
+smaller.
+
+Positions use the ``min(pos where value == min)`` form rather than a
+gather at the argmin (Mosaic has no in-kernel gather).  Carried positions
+increase strictly across a chunk's non-padding entries and padding holds
+``PAD_POS``, so the two forms agree bit-exactly with the leftmost-argmin
+oracle (the argument of ``kernels/hierarchy_fused``).
 """
 
 from __future__ import annotations
@@ -24,77 +31,94 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_TILE_OUT = 512
+from repro.core.constants import PAD_POS
+
+# Output rows (of c minima each) per grid step: one f32 sublane tile.
+DEFAULT_ROWS_OUT = 8
 
 
-def _min_kernel(x_ref, o_ref, *, c: int, tile_out: int):
-    x = x_ref[...].reshape(tile_out, c)
-    o_ref[...] = jnp.min(x, axis=1)
+def slab_min(xt, pt=None):
+    """Column minima of a transposed ``(c, c)`` slab (chunk ``j`` in
+    column ``j``) as ``(1, c)`` rows: values, and the leftmost positions
+    when the slab's position plane ``pt`` is given."""
+    m = jnp.min(xt, axis=0, keepdims=True)
+    if pt is None:
+        return m, None
+    pad = jnp.array(PAD_POS, pt.dtype)
+    return m, jnp.min(jnp.where(xt == m, pt, pad), axis=0, keepdims=True)
 
 
-def _argmin_kernel(x_ref, p_ref, o_ref, po_ref, *, c: int, tile_out: int):
-    x = x_ref[...].reshape(tile_out, c)
-    p = p_ref[...].reshape(tile_out, c)
-    idx = jnp.argmin(x, axis=1)  # first occurrence == leftmost tie-break
-    o_ref[...] = jnp.take_along_axis(x, idx[:, None], axis=1)[:, 0]
-    po_ref[...] = jnp.take_along_axis(p, idx[:, None], axis=1)[:, 0]
+def _min_kernel(x_ref, o_ref, *, c: int, rows_out: int):
+    for r in range(rows_out):
+        xt = x_ref[pl.ds(r * c, c), :].T           # chunk j -> column j
+        o_ref[pl.ds(r, 1), :] = slab_min(xt)[0]
+
+
+def _argmin_kernel(x_ref, p_ref, o_ref, po_ref, *, c: int, rows_out: int):
+    for r in range(rows_out):
+        m, pm = slab_min(x_ref[pl.ds(r * c, c), :].T,
+                         p_ref[pl.ds(r * c, c), :].T)
+        o_ref[pl.ds(r, 1), :] = m
+        po_ref[pl.ds(r, 1), :] = pm
+
+
+def _specs(chunks: int, c: int, rows_out: int):
+    """Grid and blocks: ``rows_out * c`` input rows -> ``rows_out`` rows."""
+    assert chunks % (rows_out * c) == 0, (chunks, rows_out, c)
+    grid = (chunks // (rows_out * c),)
+    in_spec = pl.BlockSpec((rows_out * c, c), lambda i: (i, 0))
+    out_spec = pl.BlockSpec((rows_out, c), lambda i: (i, 0))
+    return grid, in_spec, out_spec
 
 
 @functools.partial(
-    jax.jit, static_argnames=("c", "tile_out", "interpret")
+    jax.jit, static_argnames=("c", "rows_out", "interpret")
 )
 def build_level(
     values: jax.Array,
     c: int,
-    tile_out: int = DEFAULT_TILE_OUT,
+    rows_out: int = DEFAULT_ROWS_OUT,
     interpret: bool = False,
 ) -> jax.Array:
-    """Reduce a (padded) level to its chunk minima: ``(m*c,) -> (m,)``.
+    """Chunk minima of a level: ``(chunks, c) -> (chunks / c, c)``.
 
-    ``values`` must already be padded to a multiple of ``tile_out * c``
-    by the caller (ops.py handles padding with +inf).
+    ``chunks`` must be a multiple of ``rows_out * c`` (ops.py pads the
+    level with +inf); ``rows_out`` must be a multiple of 8 or the whole
+    output on TPU.
     """
-    total = values.shape[0]
-    assert total % (tile_out * c) == 0, (total, tile_out, c)
-    grid = (total // (tile_out * c),)
+    chunks = values.shape[0]
+    grid, in_spec, out_spec = _specs(chunks, c, rows_out)
     return pl.pallas_call(
-        functools.partial(_min_kernel, c=c, tile_out=tile_out),
+        functools.partial(_min_kernel, c=c, rows_out=rows_out),
         grid=grid,
-        in_specs=[pl.BlockSpec((tile_out * c,), lambda i: (i,))],
-        out_specs=pl.BlockSpec((tile_out,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((total // c,), values.dtype),
+        in_specs=[in_spec],
+        out_specs=out_spec,
+        out_shape=jax.ShapeDtypeStruct((chunks // c, c), values.dtype),
         interpret=interpret,
     )(values)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("c", "tile_out", "interpret")
+    jax.jit, static_argnames=("c", "rows_out", "interpret")
 )
 def build_level_with_positions(
     values: jax.Array,
     positions: jax.Array,
     c: int,
-    tile_out: int = DEFAULT_TILE_OUT,
+    rows_out: int = DEFAULT_ROWS_OUT,
     interpret: bool = False,
 ):
     """Chunk-min with carried original-array positions (for RMQ_index)."""
-    total = values.shape[0]
-    assert total % (tile_out * c) == 0, (total, tile_out, c)
-    grid = (total // (tile_out * c),)
+    chunks = values.shape[0]
+    grid, in_spec, out_spec = _specs(chunks, c, rows_out)
     return pl.pallas_call(
-        functools.partial(_argmin_kernel, c=c, tile_out=tile_out),
+        functools.partial(_argmin_kernel, c=c, rows_out=rows_out),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((tile_out * c,), lambda i: (i,)),
-            pl.BlockSpec((tile_out * c,), lambda i: (i,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((tile_out,), lambda i: (i,)),
-            pl.BlockSpec((tile_out,), lambda i: (i,)),
-        ],
+        in_specs=[in_spec, in_spec],
+        out_specs=[out_spec, out_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((total // c,), values.dtype),
-            jax.ShapeDtypeStruct((total // c,), positions.dtype),
+            jax.ShapeDtypeStruct((chunks // c, c), values.dtype),
+            jax.ShapeDtypeStruct((chunks // c, c), positions.dtype),
         ],
         interpret=interpret,
     )(values, positions)

@@ -22,21 +22,14 @@ import jax.numpy as jnp
 from repro.core.constants import PAD_POS as _PAD_POS
 from repro.core.hierarchy import Hierarchy, _pad_to, pos_dtype_for
 from repro.core.plan import HierarchyPlan
-from repro.kernels import profiling
+from repro.kernels import common, profiling
 from repro.kernels.hierarchy_build import kernel as K
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def _pick_tile_out(padded_len: int, c: int) -> int:
-    """Largest power-of-two tile (<= default) dividing the level."""
-    m = padded_len // c
-    tile = K.DEFAULT_TILE_OUT
-    while tile > 1 and m % tile != 0:
-        tile //= 2
-    return tile
+def _rows_out(out_len: int, c: int) -> int:
+    """Output rows per grid step: one sublane tile, or the whole level."""
+    rows = -(-out_len // c)
+    return rows if rows <= K.DEFAULT_ROWS_OUT else K.DEFAULT_ROWS_OUT
 
 
 @functools.partial(
@@ -54,30 +47,34 @@ def _build_jit(x, plan, with_positions, interpret):
     cur_p = jnp.arange(cap, dtype=pos_dtype) if with_positions else None
 
     for k in range(1, plan.num_levels):
-        # consume ceil(len/c)*c entries, then tile-align for the kernel
-        want = plan.level_lens[k] * c
-        tile = _pick_tile_out(want, c)
-        want_aligned = -(-want // (tile * c)) * (tile * c)
-        v_in = _pad_to(cur_v, want_aligned, inf)
+        # consume ceil(len/c)*c entries, then tile-align for the kernel:
+        # the level goes in as (chunks, c) and comes out as (chunks/c, c)
+        out_len = plan.level_lens[k]
+        rows_out = _rows_out(out_len, c)
+        step = rows_out * c * c
+        want_aligned = -(-out_len * c // step) * step
+        v_in = _pad_to(cur_v, want_aligned, inf).reshape(-1, c)
         profiling.record_launch(
             "hierarchy_build",
             lowering="pallas",
             level=k,
-            grid=int(want_aligned // (tile * c)),
+            grid=int(want_aligned // step),
             with_positions=bool(with_positions),
             operand_bytes=profiling.operand_bytes(v_in),
         )
         if with_positions:
-            p_in = _pad_to(cur_p, want_aligned, jnp.array(_PAD_POS, pos_dtype))
+            p_in = _pad_to(
+                cur_p, want_aligned, jnp.array(_PAD_POS, pos_dtype)
+            ).reshape(-1, c)
             nxt_v, nxt_p = K.build_level_with_positions(
-                v_in, p_in, c=c, tile_out=tile, interpret=interpret
+                v_in, p_in, c=c, rows_out=rows_out, interpret=interpret
             )
-            nxt_v = nxt_v[: plan.level_lens[k]]
-            nxt_p = nxt_p[: plan.level_lens[k]]
+            nxt_v = nxt_v.reshape(-1)[:out_len]
+            nxt_p = nxt_p.reshape(-1)[:out_len]
         else:
             nxt_v = K.build_level(
-                v_in, c=c, tile_out=tile, interpret=interpret
-            )[: plan.level_lens[k]]
+                v_in, c=c, rows_out=rows_out, interpret=interpret
+            ).reshape(-1)[:out_len]
             nxt_p = None
 
         padded_len = plan.padded_lens[k - 1]
@@ -107,6 +104,7 @@ def build_hierarchy_pallas(
     interpret: bool | None = None,
 ) -> Hierarchy:
     """Level-by-level Pallas build (paper §4.1, bottom-up)."""
-    if interpret is None:
-        interpret = not _on_tpu()
-    return _build_jit(jnp.asarray(x), plan, with_positions, interpret)
+    return _build_jit(
+        jnp.asarray(x), plan, with_positions,
+        common.resolve_interpret(interpret),
+    )

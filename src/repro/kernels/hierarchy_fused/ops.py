@@ -9,6 +9,9 @@ assembly compile into one XLA program, so nothing bounces through the
 host between levels.
 
 Falls back to interpret mode off-TPU, like every kernel package here.
+The resident upper buffer caps the geometries the compiled kernel admits
+(:func:`vmem_bytes` against :data:`VMEM_BUDGET_BYTES`); past it the
+build refuses with a ``ValueError``.
 """
 
 from __future__ import annotations
@@ -27,65 +30,78 @@ from repro.core.hierarchy import (
     pos_dtype_for,
 )
 from repro.core.plan import HierarchyPlan, make_plan
-from repro.kernels import profiling
+from repro.kernels import common, profiling
 from repro.kernels.hierarchy_fused import kernel as K
 
 __all__ = [
     "build_hierarchy_fused",
     "build_hierarchy_streamed",
-    "FUSED_VMEM_BUDGET_BYTES",
+    "VMEM_BUDGET_BYTES",
+    "vmem_bytes",
 ]
 
-# The upper buffer lives wholly in VMEM for the launch (~16 MiB/core on
-# current TPUs); leave headroom for the double-buffered input tile.  With
-# c=128 this admits capacities up to ~250M elements (half that with
-# positions) — past it, use the per-level 'pallas' backend.
-FUSED_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
+def _geometry(plan: HierarchyPlan):
+    """``(rows_out, chunks, slack)`` of the fused launch.
+
+    Level 0 goes in as ``chunks`` rows of ``c`` (the level-1 extent,
+    rounded up to whole grid steps); the output buffer carries ``slack``
+    rows past the upper buffer for the grid's all-inf overhang and the
+    folds' last partial slabs.
+    """
+    c = plan.c
+    rows1 = plan.padded_lens[0] // c
+    rows_out = min(rows1, K.DEFAULT_ROWS_OUT)
+    chunks = -(-plan.padded_lens[0] // (rows_out * c)) * (rows_out * c)
+    slack = max(c, chunks // c - plan.upper_size // c)
+    return rows_out, chunks, slack
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+# Largest VMEM footprint (resident upper planes + the double-buffered
+# level-0 tile) the v5e compiler accepts for the fused build, measured by
+# compiling for a described chip: 56.25 MiB value-only, 75 MiB with the
+# position plane.  At c=128 every capacity the int32 guard admits fits.
+VMEM_BUDGET_BYTES = {False: 225 * 2**18, True: 75 * 2**20}
 
 
-def _pick_tile_out(padded_level1: int) -> int:
-    """Largest power-of-two tile (<= default) dividing level 1's extent."""
-    tile = K.DEFAULT_TILE_OUT
-    while tile > 1 and padded_level1 % tile != 0:
-        tile //= 2
-    return tile
+def vmem_bytes(plan: HierarchyPlan, with_positions: bool,
+               itemsize: int = 4) -> int:
+    """VMEM the fused launch allocates: the resident upper buffer (and
+    its position plane) plus the double-buffered level-0 tile."""
+    rows_out, _, slack = _geometry(plan)
+    plane = common.tiled_bytes(
+        plan.upper_size // plan.c + slack, plan.c, itemsize)
+    tile = common.tiled_bytes(rows_out * plan.c, plan.c, itemsize)
+    return (2 if with_positions else 1) * plane + 2 * tile
 
 
 @functools.partial(
-    jax.jit, static_argnames=("plan", "with_positions", "tile_out",
-                              "interpret")
+    jax.jit, static_argnames=("plan", "with_positions", "interpret")
 )
-def _fused_jit(x, plan, with_positions, tile_out, interpret):
+def _fused_jit(x, plan, with_positions, interpret):
     c = plan.c
     inf = jnp.array(jnp.inf, x.dtype)
     base = _pad_to(x, plan.capacity, inf)
+    rows_out, chunks, slack = _geometry(plan)
     # Tile-align level 0 for the kernel's block DMA; the over-pad is
-    # < c * tile_out entries and the all-inf chunks it adds reduce to the
-    # same +inf / PAD_POS padding the oracle stores.
-    xin = _pad_to(base, plan.padded_lens[0] * c, inf)
-    offs = jnp.asarray(plan.offsets, jnp.int32)
+    # all-inf chunks, which reduce to the same +inf / PAD_POS padding the
+    # oracle stores.
+    xin = _pad_to(base, chunks * c, inf).reshape(chunks, c)
     profiling.record_launch(
         "hierarchy_fused",
         lowering="pallas",
         levels=plan.num_levels,
-        grid=int(plan.padded_lens[0] // tile_out),
+        grid=int(chunks // (rows_out * c)),
         with_positions=bool(with_positions),
-        operand_bytes=profiling.operand_bytes(xin, offs),
+        operand_bytes=profiling.operand_bytes(xin),
     )
+    upper, upper_pos = K.fused_build(
+        xin, plan, rows_out, slack,
+        pos_dtype=pos_dtype_for(plan.capacity) if with_positions else None,
+        interpret=interpret,
+    )
+    upper = upper.reshape(-1)[: plan.upper_size]
     if with_positions:
-        upper, upper_pos = K.fused_build_with_positions(
-            xin, offs, plan, pos_dtype_for(plan.capacity),
-            tile_out=tile_out, interpret=interpret,
-        )
-    else:
-        upper = K.fused_build(
-            xin, offs, plan, tile_out=tile_out, interpret=interpret
-        )
-        upper_pos = None
+        upper_pos = upper_pos.reshape(-1)[: plan.upper_size]
     return Hierarchy(base=base, upper=upper, upper_pos=upper_pos, plan=plan)
 
 
@@ -110,8 +126,7 @@ def build_hierarchy_fused(
     """Single-launch fused build (paper §4.1, all levels in one pass)."""
     from repro.core.protocol import check_capacity_limit
 
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = common.resolve_interpret(interpret)
     _check_compact_build(plan, with_positions, jnp.asarray(x).dtype)
     if plan.num_levels == 1:
         return finalize_compact(_single_level_jit(x, plan, with_positions))
@@ -121,23 +136,15 @@ def build_hierarchy_fused(
         # larger arrays through backend='jax' or the streamed build.
         check_capacity_limit(plan.padded_lens[0] * plan.c)
     x = jnp.asarray(x)
-    tile_out = _pick_tile_out(plan.padded_lens[0])
     if not interpret:
-        itemsize = jnp.dtype(x.dtype).itemsize
-        vmem = plan.upper_size * itemsize
-        if with_positions:
-            vmem += plan.upper_size * jnp.dtype(
-                pos_dtype_for(plan.capacity)
-            ).itemsize
-        vmem += 2 * tile_out * plan.c * itemsize  # double-buffered input
-        if vmem > FUSED_VMEM_BUDGET_BYTES:
-            raise ValueError(
-                f"fused build needs ~{vmem} bytes of VMEM for this plan "
-                f"(budget {FUSED_VMEM_BUDGET_BYTES}); use the per-level "
-                "backend='pallas' for this geometry"
-            )
+        common.check_vmem(
+            "the fused build",
+            vmem_bytes(plan, with_positions, jnp.dtype(x.dtype).itemsize),
+            VMEM_BUDGET_BYTES[bool(with_positions)],
+            remedy="use the per-level backend='pallas' for this geometry",
+        )
     return finalize_compact(
-        _fused_jit(x, plan, with_positions, tile_out, interpret)
+        _fused_jit(x, plan, with_positions, interpret)
     )
 
 

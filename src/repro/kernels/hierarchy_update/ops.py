@@ -15,15 +15,11 @@ import jax.numpy as jnp
 
 from repro.core.hierarchy import Hierarchy, _pad_to, pos_dtype_for
 from repro.core.plan import HierarchyPlan
-from repro.kernels import profiling
+from repro.kernels import common, profiling
 from repro.kernels.hierarchy_update import kernel as K
 from repro.streaming.updates import scatter_base, touched_chunk_ids
 
 __all__ = ["update_hierarchy_pallas", "append_hierarchy_pallas"]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _propagate_pallas(
@@ -140,9 +136,7 @@ def update_hierarchy_pallas(
         from repro.streaming import updates as U
 
         return U.update_hierarchy(h, idxs, vals)
-    if interpret is None:
-        interpret = not _on_tpu()
-    return _update_jit(h, idxs, vals, interpret)
+    return _update_jit(h, idxs, vals, common.resolve_interpret(interpret))
 
 
 def append_hierarchy_pallas(
@@ -159,6 +153,6 @@ def append_hierarchy_pallas(
         from repro.streaming import updates as U
 
         return U.append_hierarchy(h, vals, start)
-    if interpret is None:
-        interpret = not _on_tpu()
-    return _append_jit(h, vals, start, interpret)
+    return _append_jit(
+        h, vals, start, common.resolve_interpret(interpret)
+    )

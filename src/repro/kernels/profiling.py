@@ -38,8 +38,7 @@ Two richer layers stack on the same recording sites without changing the
 FLOP/byte *estimates* from the compiler are a property of a compiled
 artifact, not of a traced body, so they attach separately:
 :meth:`LaunchRegistry.attach_cost` accepts any object with an AOT
-``cost_analysis`` (normalized via ``repro.compat.cost_analysis_dict``)
-and files the estimate under the kernel name.
+``cost_analysis`` and files the estimate under the kernel name.
 """
 
 from __future__ import annotations
@@ -125,13 +124,10 @@ class LaunchRegistry:
         """File the compiler's FLOP/byte estimate for ``name``.
 
         ``compiled`` is anything exposing AOT ``cost_analysis()`` (a
-        ``jax.stages.Compiled``); the result is normalized through
-        ``repro.compat.cost_analysis_dict`` and reduced to the scalar
+        ``jax.stages.Compiled``); the result is reduced to the scalar
         entries (``flops``, ``bytes accessed``, ...).
         """
-        from repro.compat import cost_analysis_dict
-
-        raw = cost_analysis_dict(compiled) or {}
+        raw = compiled.cost_analysis() or {}
         cost = {k: float(v) for k, v in raw.items()
                 if isinstance(v, (int, float))}
         with self._lock:

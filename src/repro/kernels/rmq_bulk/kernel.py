@@ -9,7 +9,7 @@ kernel is the fused kernel with the level-0 traffic made *conditional*:
 
 * **chunk-reuse DMA.**  The query loop carries the previous query's
   aligned window anchors; a boundary chunk is copied HBM→VMEM only when
-  its anchor *changes* (``pl.when(a_start != prev_a)``).  On a batch
+  its anchor row *changes* (``pl.when(a_row != prev_a)``).  On a batch
   sorted by ``(chunk(l), chunk(r))`` — the ``BulkExecutor`` contract —
   runs of queries sharing a chunk pay ONE copy for the run, so level-0
   bytes scale with the number of *distinct* chunks touched, not with the
@@ -58,14 +58,14 @@ def _rmq_bulk_kernel(
     # inputs
     l_ref,          # SMEM (qb,) i32 — sorted by (chunk(l), chunk(r))
     r_ref,          # SMEM (qb,) i32
-    base_hbm,       # ANY  (capacity,) level 0, stays in HBM
+    base_hbm,       # ANY  (rows0, c) level 0, one chunk per row, in HBM
     upper_ref,      # VMEM (rows, c): all upper levels, one chunk per row
     upper_pos_ref,  # VMEM (rows, c) i32 or None (closure decides)
     # outputs
     out_ref,        # SMEM (qb,) values
     out_pos_ref,    # SMEM (qb,) i32 or None
     # scratch
-    win_ref,        # VMEM (2, c) resident boundary windows [side][c]
+    win_ref,        # VMEM (2, 1, c) resident boundary windows [side]
     sems,           # DMA semaphores (2,)
     *,
     plan: HierarchyPlan,
@@ -73,57 +73,54 @@ def _rmq_bulk_kernel(
     track_pos: bool,
 ):
     c = plan.c
-    n = plan.capacity
+    rows0 = -(-plan.capacity // c)  # level-0 rows (+inf tail past capacity)
     num_levels = plan.num_levels
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
 
-    def copy(start, side):
+    def copy(row, side):
         return pltpu.make_async_copy(
-            base_hbm.at[pl.ds(start, c)], win_ref.at[side],
-            sems.at[side],
+            base_hbm.at[pl.ds(row, 1)], win_ref.at[side], sems.at[side],
         )
 
     def body(i, carry):
         prev_a, prev_b = carry
         l = l_ref[i]
         r = r_ref[i] + 1  # exclusive
-        # same anchor formulas as the fused kernel (exclusive-r b_start),
-        # so sorted runs sharing a chunk pair produce identical anchors
-        a_start = jnp.clip((l // c) * c, 0, max(n - c, 0))
-        b_start = jnp.clip((r // c) * c, 0, max(n - c, 0))
+        # same anchor rows as the fused kernel (exclusive-r b_row), so
+        # sorted runs sharing a chunk pair produce identical anchors
+        a_row = jnp.clip(l // c, 0, rows0 - 1)
+        b_row = jnp.clip(r // c, 0, rows0 - 1)
 
         # level-0 chunk reuse: only a changed anchor moves any bytes.
         # The copies are synchronous (start+wait inside the guard) — a
         # single-slot window cannot overlap copy with the previous
         # query's reads, and on a sorted batch most iterations skip the
         # copy entirely, which is the win being harvested.
-        @pl.when(a_start != prev_a)
+        @pl.when(a_row != prev_a)
         def _load_a():
-            cp = copy(a_start, 0)
+            cp = copy(a_row, 0)
             cp.start()
             cp.wait()
 
-        @pl.when(b_start != prev_b)
+        @pl.when(b_row != prev_b)
         def _load_b():
-            cp = copy(b_start, 1)
+            cp = copy(b_row, 1)
             cp.start()
             cp.wait()
 
         # ---- level 0: prefix / suffix scans over the resident windows ---
         next_l = ((l + c - 1) // c) * c
         prev_r = (r // c) * c
-        idx_a = a_start + lane
-        idx_b = b_start + lane
+        idx_a = a_row * c + lane
+        idx_b = b_row * c + lane
         pos_a = idx_a if track_pos else None
         pos_b = idx_b if track_pos else None
         m, p = _masked_min_2d(
-            win_ref[0].reshape(1, c), idx_a, l,
-            jnp.minimum(next_l, r), pos_a,
+            win_ref[0], idx_a, l, jnp.minimum(next_l, r), pos_a,
         )
         m2, p2 = _masked_min_2d(
-            win_ref[1].reshape(1, c), idx_b,
-            jnp.maximum(prev_r, l), r, pos_b,
+            win_ref[1], idx_b, jnp.maximum(prev_r, l), r, pos_b,
         )
         m, p = _merge(m, p, m2, p2)
 
@@ -178,7 +175,7 @@ def _rmq_bulk_kernel(
         out_ref[i] = m
         if track_pos:
             out_pos_ref[i] = p
-        return a_start, b_start
+        return a_row, b_row
 
     # anchors start at -1 so iteration 0 always copies both windows
     jax.lax.fori_loop(
@@ -253,7 +250,7 @@ def rmq_bulk_pallas(
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((2, c), base.dtype),   # [side][c] resident windows
+            pltpu.VMEM((2, 1, c), base.dtype),  # [side] resident rows
             pltpu.SemaphoreType.DMA((2,)),
         ],
     )

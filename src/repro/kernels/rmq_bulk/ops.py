@@ -42,7 +42,7 @@ from repro.core.baselines import SparseTable
 from repro.core.constants import POS_INF_I32 as _POS_INF_I32
 from repro.core.hierarchy import Hierarchy
 from repro.core.plan import HierarchyPlan
-from repro.kernels import profiling
+from repro.kernels import common, profiling
 from repro.kernels.rmq_bulk import kernel as K
 from repro.kernels.rmq_scan.ref import _merge, _window
 
@@ -51,10 +51,6 @@ __all__ = [
     "rmq_bulk_value_batch",
     "rmq_bulk_index_batch",
 ]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _kernel_applicable(plan: HierarchyPlan) -> bool:
@@ -221,7 +217,7 @@ def _bulk_jnp(base, upper, upper_pos, ls, rs, plan, track_pos):
 def _run_kernel(base, upper, upper_pos, ls, rs, plan, qb, track_pos,
                 interpret):
     m = ls.shape[0]
-    m_pad = -(-m // qb) * qb
+    qb, m_pad = common.query_grid(m, qb, interpret)
     profiling.record_launch(
         "rmq_bulk",
         lowering="pallas",
@@ -241,7 +237,7 @@ def _run_kernel(base, upper, upper_pos, ls, rs, plan, qb, track_pos,
     upos2d = upper_pos.reshape(-1, plan.c) if track_pos else None
     offs = jnp.asarray(plan.offsets, jnp.int32)
     vals, pos = K.rmq_bulk_pallas(
-        base,
+        common.chunk_rows(base, plan.c),
         upper2d,
         upos2d,
         offs,
@@ -288,10 +284,12 @@ def rmq_bulk_batch(
         )
     plan = h.plan
     use_kernel = _kernel_applicable(plan) and (
-        _on_tpu() if interpret is None else bool(interpret) or _on_tpu()
+        bool(interpret) or common.on_tpu()
     )
     if use_kernel:
         itp = False if interpret is None else bool(interpret)
+        if not itp:
+            common.check_query_vmem(plan, track_pos, h.upper.dtype.itemsize)
         return _run_kernel(
             h.base, h.upper, h.upper_pos if track_pos else None,
             ls, rs, plan, qb, track_pos, itp,

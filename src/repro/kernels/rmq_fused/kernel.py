@@ -15,7 +15,7 @@ launch for the entire batch, with no host-side span-class split:
   engine's short/mid/long classification becomes unnecessary: one kernel
   serves the whole mix.
 * **level offsets via scalar prefetch.**  The ``plan.offsets`` table
-  (the same table ``hierarchy_fused`` consumes) arrives as a scalar-
+  (the layout the construction kernels write) arrives as a scalar-
   prefetch operand (``pltpu.PrefetchScalarGridSpec``): each level's slot
   in the contiguous ``upper`` buffer is indexed *dynamically* while every
   slice size stays static from the plan — the construction and query
@@ -26,8 +26,9 @@ launch for the entire batch, with no host-side span-class split:
   the host selects the requested plane per query.
 * **query-tile staging + double-buffered boundary DMA.**  As in
   ``rmq_scan``: bounds arrive in SMEM per tile, level-0 boundary chunks
-  are DMA'd HBM→VMEM with a two-slot pipeline, the upper buffer is
-  VMEM-resident for the whole launch.
+  (one row each of the ``(rows, c)`` level 0) are DMA'd HBM→VMEM with a
+  two-slot pipeline, the upper buffer is VMEM-resident for the whole
+  launch.
 
 Tie-breaking: the ``min(pos where value == min)`` form everywhere, which
 is bit-identical to the leftmost-argmin oracle (same argument as the
@@ -47,8 +48,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.constants import POS_INF_I32 as _POS_INF_I32
 from repro.core.plan import HierarchyPlan
+from repro.kernels.common import DEFAULT_QUERY_BLOCK
 
-DEFAULT_QUERY_BLOCK = 256
 
 
 def _masked_min_2d(vals, idx, lo, hi, pos=None):
@@ -74,14 +75,14 @@ def _rmq_fused_kernel(
     # inputs
     l_ref,          # SMEM (qb,) i32
     r_ref,          # SMEM (qb,) i32
-    base_hbm,       # ANY  (capacity,) level 0, stays in HBM
+    base_hbm,       # ANY  (rows0, c) level 0, one chunk per row, in HBM
     upper_ref,      # VMEM (rows, c): all upper levels, one chunk per row
     upper_pos_ref,  # VMEM (rows, c) i32 or None (closure decides)
     # outputs
     out_ref,        # SMEM (qb,) values
     out_pos_ref,    # SMEM (qb,) i32 or None
     # scratch
-    win_ref,        # VMEM (2, 2, c) double-buffered boundary windows
+    win_ref,        # VMEM (2, 2, 1, c) double-buffered boundary windows
     sems,           # DMA semaphores (2, 2)
     *,
     plan: HierarchyPlan,
@@ -89,40 +90,32 @@ def _rmq_fused_kernel(
     track_pos: bool,
 ):
     c = plan.c
-    n = plan.capacity  # stored base length (+inf-padded past the live tail)
+    rows0 = -(-plan.capacity // c)  # level-0 rows (+inf tail past capacity)
     num_levels = plan.num_levels
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
 
-    def window_starts(i):
-        """Aligned level-0 window anchors for query i."""
-        l = l_ref[i]
-        r = r_ref[i] + 1
-        a_start = jnp.clip((l // c) * c, 0, max(n - c, 0))
-        b_start = jnp.clip((r // c) * c, 0, max(n - c, 0))
-        return a_start, b_start
+    def window_rows(i):
+        """Level-0 rows holding query i's boundary chunks."""
+        a_row = jnp.clip(l_ref[i] // c, 0, rows0 - 1)
+        b_row = jnp.clip((r_ref[i] + 1) // c, 0, rows0 - 1)
+        return a_row, b_row
+
+    def copy(row, slot, side):
+        return pltpu.make_async_copy(
+            base_hbm.at[pl.ds(row, 1)], win_ref.at[slot, side],
+            sems.at[slot, side],
+        )
 
     def issue(i, slot):
-        a_start, b_start = window_starts(i)
-        pltpu.make_async_copy(
-            base_hbm.at[pl.ds(a_start, c)], win_ref.at[slot, 0],
-            sems.at[slot, 0],
-        ).start()
-        pltpu.make_async_copy(
-            base_hbm.at[pl.ds(b_start, c)], win_ref.at[slot, 1],
-            sems.at[slot, 1],
-        ).start()
+        a_row, b_row = window_rows(i)
+        copy(a_row, slot, 0).start()
+        copy(b_row, slot, 1).start()
 
     def wait(i, slot):
-        a_start, b_start = window_starts(i)
-        pltpu.make_async_copy(
-            base_hbm.at[pl.ds(a_start, c)], win_ref.at[slot, 0],
-            sems.at[slot, 0],
-        ).wait()
-        pltpu.make_async_copy(
-            base_hbm.at[pl.ds(b_start, c)], win_ref.at[slot, 1],
-            sems.at[slot, 1],
-        ).wait()
+        a_row, b_row = window_rows(i)
+        copy(a_row, slot, 0).wait()
+        copy(b_row, slot, 1).wait()
 
     issue(0, 0)
 
@@ -136,7 +129,7 @@ def _rmq_fused_kernel(
 
         l = l_ref[i]
         r = r_ref[i] + 1  # exclusive
-        a_start, b_start = window_starts(i)
+        a_row, b_row = window_rows(i)
 
         next_l = ((l + c - 1) // c) * c
         prev_r = (r // c) * c
@@ -145,17 +138,15 @@ def _rmq_fused_kernel(
         # A short span's two windows cover [l, r) outright; the ascended
         # range below is then empty and every upper mask stays empty —
         # the kernel-internal equivalent of the planner's SHORT route.
-        idx_a = a_start + lane
-        idx_b = b_start + lane
+        idx_a = a_row * c + lane
+        idx_b = b_row * c + lane
         pos_a = idx_a if track_pos else None
         pos_b = idx_b if track_pos else None
         m, p = _masked_min_2d(
-            win_ref[slot, 0].reshape(1, c), idx_a, l,
-            jnp.minimum(next_l, r), pos_a,
+            win_ref[slot, 0], idx_a, l, jnp.minimum(next_l, r), pos_a,
         )
         m2, p2 = _masked_min_2d(
-            win_ref[slot, 1].reshape(1, c), idx_b,
-            jnp.maximum(prev_r, l), r, pos_b,
+            win_ref[slot, 1], idx_b, jnp.maximum(prev_r, l), r, pos_b,
         )
         m, p = _merge(m, p, m2, p2)
 
@@ -232,6 +223,7 @@ def rmq_fused_pallas(
 ):
     """Launch the fused query kernel.  ``ls.shape[0]`` must divide by qb.
 
+    ``base`` is level 0 as ``(rows, c)`` (``kernels.common.chunk_rows``);
     ``upper2d`` is the contiguous upper buffer reshaped ``(rows, c)``;
     ``offsets`` is the int32 ``plan.offsets`` table (entry units),
     consumed via scalar prefetch.  Returns ``(values, positions)`` —
@@ -284,7 +276,7 @@ def rmq_fused_pallas(
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((2, 2, c), base.dtype),   # [slot][side][c] dbl-buf
+            pltpu.VMEM((2, 2, 1, c), base.dtype),  # [slot][side] rows
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )

@@ -39,7 +39,7 @@ from repro.core.hierarchy import Hierarchy
 from repro.core.hybrid import _hybrid_batch
 from repro.core.plan import HierarchyPlan
 from repro.core.query import _rmq_batch_impl
-from repro.kernels import profiling
+from repro.kernels import common, profiling
 from repro.kernels.rmq_fused import kernel as K
 
 __all__ = [
@@ -47,10 +47,6 @@ __all__ = [
     "rmq_fused_value_batch",
     "rmq_fused_index_batch",
 ]
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _kernel_applicable(plan: HierarchyPlan) -> bool:
@@ -104,7 +100,7 @@ def _fused_jnp(base, upper, upper_pos, ls, rs, plan, track_pos):
 def _run_kernel(base, upper, upper_pos, ls, rs, plan, qb, track_pos,
                 interpret):
     m = ls.shape[0]
-    m_pad = -(-m // qb) * qb
+    qb, m_pad = common.query_grid(m, qb, interpret)
     profiling.record_launch(
         "rmq_fused",
         lowering="pallas",
@@ -125,7 +121,7 @@ def _run_kernel(base, upper, upper_pos, ls, rs, plan, qb, track_pos,
     upos2d = upper_pos.reshape(-1, plan.c) if track_pos else None
     offs = jnp.asarray(plan.offsets, jnp.int32)
     vals, pos = K.rmq_fused_pallas(
-        base,
+        common.chunk_rows(base, plan.c),
         upper2d,
         upos2d,
         offs,
@@ -168,10 +164,12 @@ def rmq_fused_batch(
     plan = h.plan
     quantized = h.upper.dtype != h.base.dtype
     use_kernel = _kernel_applicable(plan) and not quantized and (
-        _on_tpu() if interpret is None else bool(interpret) or _on_tpu()
+        bool(interpret) or common.on_tpu()
     )
     if use_kernel:
         itp = False if interpret is None else bool(interpret)
+        if not itp:
+            common.check_query_vmem(plan, track_pos, h.upper.dtype.itemsize)
         return _run_kernel(
             h.base, h.upper, h.upper_pos if track_pos else None,
             ls, rs, plan, qb, track_pos, itp,

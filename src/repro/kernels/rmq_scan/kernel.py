@@ -10,9 +10,9 @@ queuing (WLQ):
   and one bounds transfer per query).
 * **Chunk-aligned windows (CL analogue).** Every level access reads one
   aligned ``c``-wide chunk — the paper's "random but cache-aligned chunk
-  accesses".  Upper levels are stored ``(rows, c)`` so a chunk is exactly
-  one sublane row; level 0 chunks are DMA'd HBM→VMEM per query (the GPU's
-  coalesced global load becomes an explicit DMA).
+  accesses".  Every level is read as ``(rows, c)`` so a chunk is exactly
+  one sublane row; level 0 chunks are row-DMA'd HBM→VMEM per query (the
+  GPU's coalesced global load becomes an explicit DMA).
 * **VMEM-resident upper levels (L2 analogue).** The whole upper buffer is
   a single VMEM block with a constant index_map, fetched once and reused
   by every grid step — the role the 100 MB L2 plays in the paper's
@@ -46,10 +46,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.plan import HierarchyPlan
+from repro.kernels.common import DEFAULT_QUERY_BLOCK
 
 from repro.core.constants import POS_INF_I32 as _POS_INF_I32
 
-DEFAULT_QUERY_BLOCK = 256
 
 
 def _masked_min_2d(vals, idx, lo, hi, pos=None):
@@ -76,14 +76,14 @@ def _rmq_query_kernel(
     # inputs
     l_ref,          # SMEM (qb,) i32
     r_ref,          # SMEM (qb,) i32
-    base_hbm,       # ANY  (n,)  values, stays in HBM
+    base_hbm,       # ANY  (rows0, c) level 0, one chunk per row, in HBM
     upper_ref,      # VMEM (rows, c) all upper levels, chunk per row
     upper_pos_ref,  # VMEM (rows, c) i32 or None (closure decides)
     # outputs
     out_ref,        # SMEM (qb,) f32
     out_pos_ref,    # SMEM (qb,) i32 or None
     # scratch
-    win_ref,        # VMEM (2, 2, c) double-buffered boundary windows
+    win_ref,        # VMEM (2, 2, 1, c) double-buffered boundary windows
     sems,           # DMA semaphores (2, 2)
     *,
     plan: HierarchyPlan,
@@ -91,42 +91,33 @@ def _rmq_query_kernel(
     track_pos: bool,
 ):
     c = plan.c
-    n = plan.capacity  # stored base length (== n unless capacity reserved)
+    rows0 = -(-plan.capacity // c)  # level-0 rows (+inf tail past capacity)
     num_levels = plan.num_levels
-    inf = jnp.float32(jnp.inf)
 
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
 
-    def window_starts(i):
-        """Aligned level-0 window anchors for query i."""
-        l = l_ref[i]
-        r = r_ref[i] + 1
-        a_start = jnp.clip((l // c) * c, 0, max(n - c, 0))
-        b_start = jnp.clip(((r // c) * c), 0, max(n - c, 0))
-        return a_start, b_start
+    def window_rows(i):
+        """Level-0 rows holding query i's boundary chunks."""
+        a_row = jnp.clip(l_ref[i] // c, 0, rows0 - 1)
+        b_row = jnp.clip((r_ref[i] + 1) // c, 0, rows0 - 1)
+        return a_row, b_row
+
+    def copy(row, slot, side):
+        return pltpu.make_async_copy(
+            base_hbm.at[pl.ds(row, 1)], win_ref.at[slot, side],
+            sems.at[slot, side],
+        )
 
     def issue(i, slot):
         """Start both boundary-window DMAs for query i into buffer slot."""
-        a_start, b_start = window_starts(i)
-        pltpu.make_async_copy(
-            base_hbm.at[pl.ds(a_start, c)], win_ref.at[slot, 0],
-            sems.at[slot, 0],
-        ).start()
-        pltpu.make_async_copy(
-            base_hbm.at[pl.ds(b_start, c)], win_ref.at[slot, 1],
-            sems.at[slot, 1],
-        ).start()
+        a_row, b_row = window_rows(i)
+        copy(a_row, slot, 0).start()
+        copy(b_row, slot, 1).start()
 
     def wait(i, slot):
-        a_start, b_start = window_starts(i)
-        pltpu.make_async_copy(
-            base_hbm.at[pl.ds(a_start, c)], win_ref.at[slot, 0],
-            sems.at[slot, 0],
-        ).wait()
-        pltpu.make_async_copy(
-            base_hbm.at[pl.ds(b_start, c)], win_ref.at[slot, 1],
-            sems.at[slot, 1],
-        ).wait()
+        a_row, b_row = window_rows(i)
+        copy(a_row, slot, 0).wait()
+        copy(b_row, slot, 1).wait()
 
     # ---- software pipeline: prefetch query i+1's level-0 windows while
     # the VPU scans query i (DESIGN.md §2.1 — the DMA engines play the
@@ -144,22 +135,20 @@ def _rmq_query_kernel(
 
         l = l_ref[i]
         r = r_ref[i] + 1  # exclusive
-        a_start, b_start = window_starts(i)
+        a_row, b_row = window_rows(i)
 
         next_l = ((l + c - 1) // c) * c
         prev_r = (r // c) * c
 
-        idx_a = a_start + lane
-        idx_b = b_start + lane
+        idx_a = a_row * c + lane
+        idx_b = b_row * c + lane
         pos_a = idx_a if track_pos else None
         pos_b = idx_b if track_pos else None
         m, p = _masked_min_2d(
-            win_ref[slot, 0].reshape(1, c), idx_a, l,
-            jnp.minimum(next_l, r), pos_a,
+            win_ref[slot, 0], idx_a, l, jnp.minimum(next_l, r), pos_a,
         )
         m2, p2 = _masked_min_2d(
-            win_ref[slot, 1].reshape(1, c), idx_b,
-            jnp.maximum(prev_r, l), r, pos_b,
+            win_ref[slot, 1], idx_b, jnp.maximum(prev_r, l), r, pos_b,
         )
         m, p = _merge(m, p, m2, p2)
 
@@ -233,6 +222,7 @@ def rmq_query_pallas(
 ):
     """Launch the query kernel.  ``ls.shape[0]`` must be a multiple of qb.
 
+    ``base`` is level 0 as ``(rows, c)`` (``kernels.common.chunk_rows``);
     ``upper2d`` is the contiguous upper buffer reshaped to ``(rows, c)``
     (one chunk per sublane row).  Returns ``(values, positions)``;
     positions are INT32_MAX when ``track_pos=False``.
@@ -284,7 +274,7 @@ def rmq_query_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((2, 2, c), base.dtype),   # [slot][side][c] dbl-buf
+            pltpu.VMEM((2, 2, 1, c), base.dtype),  # [slot][side] rows
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
         interpret=interpret,

@@ -15,12 +15,8 @@ import jax.numpy as jnp
 from repro.core import bitpack
 from repro.core.hierarchy import Hierarchy
 from repro.core.query import rmq_index_batch, rmq_value_batch
-from repro.kernels import profiling
+from repro.kernels import common, profiling
 from repro.kernels.rmq_scan import kernel as K
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _kernel_applicable(h: Hierarchy) -> bool:
@@ -33,7 +29,7 @@ def _kernel_applicable(h: Hierarchy) -> bool:
 )
 def _run(base, upper, upper_pos, ls, rs, plan, qb, track_pos, interpret):
     m = ls.shape[0]
-    m_pad = -(-m // qb) * qb
+    qb, m_pad = common.query_grid(m, qb, interpret)
     profiling.record_launch(
         "rmq_scan",
         lowering="pallas",
@@ -55,7 +51,7 @@ def _run(base, upper, upper_pos, ls, rs, plan, qb, track_pos, interpret):
         upper_pos.reshape(-1, plan.c) if track_pos else None
     )
     vals, pos = K.rmq_query_pallas(
-        base,
+        common.chunk_rows(base, plan.c),
         upper2d,
         upos2d,
         ls.astype(jnp.int32),
@@ -81,8 +77,9 @@ def rmq_value_batch_pallas(
         # bf16 summaries need the exact-recovery walk; the scan kernel
         # compares quantized values only.
         return rmq_value_batch(h, ls, rs)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = common.resolve_interpret(interpret)
+    if not interpret:
+        common.check_query_vmem(h.plan, False, h.upper.dtype.itemsize)
     vals, _ = _run(
         h.base, h.upper, None, ls, rs, h.plan, qb, False, interpret
     )
@@ -100,8 +97,9 @@ def rmq_index_batch_pallas(
         raise ValueError("hierarchy built without positions")
     if not _kernel_applicable(h) or h.upper.dtype != h.base.dtype:
         return rmq_index_batch(h, ls, rs)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = common.resolve_interpret(interpret)
+    if not interpret:
+        common.check_query_vmem(h.plan, True, h.upper.dtype.itemsize)
     _, pos = _run(
         h.base, h.upper, h.upper_pos, ls, rs, h.plan, qb, True, interpret
     )

@@ -13,15 +13,17 @@ skips the hierarchy entirely:
 * per query, the two aligned chunks ``floor(l/c)`` and ``floor(l/c)+1``
   are DMA'd HBM→VMEM into a double buffer, prefetching query ``i+1``'s
   chunks while the VPU scans query ``i``;
-* one masked min over the ``(2, c)`` window produces the value, and —
+* a masked min over the two rows produces the value, and —
   because level 0 is the original array — the leftmost-minimum
   *position* falls out of the same scan as the masked index min.  No
   ``upper_pos`` planes, so ``RMQ_index`` works even on value-only
   builds.
 
-The anchor is clamped to ``capacity - 2c`` (mirrors the ref oracle), so
-the kernel requires ``capacity >= 2c``; ``ops.py`` falls back to the ref
-below that.
+Level 0 arrives as ``(rows, c)``, one chunk per row, so each chunk is one
+row DMA.  The anchor row is clamped to ``rows - 2``, which covers the
+same entries as the ref oracle's ``capacity - 2c`` clamp; the kernel
+requires ``capacity >= 2c`` and ``ops.py`` falls back to the ref below
+that.
 """
 
 from __future__ import annotations
@@ -34,22 +36,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.plan import HierarchyPlan
+from repro.kernels.common import DEFAULT_QUERY_BLOCK
 
 from repro.core.constants import POS_INF_I32 as _POS_INF_I32
 
-DEFAULT_QUERY_BLOCK = 256
 
 
 def _rmq_short_kernel(
     # inputs
     l_ref,       # SMEM (qb,) i32
     r_ref,       # SMEM (qb,) i32
-    base_hbm,    # ANY  (capacity,) values, stays in HBM
+    base_hbm,    # ANY  (rows0, c) level 0, one chunk per row, in HBM
     # outputs
     out_ref,     # SMEM (qb,) f32
     out_pos_ref, # SMEM (qb,) i32 or None (closure decides)
     # scratch
-    win_ref,     # VMEM (2, 2, c) double-buffered two-chunk windows
+    win_ref,     # VMEM (2, 2, 1, c) double-buffered two-chunk windows
     sems,        # DMA semaphores (2, 2)
     *,
     plan: HierarchyPlan,
@@ -57,31 +59,28 @@ def _rmq_short_kernel(
     track_pos: bool,
 ):
     c = plan.c
-    cap = plan.capacity
-    lane = jax.lax.broadcasted_iota(jnp.int32, (2, c), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (2, c), 0)
+    rows0 = -(-plan.capacity // c)  # level-0 rows (+inf tail past capacity)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, c), 1)
 
     def anchor_of(i):
-        l = l_ref[i]
-        return jnp.clip((l // c) * c, 0, max(cap - 2 * c, 0))
+        """First of the two level-0 rows scanned for query i."""
+        return jnp.clip(l_ref[i] // c, 0, rows0 - 2)
+
+    def copy(a, slot, side):
+        return pltpu.make_async_copy(
+            base_hbm.at[pl.ds(a + side, 1)], win_ref.at[slot, side],
+            sems.at[slot, side],
+        )
 
     def issue(i, slot):
         a = anchor_of(i)
         for side in range(2):
-            pltpu.make_async_copy(
-                base_hbm.at[pl.ds(a + side * c, c)],
-                win_ref.at[slot, side],
-                sems.at[slot, side],
-            ).start()
+            copy(a, slot, side).start()
 
     def wait(i, slot):
         a = anchor_of(i)
         for side in range(2):
-            pltpu.make_async_copy(
-                base_hbm.at[pl.ds(a + side * c, c)],
-                win_ref.at[slot, side],
-                sems.at[slot, side],
-            ).wait()
+            copy(a, slot, side).wait()
 
     issue(0, 0)
 
@@ -96,14 +95,21 @@ def _rmq_short_kernel(
         l = l_ref[i]
         r = r_ref[i]
         a = anchor_of(i)
-        idx = a + row * c + lane              # absolute level-0 indices
-        mask = (idx >= l) & (idx <= r)
-        masked = jnp.where(mask, win_ref[slot], jnp.inf)
-        m = jnp.min(masked)
+        m = jnp.float32(jnp.inf).astype(out_ref.dtype)
+        p = jnp.int32(_POS_INF_I32)
+        for side in range(2):
+            idx = (a + side) * c + lane       # absolute level-0 indices
+            mask = (idx >= l) & (idx <= r)
+            masked = jnp.where(mask, win_ref[slot, side], jnp.inf)
+            m2 = jnp.min(masked)
+            p2 = jnp.min(jnp.where(mask & (masked == m2), idx, _POS_INF_I32))
+            # lexicographic (value, position) merge keeps ties leftmost
+            take2 = (m2 < m) | ((m2 == m) & (p2 < p))
+            m = jnp.where(take2, m2, m)
+            p = jnp.where(take2, p2, p)
         out_ref[i] = m
         if track_pos:
-            cand = jnp.where(mask & (masked == m), idx, _POS_INF_I32)
-            out_pos_ref[i] = jnp.min(cand)
+            out_pos_ref[i] = p
         return 0
 
     jax.lax.fori_loop(0, qb, body, 0)
@@ -163,7 +169,7 @@ def rmq_short_pallas(
         out_specs=out_specs,
         out_shape=out_shape,
         scratch_shapes=[
-            pltpu.VMEM((2, 2, c), base.dtype),   # [slot][chunk][c] dbl-buf
+            pltpu.VMEM((2, 2, 1, c), base.dtype),  # [slot][chunk] rows
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
         interpret=interpret,

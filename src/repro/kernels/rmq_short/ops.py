@@ -17,13 +17,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.hierarchy import Hierarchy
-from repro.kernels import profiling
+from repro.kernels import common, profiling
 from repro.kernels.rmq_short import kernel as K
 from repro.kernels.rmq_short.ref import rmq_short_batch_ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 def _kernel_applicable(h: Hierarchy) -> bool:
@@ -35,7 +31,7 @@ def _kernel_applicable(h: Hierarchy) -> bool:
 )
 def _run(base, ls, rs, plan, qb, track_pos, interpret):
     m = ls.shape[0]
-    m_pad = -(-m // qb) * qb
+    qb, m_pad = common.query_grid(m, qb, interpret)
     profiling.record_launch(
         "rmq_short",
         lowering="pallas",
@@ -48,7 +44,7 @@ def _run(base, ls, rs, plan, qb, track_pos, interpret):
         ls = jnp.pad(ls, (0, m_pad - m))
         rs = jnp.pad(rs, (0, m_pad - m))
     vals, pos = K.rmq_short_pallas(
-        base,
+        common.chunk_rows(base, plan.c),
         ls.astype(jnp.int32),
         rs.astype(jnp.int32),
         plan,
@@ -90,8 +86,7 @@ def rmq_short_value_batch_pallas(
 ) -> jax.Array:
     if not _kernel_applicable(h):
         return rmq_short_value_batch(h, ls, rs)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = common.resolve_interpret(interpret)
     vals, _ = _run(
         h.base, jnp.asarray(ls), jnp.asarray(rs), h.plan, qb, False,
         interpret,
@@ -108,8 +103,7 @@ def rmq_short_index_batch_pallas(
 ) -> jax.Array:
     if not _kernel_applicable(h):
         return rmq_short_index_batch(h, ls, rs)
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = common.resolve_interpret(interpret)
     _, pos = _run(
         h.base, jnp.asarray(ls), jnp.asarray(rs), h.plan, qb, True,
         interpret,

@@ -31,7 +31,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.compat import cost_analysis_dict
 from repro.configs.base import (
     ARCH_IDS,
     ModelConfig,
@@ -349,7 +348,7 @@ def run_cell(arch: str, shape: str, mesh, mesh_desc: str,
         compiled = lowered.compile()
     dt = time.time() - t0
 
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     ma = compiled.memory_analysis()
     hlo = compiled.as_text()
     return CellResult(

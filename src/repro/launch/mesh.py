@@ -22,14 +22,9 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType (explicit-sharding API) only exists on newer
-    # JAX; Auto is the default there and the only behavior on older JAX.
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
-        )
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes),
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False, num_pods: int = 2):

@@ -31,8 +31,8 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.models.layers import cast, cdtype, dense_init, mlp, mlp_init
 

@@ -56,6 +56,7 @@ from repro.core.protocol import (
     runtime_backend,
 )
 from repro.core.query import check_query_args
+from repro.kernels.common import check_query_vmem, resolve_interpret
 from repro.kernels.profiling import record_config
 from repro.obs import trace
 from repro.obs.metrics import SIZE_BUCKETS, Metrics
@@ -332,6 +333,15 @@ class QueryEngine:
             if backend != self.backend:
                 self.backend = backend
                 self._configure_executors(backend)
+            if backend in ("pallas", "fused") and not resolve_interpret(
+                self._interpret
+            ):
+                # the compiled walk kernels hold the upper buffer in VMEM:
+                # refuse a geometry past it here, not at the first query
+                check_query_vmem(
+                    plan, index.with_positions,
+                    jnp.dtype(index.value_dtype).itemsize,
+                )
             resolved = self._resolve_config(index)
             self.bulk_crossover = self._resolve_bulk_crossover(index)
             resolved["bulk_crossover"] = self.bulk_crossover

@@ -1,10 +1,9 @@
-"""Compat shims: the shard_map keyword must be detected by *support*.
+"""The installed JAX API surface the code base calls directly.
 
-Regression coverage for the mid-band JAX hazard: releases where
-``shard_map`` already lives at ``jax.shard_map`` but still only accepts
-``check_rep`` (the ``check_vma`` rename landed later).  Probing by
-attribute location would pass the wrong keyword on those versions; the
-shim must inspect the signature instead.
+The repo targets one JAX release and calls its entry points without
+version shims: ``jax.shard_map`` with the ``check_vma`` keyword
+(``core/distributed.py``, ``models/moe.py``) and ``Compiled.cost_analysis``
+returning a flat dict (``launch/cells.py``, ``kernels/profiling.py``).
 """
 
 import jax
@@ -12,60 +11,11 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
-
-
-# -- signature fakes (each spelling the shim must cope with) ---------------
-def _modern(f, *, mesh, in_specs, out_specs, check_vma=True):
-    return ("check_vma", check_vma)
-
-
-def _mid_band(f, *, mesh, in_specs, out_specs, check_rep=True):
-    # the hazard: modern *location*, legacy *keyword*
-    return ("check_rep", check_rep)
-
-
-def _kwargs_only(f, **kwargs):
-    return ("kwargs", kwargs.get("check_vma"))
-
-
-def _no_knob(f, *, mesh, in_specs, out_specs):
-    return ("none", None)
-
-
-def _call(**kw):
-    return compat.shard_map(
-        lambda: None, mesh="m", in_specs="i", out_specs="o", **kw
-    )
-
-
-def test_modern_signature_gets_check_vma(monkeypatch):
-    monkeypatch.setattr(jax, "shard_map", _modern, raising=False)
-    assert _call(check_vma=False) == ("check_vma", False)
-    assert _call() == ("check_vma", True)
-
-
-def test_mid_band_check_rep_only_gets_check_rep(monkeypatch):
-    """jax.shard_map exists but only accepts check_rep — the regression."""
-    monkeypatch.setattr(jax, "shard_map", _mid_band, raising=False)
-    assert _call(check_vma=False) == ("check_rep", False)
-    assert _call(check_vma=True) == ("check_rep", True)
-
-
-def test_uninspectable_kwargs_passthrough(monkeypatch):
-    monkeypatch.setattr(jax, "shard_map", _kwargs_only, raising=False)
-    assert _call(check_vma=False) == ("kwargs", False)
-
-
-def test_signature_without_knob_omits_it(monkeypatch):
-    monkeypatch.setattr(jax, "shard_map", _no_knob, raising=False)
-    assert _call(check_vma=False) == ("none", None)
-
 
 def test_real_shard_map_roundtrip():
-    """The shim drives the actually-installed JAX end to end."""
+    """``jax.shard_map`` with ``check_vma`` drives the installed JAX."""
     mesh = jax.make_mesh((1,), ("x",))
-    f = compat.shard_map(
+    f = jax.shard_map(
         lambda a: a * 2.0,
         mesh=mesh,
         in_specs=P("x"),
@@ -74,3 +24,10 @@ def test_real_shard_map_roundtrip():
     )
     out = jax.jit(f)(jnp.arange(4.0))
     np.testing.assert_array_equal(np.asarray(out), np.arange(4.0) * 2.0)
+
+
+def test_cost_analysis_is_flat_dict():
+    compiled = jax.jit(lambda a: a @ a).lower(jnp.ones((8, 8))).compile()
+    ca = compiled.cost_analysis()
+    assert isinstance(ca, dict)
+    assert ca.get("flops", 0) > 0

@@ -50,7 +50,6 @@ _SUBPROC = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import dataclasses, json, jax
-from repro.compat import cost_analysis_dict
 from repro.launch.mesh import make_test_mesh
 from repro.launch.cells import train_cell, decode_cell, collective_bytes_from_hlo
 from repro.configs.base import get_smoke_config, TrainConfig
@@ -64,7 +63,7 @@ fn, args, _ = train_cell(cfg, mesh, 64, 8, tc=tc)
 with mesh:
     lowered = jax.jit(fn, donate_argnums=(0,)).lower(*args)
     compiled = lowered.compile()
-ca = cost_analysis_dict(compiled)
+ca = compiled.cost_analysis()
 ma = compiled.memory_analysis()
 assert ca.get("flops", 0) > 0
 assert ma.argument_size_in_bytes > 0
@@ -75,7 +74,7 @@ assert sum(colls.values()) > 0, colls
 fn, args = decode_cell(cfg, mesh, 128, 8)
 with mesh:
     compiled = jax.jit(fn, donate_argnums=(2,)).lower(*args).compile()
-assert cost_analysis_dict(compiled).get("flops", 0) > 0
+assert compiled.cost_analysis().get("flops", 0) > 0
 print("DRYRUN_SMOKE_OK")
 """
 
@@ -107,3 +106,33 @@ def test_production_dryrun_artifacts_if_present():
         else:
             assert r.get("ok"), (arch, shape, r.get("error"))
             assert r["flops_per_device"] > 0
+
+
+_CACHE_PROBE = r"""
+import jax
+from repro.launch.compile_cache import enable_compile_cache
+print(enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, from_env):
+    """The cache goes where JAX_COMPILATION_CACHE_DIR says, else to the
+    fixed <repo>/.jax_cache; nothing else is set in code."""
+    import os
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    env = {"PYTHONPATH": str(root / "src"), "PATH": "/usr/bin:/bin",
+           "JAX_PLATFORMS": "cpu"}
+    if from_env:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
+    res = subprocess.run(
+        [sys.executable, "-c", _CACHE_PROBE], capture_output=True,
+        text=True, timeout=120, env=env, cwd=tmp_path,
+    )
+    assert res.returncode == 0, res.stderr
+    want = str(tmp_path) if from_env else str(root / ".jax_cache")
+    assert res.stdout.split() == [want, want]
+    assert not os.listdir(tmp_path)  # nothing compiled, nothing written

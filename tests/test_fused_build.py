@@ -96,7 +96,7 @@ class TestFusedBuildParity:
     @pytest.mark.parametrize("n,c,t,cap", [(777, 4, 2, 1024)])
     def test_f64_parity(self, n, c, t, cap):
         """x64 mode: f64 values with int64 positions, all backends."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             rng = np.random.default_rng(7)
             x = jnp.asarray(_tied_input(rng, n, np.float64))
             assert x.dtype == jnp.float64

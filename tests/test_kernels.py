@@ -55,11 +55,17 @@ class TestHierarchyBuildKernel:
         from repro.kernels.hierarchy_build.ref import build_level_ref
 
         rng = np.random.default_rng(0)
-        for c, tile in [(128, 8), (256, 4), (8, 64)]:
-            x = jnp.asarray(rng.random(c * tile * 4).astype(np.float32))
-            got = build_level(x, c=c, tile_out=tile, interpret=True)
+        for c, rows_out in [(128, 8), (256, 2), (8, 8)]:
+            x = jnp.asarray(
+                rng.random(c * c * rows_out * 4).astype(np.float32)
+            )
+            got = build_level(
+                x.reshape(-1, c), c=c, rows_out=rows_out, interpret=True
+            )
+            assert got.shape == (x.shape[0] // (c * c), c)
             np.testing.assert_array_equal(
-                np.asarray(got), np.asarray(build_level_ref(x, c))
+                np.asarray(got).reshape(-1),
+                np.asarray(build_level_ref(x, c)),
             )
 
 
