@@ -34,6 +34,7 @@ from repro.core import protocol as px
 from repro.core.hierarchy import Hierarchy
 from repro.core.plan import HierarchyPlan, make_plan
 from repro.core.query import check_query_args
+from repro.obs import trace
 
 __all__ = ["RMQ"]
 
@@ -84,46 +85,28 @@ class RMQ:
         with exact recovery — see ``make_plan``); ``None`` defers to the
         tuning cache, then the classic layout.
         """
-        x = px.coerce_values(x)
-        if plan is not None and capacity is not None:
-            raise ValueError(
-                "pass capacity via make_plan(..., capacity=...) when "
-                "supplying an explicit plan"
-            )
-        tuned_cfg = None
-        if plan is None and (c == "auto" or tuning is not None):
-            from repro.tune import cache as _tc
-
-            store = tuning if tuning is not None else _tc.default_cache()
-            tuned_cfg = store.lookup(
-                _tc.current_platform(), int(x.shape[0]), span_mix
-            )
-        if plan is None:
-            if tuned_cfg is not None:
-                if packed_pos is None:
-                    packed_pos = getattr(tuned_cfg, "packed_pos", None)
-                if summary_dtype is None:
-                    summary_dtype = getattr(
-                        tuned_cfg, "summary_dtype", None
+        with trace.span("build") as sp:
+            x = px.coerce_values(x)
+            if plan is not None and capacity is not None:
+                raise ValueError(
+                    "pass capacity via make_plan(..., capacity=...) when "
+                    "supplying an explicit plan"
+                )
+            tuned_cfg = None
+            with trace.span("build_plan"):
+                if plan is None:
+                    plan, tuned_cfg = _resolve_plan(
+                        int(x.shape[0]), c, t, capacity, tuning, span_mix,
+                        packed_pos, summary_dtype,
                     )
-                plan = make_plan(
-                    int(x.shape[0]), c=tuned_cfg.c, t=tuned_cfg.t,
-                    capacity=capacity,
-                    level_split=tuned_cfg.level_split(),
-                    packed_pos=packed_pos, summary_dtype=summary_dtype,
-                )
-            else:
-                plan = make_plan(
-                    int(x.shape[0]), c=128 if c == "auto" else c, t=t,
-                    capacity=capacity,
-                    packed_pos=packed_pos, summary_dtype=summary_dtype,
-                )
-        if backend == "auto" and tuned_cfg is not None:
-            backend = tuned_cfg.backend
-        backend = px.resolve_backend(backend)
-        h = px.build_hierarchy_with_backend(
-            x, plan, with_positions=with_positions, backend=backend
-        )
+            if backend == "auto" and tuned_cfg is not None:
+                backend = tuned_cfg.backend
+            backend = px.resolve_backend(backend)
+            h = px.build_hierarchy_with_backend(
+                x, plan, with_positions=with_positions, backend=backend
+            )
+            if sp is not None:
+                sp.args.update(n=int(x.shape[0]), backend=backend)
         return RMQ(hierarchy=h, backend=backend, length=plan.n)
 
     @staticmethod
@@ -260,3 +243,32 @@ class RMQ:
 
     def auxiliary_bytes(self) -> int:
         return self.hierarchy.auxiliary_bytes()
+
+
+def _resolve_plan(n, c, t, capacity, tuning, span_mix, packed_pos,
+                  summary_dtype):
+    """``(plan, tuned config or None)`` for a build of ``n`` values: the
+    tuning cache's entry when ``c="auto"`` or ``tuning`` is given and it
+    has one, else the explicit geometry (``c="auto"`` -> 128)."""
+    tuned_cfg = None
+    if c == "auto" or tuning is not None:
+        from repro.tune import cache as _tc
+
+        store = tuning if tuning is not None else _tc.default_cache()
+        tuned_cfg = store.lookup(_tc.current_platform(), n, span_mix)
+    if tuned_cfg is None:
+        plan = make_plan(
+            n, c=128 if c == "auto" else c, t=t, capacity=capacity,
+            packed_pos=packed_pos, summary_dtype=summary_dtype,
+        )
+        return plan, None
+    if packed_pos is None:
+        packed_pos = getattr(tuned_cfg, "packed_pos", None)
+    if summary_dtype is None:
+        summary_dtype = getattr(tuned_cfg, "summary_dtype", None)
+    plan = make_plan(
+        n, c=tuned_cfg.c, t=tuned_cfg.t, capacity=capacity,
+        level_split=tuned_cfg.level_split(),
+        packed_pos=packed_pos, summary_dtype=summary_dtype,
+    )
+    return plan, tuned_cfg

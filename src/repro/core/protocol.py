@@ -292,20 +292,22 @@ def build_hierarchy_with_backend(
     from repro.core.hierarchy import _check_compact_build
 
     _check_compact_build(plan, with_positions, x.dtype)
-    if backend == "fused":
-        from repro.kernels.hierarchy_fused import ops as fused_ops
+    # device work is asynchronous: the span is the host's dispatch alone
+    with trace.span("build_dispatch", backend=backend):
+        if backend == "fused":
+            from repro.kernels.hierarchy_fused import ops as fused_ops
 
-        return finalize_compact(fused_ops.build_hierarchy_fused(
-            x, plan, with_positions=with_positions
-        ))
-    if backend == "pallas":
-        from repro.kernels.hierarchy_build import ops as build_ops
+            return finalize_compact(fused_ops.build_hierarchy_fused(
+                x, plan, with_positions=with_positions
+            ))
+        if backend == "pallas":
+            from repro.kernels.hierarchy_build import ops as build_ops
 
-        return finalize_compact(build_ops.build_hierarchy_pallas(
-            x, plan, with_positions=with_positions
-        ))
-    if backend == "jax":
-        return build_hierarchy(x, plan, with_positions=with_positions)
+            return finalize_compact(build_ops.build_hierarchy_pallas(
+                x, plan, with_positions=with_positions
+            ))
+        if backend == "jax":
+            return build_hierarchy(x, plan, with_positions=with_positions)
     raise ValueError(f"unknown backend {backend!r}")
 
 

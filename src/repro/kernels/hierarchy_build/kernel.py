@@ -95,6 +95,7 @@ def build_level(
         out_specs=out_spec,
         out_shape=jax.ShapeDtypeStruct((chunks // c, c), values.dtype),
         interpret=interpret,
+        name="hierarchy_build",
     )(values)
 
 
@@ -121,4 +122,5 @@ def build_level_with_positions(
             jax.ShapeDtypeStruct((chunks // c, c), positions.dtype),
         ],
         interpret=interpret,
+        name="hierarchy_build",
     )(values, positions)

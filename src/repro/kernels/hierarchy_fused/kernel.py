@@ -176,5 +176,6 @@ def fused_build(
         out_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * len(out_shape),
         out_shape=out_shape,
         interpret=interpret,
+        name="hierarchy_fused",
     )(values)
     return out[0], (out[1] if track else None)

@@ -136,6 +136,7 @@ def _launch(values, positions, ids, c, cap, pos_dtype, interpret,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="hierarchy_update",
     )(*operands)
     if track:
         return out[0][:b], out[1][:b]

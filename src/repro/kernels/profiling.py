@@ -1,4 +1,4 @@
-"""Trace-time kernel-launch accounting and the launch/cost registry.
+"""Trace-time kernel-launch accounting and the launch registry.
 
 The fused construction pipeline's contract is *one* Pallas launch per
 build (vs. one per level on the historical path).  That claim is easy to
@@ -20,25 +20,14 @@ geometry is traced — wrap the *first* build of a fresh geometry in
 Outside a :func:`count_launches` scope, :func:`record_launch` is a no-op,
 so production builds pay nothing.
 
-Two richer layers stack on the same recording sites without changing the
-:func:`count_launches` contract:
-
-* :func:`launch_registry` collects :class:`LaunchRecord`\\ s — kernel
-  name plus whatever static metadata the wrapper knows at trace time
-  (grid/level count, operand bytes, query count).  Wrappers pass these
-  as keyword arguments to :func:`record_launch`; when only the plain
-  counter is active the kwargs are ignored.
-* ``launch_registry(timing=True)`` additionally makes
-  :func:`timed_dispatch` time dispatch sites wall-clock (with a
-  ``jax.block_until_ready`` barrier, imported lazily so this module
-  stays jax-free when idle).  Timing records are *per call*, unlike
-  trace-time launch records which are per specialization — the registry
-  keeps them in separate tables.
-
-FLOP/byte *estimates* from the compiler are a property of a compiled
-artifact, not of a traced body, so they attach separately:
-:meth:`LaunchRegistry.attach_cost` accepts any object with an AOT
-``cost_analysis`` and files the estimate under the kernel name.
+A richer layer stacks on the same recording sites without changing the
+:func:`count_launches` contract: :func:`launch_registry` collects
+:class:`LaunchRecord`\\ s — kernel name plus whatever static metadata
+the wrapper knows at trace time (grid/level count, operand bytes, query
+count).  Wrappers pass these as keyword arguments to
+:func:`record_launch`; when only the plain counter is active the kwargs
+are ignored.  Run-time phases (dispatch, the host's wait on the device)
+are spans of :mod:`repro.obs.trace`, not records here.
 """
 
 from __future__ import annotations
@@ -47,7 +36,6 @@ import contextlib
 import dataclasses
 import math
 import threading
-import time
 from typing import Any, Dict, Iterator, List, Optional
 
 __all__ = [
@@ -58,7 +46,6 @@ __all__ = [
     "operand_bytes",
     "record_config",
     "record_launch",
-    "timed_dispatch",
 ]
 
 
@@ -92,15 +79,12 @@ class LaunchRecord:
 
 
 class LaunchRegistry:
-    """Thread-safe collection of launch records, timings, and cost
-    estimates, keyed by kernel name."""
+    """Thread-safe collection of launch records and configuration
+    decisions, keyed by kernel name."""
 
-    def __init__(self, timing: bool = False):
+    def __init__(self):
         self._lock = threading.Lock()
-        self.timing = bool(timing)
         self.records: List[LaunchRecord] = []
-        self.timings: Dict[str, List[float]] = {}
-        self.costs: Dict[str, Dict[str, float]] = {}
         self.configs: List[LaunchRecord] = []
 
     # -- recording ---------------------------------------------------------
@@ -115,24 +99,6 @@ class LaunchRegistry:
         per-kernel launch views."""
         with self._lock:
             self.configs.append(LaunchRecord(name, dict(meta)))
-
-    def add_timing(self, name: str, seconds: float) -> None:
-        with self._lock:
-            self.timings.setdefault(name, []).append(float(seconds))
-
-    def attach_cost(self, name: str, compiled: Any) -> Dict[str, float]:
-        """File the compiler's FLOP/byte estimate for ``name``.
-
-        ``compiled`` is anything exposing AOT ``cost_analysis()`` (a
-        ``jax.stages.Compiled``); the result is reduced to the scalar
-        entries (``flops``, ``bytes accessed``, ...).
-        """
-        raw = compiled.cost_analysis() or {}
-        cost = {k: float(v) for k, v in raw.items()
-                if isinstance(v, (int, float))}
-        with self._lock:
-            self.costs[name] = cost
-        return cost
 
     # -- views -------------------------------------------------------------
     @property
@@ -157,8 +123,6 @@ class LaunchRegistry:
     def as_dict(self) -> dict:
         with self._lock:
             records = [r.as_dict() for r in self.records]
-            timings = {k: list(v) for k, v in self.timings.items()}
-            costs = {k: dict(v) for k, v in self.costs.items()}
             configs = [r.as_dict() for r in self.configs]
         counts: Dict[str, int] = {}
         for r in records:
@@ -166,14 +130,6 @@ class LaunchRegistry:
         out: dict = {"counts": counts, "launches": records}
         if configs:
             out["configs"] = configs
-        if timings:
-            out["timings_s"] = {
-                k: {"calls": len(v), "total": sum(v),
-                    "mean": sum(v) / len(v), "max": max(v)}
-                for k, v in timings.items()
-            }
-        if costs:
-            out["cost_estimates"] = costs
         return out
 
 
@@ -217,40 +173,14 @@ def count_launches() -> Iterator[Dict[str, int]]:
 
 
 @contextlib.contextmanager
-def launch_registry(timing: bool = False) -> Iterator[LaunchRegistry]:
-    """Collect full :class:`LaunchRecord`\\ s (and, with ``timing=True``,
-    wall-clock dispatch timings via :func:`timed_dispatch`) for the
-    duration of the block."""
+def launch_registry() -> Iterator[LaunchRegistry]:
+    """Collect full :class:`LaunchRecord`\\ s for the duration of the
+    block."""
     global _registry
     prev = _registry
-    reg = LaunchRegistry(timing=timing)
+    reg = LaunchRegistry()
     _registry = reg
     try:
         yield reg
     finally:
         _registry = prev
-
-
-def current_registry() -> Optional["LaunchRegistry"]:
-    return _registry
-
-
-def timed_dispatch(name: str, fn, *args, **kwargs):
-    """Call ``fn(*args, **kwargs)``; when a timing-enabled registry is
-    active, record wall time to completion (``jax.block_until_ready`` on
-    the result, so device work is included, not just dispatch).
-
-    When no registry is active — the production default — this is one
-    global load and a tail call: no timers, no barriers.  The barrier is
-    the point *and* the cost: enabling timing serializes dispatch sites,
-    so it is strictly an offline profiling mode.
-    """
-    reg = _registry
-    if reg is None or not reg.timing:
-        return fn(*args, **kwargs)
-    import jax
-
-    t0 = time.perf_counter()
-    out = jax.block_until_ready(fn(*args, **kwargs))
-    reg.add_timing(name, time.perf_counter() - t0)
-    return out

@@ -259,6 +259,7 @@ def rmq_bulk_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="rmq_bulk",
     )(offsets.astype(jnp.int32), *args)
     if track_pos:
         return out[0], out[1]

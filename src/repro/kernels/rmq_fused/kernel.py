@@ -285,6 +285,7 @@ def rmq_fused_pallas(
         grid_spec=grid_spec,
         out_shape=out_shape,
         interpret=interpret,
+        name="rmq_fused",
     )(offsets.astype(jnp.int32), *args)
     if track_pos:
         return out[0], out[1]

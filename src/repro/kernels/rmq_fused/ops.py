@@ -97,7 +97,7 @@ def _fused_jnp(base, upper, upper_pos, ls, rs, plan, track_pos):
 @functools.partial(
     jax.jit, static_argnames=("plan", "qb", "track_pos", "interpret")
 )
-def _run_kernel(base, upper, upper_pos, ls, rs, plan, qb, track_pos,
+def _run_rmq_fused(base, upper, upper_pos, ls, rs, plan, qb, track_pos,
                 interpret):
     m = ls.shape[0]
     qb, m_pad = common.query_grid(m, qb, interpret)
@@ -170,7 +170,7 @@ def rmq_fused_batch(
         itp = False if interpret is None else bool(interpret)
         if not itp:
             common.check_query_vmem(plan, track_pos, h.upper.dtype.itemsize)
-        return _run_kernel(
+        return _run_rmq_fused(
             h.base, h.upper, h.upper_pos if track_pos else None,
             ls, rs, plan, qb, track_pos, itp,
         )

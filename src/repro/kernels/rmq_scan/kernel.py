@@ -278,6 +278,7 @@ def rmq_query_pallas(
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
         interpret=interpret,
+        name="rmq_scan",
     )(*args)
     if track_pos:
         return out[0], out[1]

@@ -27,7 +27,7 @@ def _kernel_applicable(h: Hierarchy) -> bool:
     jax.jit,
     static_argnames=("plan", "qb", "track_pos", "interpret"),
 )
-def _run(base, upper, upper_pos, ls, rs, plan, qb, track_pos, interpret):
+def _run_rmq_scan(base, upper, upper_pos, ls, rs, plan, qb, track_pos, interpret):
     m = ls.shape[0]
     qb, m_pad = common.query_grid(m, qb, interpret)
     profiling.record_launch(
@@ -80,7 +80,7 @@ def rmq_value_batch_pallas(
     interpret = common.resolve_interpret(interpret)
     if not interpret:
         common.check_query_vmem(h.plan, False, h.upper.dtype.itemsize)
-    vals, _ = _run(
+    vals, _ = _run_rmq_scan(
         h.base, h.upper, None, ls, rs, h.plan, qb, False, interpret
     )
     return vals
@@ -100,7 +100,7 @@ def rmq_index_batch_pallas(
     interpret = common.resolve_interpret(interpret)
     if not interpret:
         common.check_query_vmem(h.plan, True, h.upper.dtype.itemsize)
-    _, pos = _run(
+    _, pos = _run_rmq_scan(
         h.base, h.upper, h.upper_pos, ls, rs, h.plan, qb, True, interpret
     )
     return pos

@@ -173,6 +173,7 @@ def rmq_short_pallas(
             pltpu.SemaphoreType.DMA((2, 2)),
         ],
         interpret=interpret,
+        name="rmq_short",
     )(ls, rs, base)
     if track_pos:
         return out[0], out[1]

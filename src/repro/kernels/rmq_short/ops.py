@@ -29,7 +29,7 @@ def _kernel_applicable(h: Hierarchy) -> bool:
 @functools.partial(
     jax.jit, static_argnames=("plan", "qb", "track_pos", "interpret")
 )
-def _run(base, ls, rs, plan, qb, track_pos, interpret):
+def _run_rmq_short(base, ls, rs, plan, qb, track_pos, interpret):
     m = ls.shape[0]
     qb, m_pad = common.query_grid(m, qb, interpret)
     profiling.record_launch(
@@ -87,7 +87,7 @@ def rmq_short_value_batch_pallas(
     if not _kernel_applicable(h):
         return rmq_short_value_batch(h, ls, rs)
     interpret = common.resolve_interpret(interpret)
-    vals, _ = _run(
+    vals, _ = _run_rmq_short(
         h.base, jnp.asarray(ls), jnp.asarray(rs), h.plan, qb, False,
         interpret,
     )
@@ -104,7 +104,7 @@ def rmq_short_index_batch_pallas(
     if not _kernel_applicable(h):
         return rmq_short_index_batch(h, ls, rs)
     interpret = common.resolve_interpret(interpret)
-    _, pos = _run(
+    _, pos = _run_rmq_short(
         h.base, jnp.asarray(ls), jnp.asarray(rs), h.plan, qb, True,
         interpret,
     )

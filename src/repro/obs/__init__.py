@@ -2,12 +2,16 @@
 
 Three surfaces, one import point:
 
-* :mod:`repro.obs.trace` — request-lifecycle spans
+* :mod:`repro.obs.trace` — spans of the request lifecycle
   (``submit → admission → queue → snapshot_swap → plan → execute →
-  scatter``) with Chrome-trace/Perfetto export;
+  scatter``), of a bulk batch (``query_bulk → dedup, cache_get, plan,
+  execute → {launch, fetch}, cache_put, scatter``), of a build
+  (``build → build_plan, build_dispatch``) and of runtime pauses
+  (``gc``, ``compile``), with Chrome-trace/Perfetto export and a
+  ``repro.*`` mirror into the ``jax.profiler`` trace;
 * :mod:`repro.obs.metrics` — counters/gauges/histograms with dict and
   Prometheus text exposition (promoted from ``repro.serving.metrics``);
-* :mod:`repro.kernels.profiling` — the kernel launch/cost registry
+* :mod:`repro.kernels.profiling` — the kernel launch registry
   (lives next to the kernels it instruments; re-exported here).
 
 All three follow the same discipline: a single module-global check on

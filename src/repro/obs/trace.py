@@ -36,12 +36,33 @@ Export: :meth:`Tracer.to_chrome_trace` emits the Chrome trace event
 format (``chrome://tracing`` / Perfetto / ``ui.perfetto.dev``) — one
 complete (``"ph": "X"``) event per span, microsecond timestamps, span
 and parent ids in ``args`` so the tree survives tools that re-sort.
+
+While a tracer is installed (:func:`set_tracer`) three more things
+happen, and all three stop when it is removed:
+
+* **profiler mirror** — every span opened with :meth:`Tracer.begin` /
+  :meth:`Tracer.end` / :meth:`Tracer.span` also enters and exits a
+  ``jax.profiler.TraceAnnotation("repro.<name>")``, so the program's
+  phases land in any ``jax.profiler`` trace on the device trace's own
+  clock.  Spans recorded after the fact (:meth:`Tracer.record`,
+  :meth:`Tracer.instant`: ``queue``, ``compile``) cannot be mirrored;
+* **``gc`` spans** — a ``gc.callbacks`` hook opens a span at the start
+  of each collection and closes it at the stop (args ``generation``,
+  ``collected``), on the collecting thread;
+* **``compile`` spans** — a ``jax.monitoring`` time-span listener
+  records each JAX compile stage (args ``fun_name``, ``stage``:
+  ``jaxpr_trace``, ``to_mlir``, ``backend_compile``), mapped from the
+  wall clock onto the tracer's clock, under the thread's open span.
+
+JAX is imported only when a tracer is first installed; this module
+imports without it.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import itertools
 import json
 import threading
@@ -72,6 +93,9 @@ class Span:
     thread: str
     end: Optional[float] = None
     args: Dict[str, Any] = dataclasses.field(default_factory=dict)
+    # the open profiler annotation mirroring this span, if any
+    _ann: Any = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     @property
     def duration(self) -> float:
@@ -116,6 +140,10 @@ class Tracer:
     ``clock`` is injectable (fake clocks in tests; must match the clock
     of any timestamps passed to :meth:`record`).  ``capacity`` bounds
     the retained span count — the oldest spans fall off the ring.
+
+    The buffer's lock is re-entrant: a ``gc`` span can close on a thread
+    that is already inside the tracer (a collection runs between any two
+    bytecodes).
     """
 
     def __init__(
@@ -126,11 +154,13 @@ class Tracer:
         if capacity <= 0:
             raise ValueError(f"capacity must be > 0, got {capacity}")
         self._clock = clock
-        self._lock = threading.Lock()
+        self._lock = threading.RLock()
         self._spans: "deque[Span]" = deque(maxlen=capacity)
         self._ids = itertools.count(1)
         self._tls = threading.local()
         self.dropped = 0          # spans pushed off the ring
+        # profiler annotation class while installed (set_tracer), else None
+        self._mirror = None
 
     # -- span lifecycle ----------------------------------------------------
     def _stack(self) -> List[Span]:
@@ -151,18 +181,29 @@ class Tracer:
             thread=threading.current_thread().name,
         )
         stack.append(sp)
+        mirror = self._mirror
+        if mirror is not None:
+            sp._ann = mirror("repro." + name)
+            sp._ann.__enter__()
         return sp
 
     def end(self, sp: Span, **args) -> Span:
         """Close ``sp`` and record it.  Tolerant of unbalanced stacks
         (an exception that skipped inner ``end`` calls): closes any
-        still-open descendants silently."""
+        still-open descendants silently, and their profiler annotations
+        innermost first."""
         sp.end = self._clock()
         if args:
             sp.args.update(args)
         stack = self._stack()
         if sp in stack:
-            del stack[stack.index(sp):]
+            i = stack.index(sp)
+            for open_sp in reversed(stack[i:]):
+                ann = open_sp._ann
+                if ann is not None:
+                    open_sp._ann = None
+                    ann.__exit__(None, None, None)
+            del stack[i:]
         with self._lock:
             if len(self._spans) == self._spans.maxlen:
                 self.dropped += 1
@@ -184,7 +225,9 @@ class Tracer:
         """Record a span with explicit timestamps (same clock as the
         tracer's).  This is how cross-thread waits — e.g. the ``queue``
         time between a caller's submit and the flusher's drain — enter
-        the trace without holding a span open across threads."""
+        the trace without holding a span open across threads.  Such a
+        span is not mirrored into the profiler: it is over before it is
+        known."""
         sp = Span(
             name=name,
             start=start,
@@ -201,7 +244,7 @@ class Tracer:
         return sp
 
     def instant(self, name: str, **args) -> Span:
-        """Zero-duration marker event."""
+        """Zero-duration marker event (not mirrored into the profiler)."""
         now = self._clock()
         return self.record(name, now, now, **args)
 
@@ -264,11 +307,88 @@ def current() -> Optional[Tracer]:
 
 def set_tracer(tracer: Optional[Tracer]) -> Optional[Tracer]:
     """Install (or, with None, remove) the process-wide tracer.
-    Returns the previously installed tracer."""
+    Returns the previously installed tracer.
+
+    Installing also turns on the profiler mirror for ``tracer`` and the
+    ``gc`` / ``compile`` hooks; removing turns them off again, so none
+    of them costs anything while tracing is disabled.
+    """
     global _active
     prev = _active
+    if prev is not None and prev is not tracer:
+        prev._mirror = None
     _active = tracer
+    if tracer is None:
+        if _on_gc in gc.callbacks:
+            gc.callbacks.remove(_on_gc)
+    else:
+        _install_hooks()
+        tracer._mirror = _annotation
     return prev
+
+
+# ---------------------------------------------------------------------------
+# runtime pauses: garbage collections and JAX compiles
+# ---------------------------------------------------------------------------
+_annotation = None            # jax.profiler.TraceAnnotation, once imported
+_jax_hooked = False
+_gc_open: Optional[tuple] = None   # (tracer, span) of the running collection
+
+# jax.monitoring event -> compile stage
+_COMPILE_STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jaxpr_trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "to_mlir",
+    "/jax/core/compile/backend_compile_duration": "backend_compile",
+}
+
+
+def _install_hooks() -> None:
+    """Hook ``gc.callbacks``; the first time, register the JAX listener
+    (it stays registered and returns at once while no tracer is
+    installed) and fetch the profiler annotation class."""
+    global _annotation, _jax_hooked
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    if _jax_hooked:
+        return
+    _jax_hooked = True
+    try:
+        import jax.monitoring
+        import jax.profiler
+    except ImportError:
+        return
+    jax.monitoring.register_event_time_span_listener(_on_compile)
+    _annotation = jax.profiler.TraceAnnotation
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    global _gc_open
+    if phase == "start":
+        t = _active
+        if t is not None:
+            _gc_open = (t, t.begin("gc"))
+    elif _gc_open is not None:
+        t, sp = _gc_open
+        _gc_open = None
+        t.end(sp, generation=info["generation"],
+              collected=info["collected"])
+
+
+def _on_compile(event: str, start_time: float, end_time: float,
+                **kwargs) -> None:
+    t = _active
+    if t is None:
+        return
+    stage = _COMPILE_STAGES.get(event)
+    if stage is None:
+        return
+    # JAX stamps the stage with time.time(); move it onto the tracer's
+    # clock by the two clocks' offset now
+    shift = t._clock() - time.time()
+    stack = t._stack()
+    t.record("compile", start_time + shift, end_time + shift,
+             parent=stack[-1] if stack else None,
+             fun_name=kwargs.get("fun_name"), stage=stage)
 
 
 @contextlib.contextmanager

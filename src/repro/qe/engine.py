@@ -428,27 +428,39 @@ class QueryEngine:
                 "index was built without positions; rebuild it with "
                 "with_positions=True to serve RMQ_index queries"
             )
-        n = live_length(index)
-        ls, rs = check_query_args(ls, rs, n)
-        ls = np.asarray(ls, np.int32).ravel()
-        rs = np.asarray(rs, np.int32).ravel()
-        if ls.shape[0] < self.bulk_crossover or (
-            self.distributed is None and _quantized(index)
-        ):
+        # the root span of one batch: every engine span below nests in it
+        tr = trace.current()
+        sp = tr.begin("query_bulk") if tr is not None else None
+        try:
+            n = live_length(index)
+            ls, rs = check_query_args(ls, rs, n)
+            ls = np.asarray(ls, np.int32).ravel()
+            rs = np.asarray(rs, np.int32).ravel()
             # bf16 summaries: the coalesced bulk sweep compares quantized
             # level-1 values with no exact-recovery pass, so bf16 indexes
             # always take the routed path (whose walks re-read level 0).
-            return self._execute(ls, rs, op)
-        self.batches += 1
-        self.queries_in += ls.shape[0]
-        if self.distributed is not None:
-            res = self.distributed.run_bulk(index, ls, rs, op)
-        else:
-            res = self._bulk.run(index.hierarchy, ls, rs, op)
-        out_dtype = (
-            np.int32 if op == INDEX else np.dtype(index.value_dtype)
-        )
-        return jnp.asarray(np.asarray(res).astype(out_dtype, copy=False))
+            routed = ls.shape[0] < self.bulk_crossover or (
+                self.distributed is None and _quantized(index)
+            )
+            if sp is not None:
+                sp.args.update(queries=int(ls.shape[0]),
+                               route="routed" if routed else "bulk")
+            if routed:
+                return self._execute(ls, rs, op)
+            self.batches += 1
+            self.queries_in += ls.shape[0]
+            if self.distributed is not None:
+                res = self.distributed.run_bulk(index, ls, rs, op)
+            else:
+                res = self._bulk.run(index.hierarchy, ls, rs, op)
+            out_dtype = (
+                np.int32 if op == INDEX else np.dtype(index.value_dtype)
+            )
+            return jnp.asarray(
+                np.asarray(res).astype(out_dtype, copy=False))
+        finally:
+            if sp is not None:
+                tr.end(sp)
 
     @property
     def supports_mixed(self) -> bool:
@@ -519,6 +531,8 @@ class QueryEngine:
         # Dedup on (l, r) pairs — the fused launch computes both planes
         # for every query anyway, so value and index requests for the
         # same range share one execution.
+        tr = trace.current()
+        sp = tr.begin("dedup") if tr is not None else None
         uniq, inverse = np.unique(
             np.stack([ls, rs]), axis=1, return_inverse=True
         )
@@ -526,6 +540,8 @@ class QueryEngine:
         k = uls.shape[0]
         self.dedup_saved += m - k
         inverse = inverse.ravel()
+        if tr is not None:
+            tr.end(sp, queries=m, unique=k)
         uv = np.zeros((k,), val_dtype)
         up = np.zeros((k,), np.int32)
         need_val = np.zeros((k,), bool)
@@ -535,6 +551,7 @@ class QueryEngine:
 
         gen = self.generation
         if self.cache.capacity > 0:
+            sp = self._begin_cache_get(tr)
             missing = np.zeros((k,), bool)
             for i in range(k):
                 l, r = int(uls[i]), int(urs[i])
@@ -551,10 +568,11 @@ class QueryEngine:
                     else:
                         up[i] = hit
             miss_idx = np.nonzero(missing)[0]
+            if tr is not None:
+                self._end_cache_get(tr, sp)
         else:
             miss_idx = np.arange(k)
 
-        tr = trace.current()
         if miss_idx.shape[0]:
             h = index.hierarchy
             fused = self.executors[FUSED]
@@ -569,24 +587,32 @@ class QueryEngine:
                     continue
                 self._note_bucket(bucket)
                 sp = tr.begin("execute") if tr is not None else None
+                sub = tr.begin("launch") if tr is not None else None
                 bv, bp = fused.run_mixed(
                     h, jnp.asarray(bucket.ls), jnp.asarray(bucket.rs)
                 )
+                if tr is not None:
+                    tr.end(sub)
+                    sub = tr.begin("fetch")
                 rows = miss_idx[bucket.idxs]
                 uv[rows] = np.asarray(bv)[: bucket.count].astype(
                     val_dtype, copy=False
                 )
                 up[rows] = np.asarray(bp)[: bucket.count]
                 if tr is not None:
+                    tr.end(sub)
                     tr.end(sp, cls=bucket.cls, count=bucket.count,
                            shape=bucket.shape, op="mixed")
             if self.cache.capacity > 0:
+                sp = tr.begin("cache_put") if tr is not None else None
                 for i in miss_idx:
                     l, r = int(uls[i]), int(urs[i])
                     if need_val[i]:
                         self.cache.put(VALUE, gen, l, r, uv[i].item())
                     if need_pos[i]:
                         self.cache.put(INDEX, gen, l, r, int(up[i]))
+                if tr is not None:
+                    tr.end(sp, entries=int(miss_idx.shape[0]))
 
         sp = tr.begin("scatter") if tr is not None else None
         out = uv[inverse], up[inverse]
@@ -595,6 +621,20 @@ class QueryEngine:
         return out
 
     # -- execution --------------------------------------------------------
+    def _begin_cache_get(self, tr):
+        """Open the ``cache_get`` span holding the cache's counters; its
+        end replaces them by their deltas (nothing counts per query)."""
+        if tr is None:
+            return None
+        sp = tr.begin("cache_get")
+        sp.args.update(hits=self.cache.hits, misses=self.cache.misses)
+        return sp
+
+    def _end_cache_get(self, tr, sp) -> None:
+        hits = self.cache.hits - sp.args["hits"]
+        misses = self.cache.misses - sp.args["misses"]
+        tr.end(sp, lookups=hits + misses, hits=hits, misses=misses)
+
     # NOTE: query_mixed above carries a dual-plane variant of this
     # dedup -> LRU -> bucket-execute -> cache-writeback pipeline (its
     # cache entries are per-op, its execution per-(l,r) pair); cache or
@@ -616,17 +656,22 @@ class QueryEngine:
         self.queries_in += m
 
         # -- within-batch dedup -------------------------------------------
+        tr = trace.current()
+        sp = tr.begin("dedup") if tr is not None else None
         uniq, inverse = np.unique(
             np.stack([ls, rs]), axis=1, return_inverse=True
         )
         uls, urs = uniq[0], uniq[1]
         k = uls.shape[0]
         self.dedup_saved += m - k
+        if tr is not None:
+            tr.end(sp, queries=m, unique=k)
         uniq_res = np.empty((k,), out_dtype)
 
         # -- LRU lookup ---------------------------------------------------
         gen = self.generation
         if self.cache.capacity > 0:
+            sp = self._begin_cache_get(tr)
             missing = np.ones((k,), bool)
             for i in range(k):
                 hit = self.cache.get(op, gen, int(uls[i]), int(urs[i]))
@@ -634,11 +679,12 @@ class QueryEngine:
                     uniq_res[i] = hit
                     missing[i] = False
             miss_idx = np.nonzero(missing)[0]
+            if tr is not None:
+                self._end_cache_get(tr, sp)
         else:
             miss_idx = np.arange(k)
 
         # -- plan + execute the misses ------------------------------------
-        tr = trace.current()
         if miss_idx.shape[0]:
             mls, mrs = uls[miss_idx], urs[miss_idx]
             if self.distributed is not None:
@@ -656,23 +702,31 @@ class QueryEngine:
                         continue
                     self._note_bucket(bucket)
                     sp = tr.begin("execute") if tr is not None else None
+                    sub = tr.begin("launch") if tr is not None else None
                     res = self.executors[bucket.cls].run(
                         h, jnp.asarray(bucket.ls), jnp.asarray(bucket.rs),
                         op,
                     )
+                    if tr is not None:
+                        tr.end(sub)
+                        sub = tr.begin("fetch")
                     res = np.asarray(res)[: bucket.count].astype(
                         out_dtype, copy=False
                     )
                     if tr is not None:
+                        tr.end(sub)
                         tr.end(sp, cls=bucket.cls, count=bucket.count,
                                shape=bucket.shape, op=op)
                     uniq_res[miss_idx[bucket.idxs]] = res
             if self.cache.capacity > 0:
+                sp = tr.begin("cache_put") if tr is not None else None
                 for i in miss_idx:
                     self.cache.put(
                         op, gen, int(uls[i]), int(urs[i]),
                         uniq_res[i].item(),
                     )
+                if tr is not None:
+                    tr.end(sp, entries=int(miss_idx.shape[0]))
 
         sp = tr.begin("scatter") if tr is not None else None
         out = jnp.asarray(uniq_res[inverse.ravel()])

@@ -28,7 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hierarchy import Hierarchy
-from repro.kernels.profiling import timed_dispatch
 from repro.obs import trace
 
 __all__ = [
@@ -47,9 +46,6 @@ MIXED = "mixed"
 class _ExecutorBase:
     """Shared bookkeeping: the (op, shape) -> callable table and stats."""
 
-    # dispatch-site label for the launch registry's opt-in wall timer
-    label = "executor"
-
     def __init__(self):
         self._compiled: Dict[Tuple[str, int], Callable] = {}
         self.calls = 0
@@ -67,7 +63,7 @@ class _ExecutorBase:
         self.calls += 1
         self.queries += int(ls.shape[0])
         fn = self._bind(op, int(ls.shape[0]), lambda: self._make(h, op))
-        return timed_dispatch(f"{self.label}:{op}", fn, h, ls, rs)
+        return fn(h, ls, rs)
 
     def stats(self) -> dict:
         return {
@@ -82,8 +78,6 @@ class _ExecutorBase:
 
 class ShortSpanExecutor(_ExecutorBase):
     """Two-chunk level-0 scan; never touches the hierarchy."""
-
-    label = "short"
 
     def __init__(self, backend: str, interpret: Optional[bool] = None):
         super().__init__()
@@ -108,8 +102,6 @@ class ShortSpanExecutor(_ExecutorBase):
 
 class MidSpanExecutor(_ExecutorBase):
     """The standard full hierarchy walk (the previous monolithic path)."""
-
-    label = "mid"
 
     def __init__(self, backend: str, interpret: Optional[bool] = None):
         super().__init__()
@@ -140,8 +132,6 @@ class LongSpanExecutor(_ExecutorBase):
     build), so it must be re-derived when the index mutates: the engine
     calls :meth:`invalidate` on every attach.
     """
-
-    label = "long"
 
     def __init__(self):
         super().__init__()
@@ -174,8 +164,6 @@ class FusedExecutor(_ExecutorBase):
     ops avoids a second dispatch.
     """
 
-    label = "fused"
-
     def __init__(self, interpret: Optional[bool] = None):
         super().__init__()
         self.interpret = interpret
@@ -202,7 +190,7 @@ class FusedExecutor(_ExecutorBase):
         self.queries += int(ls.shape[0])
         fn = self._bind(MIXED, int(ls.shape[0]),
                         lambda: self._make(h, MIXED))
-        return timed_dispatch(f"{self.label}:{MIXED}", fn, h, ls, rs)
+        return fn(h, ls, rs)
 
 
 def _next_pow2(x: int) -> int:
@@ -231,8 +219,6 @@ class BulkExecutor(_ExecutorBase):
     buckets amortize it further; the kernel path has no per-dispatch
     setup worth splitting for.
     """
-
-    label = "bulk"
 
     def __init__(
         self,
@@ -294,14 +280,16 @@ class BulkExecutor(_ExecutorBase):
             self.calls += 1
             fn = self._bind(op, k, lambda: self._make(h, op))
             sp = tr.begin("execute") if tr is not None else None
-            res = timed_dispatch(
-                f"{self.label}:{op}", fn, h, jnp.asarray(bl),
-                jnp.asarray(br),
-            )
+            sub = tr.begin("launch") if tr is not None else None
+            res = fn(h, jnp.asarray(bl), jnp.asarray(br))
+            if tr is not None:
+                tr.end(sub)
+                sub = tr.begin("fetch")
             sorted_res[start:stop] = np.asarray(res)[:count].astype(
                 out_dtype, copy=False
             )
             if tr is not None:
+                tr.end(sub)
                 tr.end(sp, cls="bulk", count=count, shape=k, op=op)
 
         sp = tr.begin("scatter") if tr is not None else None

@@ -9,6 +9,7 @@ must export engine cache/span-class/padding series.
 
 from __future__ import annotations
 
+import gc
 import json
 import threading
 
@@ -20,7 +21,6 @@ from repro.kernels.profiling import (
     count_launches,
     launch_registry,
     operand_bytes,
-    timed_dispatch,
 )
 from repro.obs import trace
 from repro.obs.metrics import Counter, Gauge, Histogram, Metrics
@@ -29,6 +29,10 @@ from repro.qe import QueryService
 from repro.qe.cache import ResultCache
 from repro.qe.executors import INDEX, VALUE
 from repro.serving import ServingTier
+
+
+# spans the runtime hooks add while a tracer is installed
+RUNTIME = {"gc", "compile"}
 
 
 class FakeClock:
@@ -178,7 +182,9 @@ class TestTracer:
             assert got is tr and trace.current() is tr
             trace.instant("inside")
         assert trace.current() is None
-        assert [s.name for s in tr.spans()] == ["inside"]
+        # a collection may land in the block: its span is the hook's
+        assert [s.name for s in tr.spans() if s.name not in RUNTIME] == [
+            "inside"]
 
 
 # ---------------------------------------------------------------------------
@@ -388,32 +394,245 @@ class TestLaunchRegistry:
         dump = reg.as_dict()
         assert dump["counts"] == reg.counts
         assert len(dump["launches"]) == 2
-        assert "timings_s" not in dump      # timing was off
 
-    def test_timed_dispatch_records_only_when_enabled(self):
+
+# ---------------------------------------------------------------------------
+# Program spans: an engine batch, a build, runtime pauses, the mirror
+# ---------------------------------------------------------------------------
+N_SPANS = 4096
+
+
+def _children(spans):
+    """``{parent id: [child spans]}`` without the runtime hooks' spans."""
+    kids = {}
+    for s in spans:
+        if s.name not in RUNTIME:
+            kids.setdefault(s.parent_id, []).append(s)
+    return kids
+
+
+def _engine(**kw):
+    rng = np.random.default_rng(11)
+    x = rng.random(N_SPANS).astype(np.float32)
+    engine = RMQ.build(x, c=16, t=4, backend="jax").engine(**kw)
+    ls = rng.integers(0, N_SPANS - 100, 300).astype(np.int32)
+    rs = (ls + rng.integers(0, 100, 300)).astype(np.int32)
+    # repeated pairs, so dedup has work
+    return engine, np.concatenate([ls, ls[:50]]), np.concatenate(
+        [rs, rs[:50]])
+
+
+class TestProgramSpans:
+    def test_engine_batch_span_tree(self):
+        engine, ls, rs = _engine(cache_size=64, bulk_crossover=1 << 20)
+        engine.query_bulk(ls, rs)          # compiles and fills the cache
+        hits, misses = engine.cache.hits, engine.cache.misses
+        tr = Tracer(clock=FakeClock())
+        with use_tracer(tr):
+            engine.query_bulk(ls, rs)
+        hits = engine.cache.hits - hits
+        misses = engine.cache.misses - misses
+        kids = _children(tr.spans())
+        (root,) = kids[None]
+        assert root.name == "query_bulk"
+        assert root.args == {"queries": 350, "route": "routed"}
+        names = [s.name for s in kids[root.span_id]]
+        assert names[:3] == ["dedup", "cache_get", "plan"]
+        assert names[-2:] == ["cache_put", "scatter"]
+        assert set(names[3:-2]) == {"execute"}
+        for ex in kids[root.span_id][3:-2]:
+            assert [c.name for c in kids[ex.span_id]] == ["launch", "fetch"]
+        dedup, get = kids[root.span_id][:2]
+        unique = np.unique(np.stack([ls, rs]), axis=1).shape[1]
+        assert dedup.args == {"queries": 350, "unique": unique}
+        assert get.args == {"lookups": hits + misses, "hits": hits,
+                            "misses": misses}
+        assert get.args["lookups"] == unique and 0 < hits < unique
+        put = kids[root.span_id][-2]
+        assert put.args == {"entries": misses}
+
+    def test_bulk_route_spans_nest_under_the_batch(self):
+        engine, ls, rs = _engine(cache_size=64, bulk_crossover=16)
+        engine.query_bulk(ls, rs)
+        tr = Tracer(clock=FakeClock())
+        with use_tracer(tr):
+            engine.query_bulk(ls, rs)
+        kids = _children(tr.spans())
+        (root,) = kids[None]
+        assert root.args == {"queries": 350, "route": "bulk"}
+        assert [s.name for s in kids[root.span_id]] == [
+            "plan", "execute", "scatter"]
+        ex = kids[root.span_id][1]
+        assert [c.name for c in kids[ex.span_id]] == ["launch", "fetch"]
+
+    def test_build_span_tree(self):
+        x = np.random.default_rng(5).random(3000).astype(np.float32)
+        tr = Tracer(clock=FakeClock())
+        with use_tracer(tr):
+            RMQ.build(x, c=8, t=4, backend="jax")
+        kids = _children(tr.spans())
+        (root,) = kids[None]
+        assert root.name == "build"
+        assert root.args == {"n": 3000, "backend": "jax"}
+        plan, dispatch = kids[root.span_id]
+        assert (plan.name, dispatch.name) == ("build_plan", "build_dispatch")
+        assert dispatch.args == {"backend": "jax"}
+
+    def test_gc_span_only_while_installed(self):
+        tr = Tracer()
+        with use_tracer(tr):
+            assert trace._on_gc in gc.callbacks
+            gc.collect()
+        full = [s for s in tr.spans()
+                if s.name == "gc" and s.args["generation"] == 2]
+        assert full and full[-1].end >= full[-1].start
+        assert full[-1].args["collected"] >= 0
+        assert trace._on_gc not in gc.callbacks
+        count = len(tr.spans())
+        gc.collect()
+        assert len(tr.spans()) == count
+
+    def test_compile_span_only_while_installed(self):
+        import jax
         import jax.numpy as jnp
 
-        calls = []
+        f = jax.jit(lambda a: a * 3 + 1)
+        a, b = jnp.zeros((7, 3)), jnp.zeros((9, 3))
+        tr = Tracer(clock=FakeClock())
+        with use_tracer(tr):
+            outer = tr.begin("launch")
+            f(a).block_until_ready()
+            tr.end(outer)
+        comp = [s for s in tr.spans() if s.name == "compile"]
+        assert {s.args["stage"] for s in comp
+                if "lambda" in s.args["fun_name"]} == {
+            "jaxpr_trace", "to_mlir", "backend_compile"}
+        assert all(s.parent_id == outer.span_id for s in comp)
+        # mapped onto the tracer's clock: over by the time it is recorded
+        assert all(s.start <= s.end <= 100.0 for s in comp)
+        count = len(tr.spans())
+        f(b).block_until_ready()          # a fresh shape, tracer removed
+        assert len(tr.spans()) == count
 
-        def fn(a, b):
-            calls.append(1)
-            return jnp.add(a, b)
+    def test_mirror_exits_truncated_annotations_innermost_first(
+            self, monkeypatch):
+        log = []
 
-        # no registry: pure passthrough
-        out = timed_dispatch("k", fn, 1, 2)
-        assert int(out) == 3
-        # registry without timing: still passthrough
-        with launch_registry() as reg:
-            timed_dispatch("k", fn, 1, 2)
-        assert reg.timings == {}
-        # timing on: wall-clock recorded under the dispatch label
-        with launch_registry(timing=True) as reg:
-            timed_dispatch("k", fn, 1, 2)
-            timed_dispatch("k", fn, 3, 4)
-        assert len(reg.timings["k"]) == 2
-        assert all(t >= 0.0 for t in reg.timings["k"])
-        assert len(calls) == 4
-        assert "timings_s" in reg.as_dict()
+        class Annotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                log.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", self.name))
+
+        trace._install_hooks()
+        monkeypatch.setattr(trace, "_annotation", Annotation)
+        tr = Tracer(clock=FakeClock())
+        with use_tracer(tr):
+            outer = tr.begin("outer")
+            tr.begin("mid")
+            tr.begin("inner")           # both left open
+            tr.end(outer)
+            tr.record("queue", 99.0, 100.0)     # after the fact: no mirror
+        assert [e for e in log if e[1] != "repro.gc"] == [
+            ("enter", "repro.outer"), ("enter", "repro.mid"),
+            ("enter", "repro.inner"), ("exit", "repro.inner"),
+            ("exit", "repro.mid"), ("exit", "repro.outer")]
+        log.clear()
+        tr.end(tr.begin("after"))       # removed: no mirror
+        assert log == []
+
+    def test_gc_inside_the_tracer_lock_closes_its_span(self):
+        # a collection can start while this thread holds the buffer's
+        # lock; its span must still be recorded, not deadlock
+        tr = Tracer()
+        done = []
+
+        def collect_under_lock():
+            with tr._lock:
+                gc.collect()
+            done.append(True)
+
+        with use_tracer(tr):
+            t = threading.Thread(target=collect_under_lock, daemon=True)
+            t.start()
+            t.join(timeout=30)
+        assert not t.is_alive() and done
+        assert any(s.name == "gc" and s.args["generation"] == 2
+                   for s in tr.spans())
+
+    def test_gc_spans_under_thread_stress(self):
+        # collections land between any two bytecodes, also while a thread
+        # holds the tracer's lock: it must not deadlock or lose spans
+        import sys
+
+        tr = Tracer(capacity=1 << 20)
+        threads, per = 16, 300
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            with use_tracer(tr):
+                def work():
+                    for _ in range(per):
+                        with tr.span("outer"):
+                            junk = [[i] for i in range(50)]  # noqa: F841
+                            tr.end(tr.begin("inner"))
+
+                ts = [threading.Thread(target=work) for _ in range(threads)]
+                for t in ts:
+                    t.start()
+                for t in ts:
+                    t.join(timeout=60)
+                assert not any(t.is_alive() for t in ts)
+        finally:
+            sys.setswitchinterval(old)
+        spans = tr.spans()
+        by_id = {s.span_id: s for s in spans}
+        assert sum(s.name == "outer" for s in spans) == threads * per
+        assert sum(s.name == "inner" for s in spans) == threads * per
+        gcs = [s for s in spans if s.name == "gc"]
+        assert gcs
+        for s in gcs:
+            assert s.end >= s.start
+            parent = by_id.get(s.parent_id)
+            assert parent is None or parent.thread == s.thread
+
+    def test_profiler_mirror_holds_program_spans(self, tmp_path):
+        import jax
+        from jax.profiler import ProfileData
+
+        engine, ls, rs = _engine(cache_size=64, bulk_crossover=1 << 20)
+        engine.query_bulk(ls, rs)
+        tr = Tracer()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            with use_tracer(tr):
+                engine.query_bulk(ls, rs)
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = tmp_path.rglob("*.xplane.pb")
+        got = {}
+        for plane in ProfileData.from_file(str(path)).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("repro."):
+                        got.setdefault(ev.name, []).append(
+                            (ev.end_ns - ev.start_ns) / 1e9)
+        want = {}
+        for s in tr.spans():
+            want.setdefault("repro." + s.name, []).append(s.duration)
+        for name in ("query_bulk", "dedup", "cache_get", "plan", "execute",
+                     "launch", "fetch", "cache_put", "scatter"):
+            name = "repro." + name
+            assert len(got[name]) == len(want[name]), name
+            assert sum(got[name]) == pytest.approx(
+                sum(want[name]), rel=0.05, abs=1e-3), name
+        assert "repro.compile" not in got
 
 
 # ---------------------------------------------------------------------------

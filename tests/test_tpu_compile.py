@@ -75,16 +75,16 @@ def _lower(name, sharding, n, pos, c=128):
             h, ls, _sds(sharding, (M,), jnp.float32), False)
     if name == "rmq_short":
         from repro.kernels.rmq_short import ops
-        return ops._run.lower(h.base, ls, ls, plan, QB, pos, False)
+        return ops._run_rmq_short.lower(h.base, ls, ls, plan, QB, pos, False)
     if name == "rmq_scan":
         from repro.kernels.rmq_scan import ops
-        fn = ops._run
+        fn = ops._run_rmq_scan
     elif name == "rmq_fused":
         from repro.kernels.rmq_fused import ops
-        fn = ops._run_kernel
+        fn = ops._run_rmq_fused
     else:
         from repro.kernels.rmq_bulk import ops
-        fn = ops._run_kernel
+        fn = ops._run_rmq_bulk
     return fn.lower(h.base, h.upper, h.upper_pos, ls, ls, plan, QB, pos,
                     False)
 
