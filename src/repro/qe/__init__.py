@@ -18,9 +18,11 @@ that observation into an execution layer:
   whole span mix (and both value/index output planes) in ONE
   ``kernels/rmq_fused`` launch per bucket, the planner degrading to a
   single ``FUSED`` class;
-* :class:`ResultCache` — within-batch duplicate dedup plus an LRU keyed
-  by ``(op, index generation, l, r)``; ``RMQ.update``/``append`` bump
-  the generation so streaming mutations invalidate correctly;
+* :class:`ResultCache` — an exact LRU keyed by ``(op, index generation,
+  l, r)``, stored as arrays and read and written a batch per call
+  (``get_many``/``put_many``) on one packed int64 key per query, the key
+  the engine's within-batch dedup also sorts; ``RMQ.update``/``append``
+  bump the generation so streaming mutations invalidate correctly;
 * :class:`QueryEngine` — ties the three together for one index
   (``RMQ.engine()`` on the facade); any
   :class:`repro.core.protocol.RMQIndex` attaches, including the

@@ -9,8 +9,14 @@ through persistent jitted callables (see :mod:`repro.qe.executors`).
 
 Execution pipeline per batch::
 
-    validate -> dedup (np.unique) -> LRU lookup -> planner buckets
-             -> per-class executors -> scatter-back -> LRU insert
+    validate -> pack keys ((l << 31) | r, one int64 per query)
+             -> dedup (np.unique on the keys)
+             -> LRU lookup (ResultCache.get_many: one call per batch)
+             -> planner buckets -> per-class executors
+             -> LRU insert (ResultCache.put_many) -> scatter-back
+
+Every host step is an array operation over the batch; none runs Python
+per query.
 
 For single-hierarchy indices the miss classes are short / mid / long span
 buckets; for distributed indices the planner is replaced by the
@@ -60,7 +66,15 @@ from repro.kernels.common import check_query_vmem, resolve_interpret
 from repro.kernels.profiling import record_config
 from repro.obs import trace
 from repro.obs.metrics import SIZE_BUCKETS, Metrics
-from repro.qe.cache import ResultCache
+from repro.qe.cache import (
+    OP_BITS,
+    ResultCache,
+    entry_keys,
+    from_bits,
+    pack_keys,
+    to_bits,
+    unpack_keys,
+)
 from repro.qe.distributed import DistributedExecutor
 from repro.qe.executors import (
     INDEX,
@@ -533,13 +547,9 @@ class QueryEngine:
         # same range share one execution.
         tr = trace.current()
         sp = tr.begin("dedup") if tr is not None else None
-        uniq, inverse = np.unique(
-            np.stack([ls, rs]), axis=1, return_inverse=True
-        )
-        uls, urs = uniq[0], uniq[1]
-        k = uls.shape[0]
+        ukeys, inverse = np.unique(pack_keys(ls, rs), return_inverse=True)
+        k = ukeys.shape[0]
         self.dedup_saved += m - k
-        inverse = inverse.ravel()
         if tr is not None:
             tr.end(sp, queries=m, unique=k)
         uv = np.zeros((k,), val_dtype)
@@ -549,25 +559,25 @@ class QueryEngine:
         need_val[inverse[~is_index]] = True
         need_pos[inverse[is_index]] = True
 
+        # one cache entry per (op, pair) needed: pair i's value entry,
+        # then its index entry, in pair order.  Flat index e = 2 * pair
+        # + op bit (OP_BITS: value 0, index 1), so the entry keys
+        # 2 * key + bit ascend with e.
+        need = np.stack([need_val, need_pos], axis=1)
         gen = self.generation
         if self.cache.capacity > 0:
             sp = self._begin_cache_get(tr)
+            e = np.flatnonzero(need)
+            rows, is_pos = e >> 1, (e & 1).astype(bool)
+            bits, hit = self.cache.get_many(
+                gen, entry_keys(ukeys[rows], e & 1))
+            sel = hit & ~is_pos
+            uv[rows[sel]] = from_bits(bits[sel], val_dtype)
+            sel = hit & is_pos
+            up[rows[sel]] = from_bits(bits[sel], np.int32)
             missing = np.zeros((k,), bool)
-            for i in range(k):
-                l, r = int(uls[i]), int(urs[i])
-                if need_val[i]:
-                    hit = self.cache.get(VALUE, gen, l, r)
-                    if hit is None:
-                        missing[i] = True
-                    else:
-                        uv[i] = hit
-                if need_pos[i]:
-                    hit = self.cache.get(INDEX, gen, l, r)
-                    if hit is None:
-                        missing[i] = True
-                    else:
-                        up[i] = hit
-            miss_idx = np.nonzero(missing)[0]
+            missing[rows[~hit]] = True
+            miss_idx = np.flatnonzero(missing)
             if tr is not None:
                 self._end_cache_get(tr, sp)
         else:
@@ -576,7 +586,7 @@ class QueryEngine:
         if miss_idx.shape[0]:
             h = index.hierarchy
             fused = self.executors[FUSED]
-            mls, mrs = uls[miss_idx], urs[miss_idx]
+            mls, mrs = unpack_keys(ukeys[miss_idx])
             sp = tr.begin("plan") if tr is not None else None
             buckets = self.planner.plan(mls, mrs)
             if tr is not None:
@@ -605,12 +615,12 @@ class QueryEngine:
                            shape=bucket.shape, op="mixed")
             if self.cache.capacity > 0:
                 sp = tr.begin("cache_put") if tr is not None else None
-                for i in miss_idx:
-                    l, r = int(uls[i]), int(urs[i])
-                    if need_val[i]:
-                        self.cache.put(VALUE, gen, l, r, uv[i].item())
-                    if need_pos[i]:
-                        self.cache.put(INDEX, gen, l, r, int(up[i]))
+                e = np.flatnonzero(need[miss_idx])
+                rows = miss_idx[e >> 1]
+                bits = np.where(e & 1, to_bits(up[rows]),
+                                to_bits(uv[rows]))
+                self.cache.put_many(gen, entry_keys(ukeys[rows], e & 1),
+                                    bits)
                 if tr is not None:
                     tr.end(sp, entries=int(miss_idx.shape[0]))
 
@@ -658,11 +668,8 @@ class QueryEngine:
         # -- within-batch dedup -------------------------------------------
         tr = trace.current()
         sp = tr.begin("dedup") if tr is not None else None
-        uniq, inverse = np.unique(
-            np.stack([ls, rs]), axis=1, return_inverse=True
-        )
-        uls, urs = uniq[0], uniq[1]
-        k = uls.shape[0]
+        ukeys, inverse = np.unique(pack_keys(ls, rs), return_inverse=True)
+        k = ukeys.shape[0]
         self.dedup_saved += m - k
         if tr is not None:
             tr.end(sp, queries=m, unique=k)
@@ -672,13 +679,10 @@ class QueryEngine:
         gen = self.generation
         if self.cache.capacity > 0:
             sp = self._begin_cache_get(tr)
-            missing = np.ones((k,), bool)
-            for i in range(k):
-                hit = self.cache.get(op, gen, int(uls[i]), int(urs[i]))
-                if hit is not None:
-                    uniq_res[i] = hit
-                    missing[i] = False
-            miss_idx = np.nonzero(missing)[0]
+            ekeys = entry_keys(ukeys, OP_BITS[op])
+            vals, hit = self.cache.get_many(gen, ekeys, out_dtype)
+            uniq_res[hit] = vals[hit]
+            miss_idx = np.flatnonzero(~hit)
             if tr is not None:
                 self._end_cache_get(tr, sp)
         else:
@@ -686,7 +690,7 @@ class QueryEngine:
 
         # -- plan + execute the misses ------------------------------------
         if miss_idx.shape[0]:
-            mls, mrs = uls[miss_idx], urs[miss_idx]
+            mls, mrs = unpack_keys(ukeys[miss_idx])
             if self.distributed is not None:
                 res = self.distributed.run(index, mls, mrs, op)
                 uniq_res[miss_idx] = res.astype(out_dtype, copy=False)
@@ -720,16 +724,13 @@ class QueryEngine:
                     uniq_res[miss_idx[bucket.idxs]] = res
             if self.cache.capacity > 0:
                 sp = tr.begin("cache_put") if tr is not None else None
-                for i in miss_idx:
-                    self.cache.put(
-                        op, gen, int(uls[i]), int(urs[i]),
-                        uniq_res[i].item(),
-                    )
+                self.cache.put_many(gen, ekeys[miss_idx],
+                                    uniq_res[miss_idx])
                 if tr is not None:
                     tr.end(sp, entries=int(miss_idx.shape[0]))
 
         sp = tr.begin("scatter") if tr is not None else None
-        out = jnp.asarray(uniq_res[inverse.ravel()])
+        out = jnp.asarray(uniq_res[inverse])
         if tr is not None:
             tr.end(sp, queries=m, unique=k, op=op)
         return out

@@ -16,7 +16,7 @@ from _hypothesis_compat import given, settings, st
 from repro.core.api import RMQ
 from repro.core.query import rmq_index_batch, rmq_value_batch
 from repro.qe import LONG, MID, SHORT, QueryEngine, QueryPlanner, QueryService
-from repro.qe.cache import ResultCache
+from repro.qe.cache import ResultCache, pack_keys, unpack_keys
 
 
 def _mixed_queries(rng, n, c, m):
@@ -351,6 +351,100 @@ class TestCache:
         # value and index results are cached under distinct ops
         engine.query_index(ls[:1], rs[:1])
         assert np.asarray(engine.query(ls[:1], rs[:1]))[0] == out[0]
+
+
+# ---------------------------------------------------------------------------
+# packed query keys: dedup order and cached values
+# ---------------------------------------------------------------------------
+def _bits(a):
+    a = np.asarray(a)
+    return a.view(f"u{a.dtype.itemsize}")
+
+
+def _signed_zero_values(rng, n):
+    """Values with ties, +0.0 and -0.0: cached answers must keep bits."""
+    x = rng.random(n).astype(np.float32)
+    x[rng.integers(0, n, n // 16)] = 0.0
+    x[rng.integers(0, n, n // 16)] = -0.0
+    return x
+
+
+class TestPackedKeys:
+    def test_dedup_matches_axis1_unique(self):
+        """np.unique on (l << 31) | r gives the same unique order and
+        inverse as np.unique over the (l, r) columns, edge bounds too."""
+        rng = np.random.default_rng(11)
+        top = 2**31 - 1
+        ls = rng.integers(0, top, 4000)
+        rs = np.minimum(ls + rng.integers(0, 1 << 20, 4000), top)
+        ls = np.concatenate([ls, ls[:500], [0, 0, top, 0, top, 0]])
+        rs = np.concatenate([rs, rs[:500], [0, top, top, top, top, 1]])
+        ls, rs = ls.astype(np.int32), rs.astype(np.int32)
+        perm = rng.permutation(ls.shape[0])
+        ls, rs = ls[perm], rs[perm]
+        uniq, inverse = np.unique(np.stack([ls, rs]), axis=1,
+                                  return_inverse=True)
+        ukeys, inv = np.unique(pack_keys(ls, rs), return_inverse=True)
+        uls, urs = unpack_keys(ukeys)
+        np.testing.assert_array_equal(uls, uniq[0])
+        np.testing.assert_array_equal(urs, uniq[1])
+        np.testing.assert_array_equal(inv, inverse.ravel())
+        assert uls.dtype == urs.dtype == np.int32
+
+    @pytest.mark.parametrize("layout", ["float32", "bfloat16_summaries",
+                                        "index"])
+    def test_cache_hits_bit_identical(self, layout):
+        """A repeated batch is served from the cache bit for bit."""
+        rng = np.random.default_rng(12)
+        n = 20_000
+        x = _signed_zero_values(rng, n)
+        kw = {"summary_dtype": "bfloat16"} \
+            if layout == "bfloat16_summaries" else {}
+        rmq = RMQ.build(x, c=64, t=4, with_positions=True, backend="jax",
+                        **kw)
+        engine = rmq.engine()
+        ls, rs = _mixed_queries(rng, n, 64, 600)
+        ls, rs = np.concatenate([ls, ls[:100]]), np.concatenate([rs, rs[:100]])
+        run = engine.query_index if layout == "index" else engine.query
+        oracle = rmq_index_batch if layout == "index" else rmq_value_batch
+        executed = np.asarray(run(ls, rs))
+        unique = np.unique(pack_keys(ls, rs)).shape[0]
+        h0, m0 = engine.cache.hits, engine.cache.misses
+        cached = np.asarray(run(ls, rs))
+        assert engine.cache.hits - h0 == unique
+        assert engine.cache.misses == m0
+        assert cached.dtype == executed.dtype
+        np.testing.assert_array_equal(_bits(cached), _bits(executed))
+        want = oracle(rmq.hierarchy, jnp.asarray(ls), jnp.asarray(rs))
+        np.testing.assert_array_equal(_bits(executed), _bits(want))
+
+    def test_query_mixed_cache_hits_bit_identical(self):
+        rng = np.random.default_rng(13)
+        n = 3000
+        x = _signed_zero_values(rng, n)
+        rmq = RMQ.build(x, c=8, t=8, with_positions=True, backend="fused")
+        engine = rmq.engine()
+        assert engine.supports_mixed
+        ls, rs = _mixed_queries(rng, n, 8, 300)
+        is_index = rng.random(ls.shape[0]) < 0.5
+        v1, p1 = engine.query_mixed(ls, rs, is_index)
+        keys = pack_keys(ls, rs)
+        needed = (np.unique(keys[~is_index]).shape[0]
+                  + np.unique(keys[is_index]).shape[0])
+        h0, m0 = engine.cache.hits, engine.cache.misses
+        v2, p2 = engine.query_mixed(ls, rs, is_index)
+        assert engine.cache.hits - h0 == needed
+        assert engine.cache.misses == m0
+        np.testing.assert_array_equal(_bits(v2[~is_index]),
+                                      _bits(v1[~is_index]))
+        np.testing.assert_array_equal(p2[is_index], p1[is_index])
+        lsj, rsj = jnp.asarray(ls), jnp.asarray(rs)
+        np.testing.assert_array_equal(
+            v1[~is_index],
+            np.asarray(rmq_value_batch(rmq.hierarchy, lsj, rsj))[~is_index])
+        np.testing.assert_array_equal(
+            p1[is_index],
+            np.asarray(rmq_index_batch(rmq.hierarchy, lsj, rsj))[is_index])
 
 
 # ---------------------------------------------------------------------------
