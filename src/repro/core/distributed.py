@@ -12,10 +12,16 @@ segment using the paper's algorithm, and a single ``pmin`` over the segment
 axis combines per-segment minima.
 
 Communication cost per batch: one all-reduce(min) of ``batch_local``
-floats over the segment axis — independent of n.  Per-device memory
-scales down linearly with the number of segments, lifting the paper's
-single-device ceiling up to this implementation's own int32 index-space
-bound (total capacity < 2^31, enforced at build).
+floats over the segment axis — independent of n — and, for index
+queries, two more of int32 segment ids and segment-local positions
+(:func:`_combine`).  Per-device memory scales down linearly with the
+number of segments, so the index is bounded by the mesh's memory, not
+one device's: past 2^31 global coordinates run in int64 under x64
+while each segment's own coordinates stay int32.
+
+``build`` takes the input already sharded over the segment axis (a
+``jax.Array`` with that sharding, or host data, which goes to each
+device as its own slice): no device ever holds more than its segment.
 
 ``DistributedRMQ`` implements the full
 :class:`repro.core.protocol.MutableRMQIndex` protocol:
@@ -42,12 +48,12 @@ The same code path runs on the production meshes via ``shard_map`` and on
 a single CPU device (1×1 mesh) for tests.  Query/position arithmetic runs
 in a *coordinate dtype* derived from the total capacity: int32 below
 2**31 (bit-identical to the historical stack), int64 past it **when jax
-x64 mode is on** — segment starts, globalized positions and combine
-sentinels all widen together, so the paper's index-space ceiling lifts
-with the memory ceiling.  Without x64, ``build`` refuses total
-capacities at or past 2**31 (the same loud
-``repro.core.protocol.check_capacity_limit`` contract the batched engine
-enforces at ``attach``) rather than letting bounds wrap silently.
+x64 mode is on** — segment starts and globalized positions widen
+together, so the paper's index-space ceiling lifts with the memory
+ceiling; each segment's own coordinates stay int32.  Without x64,
+``build`` (and the engine's ``attach``) refuse total capacities at or
+past 2**31 (the shared ``repro.core.protocol.check_capacity_limit``
+guard) rather than letting bounds wrap silently.
 
 Compact layouts ride along: ``build(..., packed_pos=True)`` stores each
 segment's position plane as log2(c)-bit packed words and
@@ -64,6 +70,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -115,6 +122,39 @@ def _build_fn(mesh: Mesh, seg: str, plan: HierarchyPlan,
     return jax.jit(build_local)
 
 
+def _segment_sharded(x, sharding: NamedSharding, length: int) -> jax.Array:
+    """``x`` as a ``(length,)`` array sharded over the segment axis,
+    padded with +inf, with no device ever holding more than its segment.
+
+    A ``jax.Array`` laid out so already is used as it is; another is
+    padded and resharded by one jitted program with a sharded output.
+    Host data goes to each device as that device's own slice.
+    """
+    if isinstance(x, jax.Array):
+        if x.dtype not in px.VALUE_DTYPES:
+            x = x.astype(jnp.float32)
+        if x.shape[0] == length and x.sharding.is_equivalent_to(sharding, 1):
+            return x
+        pad = length - x.shape[0]
+        return jax.jit(
+            lambda v: jnp.pad(v, (0, pad), constant_values=jnp.inf),
+            out_shardings=sharding,
+        )(x)
+    dtype = jax.dtypes.canonicalize_dtype(x.dtype)
+    if dtype not in px.VALUE_DTYPES:
+        dtype = np.dtype(np.float32)
+    n = x.shape[0]
+
+    def piece(index):
+        lo, hi, _ = index[0].indices(length)
+        out = np.full(hi - lo, np.inf, dtype)
+        live = x[lo:min(hi, n)]
+        out[: live.shape[0]] = live
+        return out
+
+    return jax.make_array_from_callback((length,), sharding, piece)
+
+
 def _need_pos_plane(plan: HierarchyPlan, track: bool) -> bool:
     """Whether the sharded walk must carry the position plane.
 
@@ -163,8 +203,8 @@ def _allreduce_query_fn(mesh: Mesh, seg: str, qaxes: Tuple[str, ...],
     n_local = plan.capacity
     # Coordinate dtype of the GLOBAL index space: int64 past 2**31 under
     # x64, int32 (the historical arithmetic, bit-identical) below.
-    coord = pos_dtype_for(n_local * mesh.shape[seg], strict=False)
-    ident = jnp.iinfo(coord).max
+    nseg = mesh.shape[seg]
+    coord = pos_dtype_for(n_local * nseg, strict=False)
     lcoord = pos_dtype_for(n_local, strict=False)
     need_pos = _need_pos_plane(plan, track)
     qspec = P(qaxes)
@@ -183,7 +223,7 @@ def _allreduce_query_fn(mesh: Mesh, seg: str, qaxes: Tuple[str, ...],
         check_vma=False,
     )
     def go(base_l, upper_l, pos_l, ls_l, rs_l):
-        seg_idx = jax.lax.axis_index(seg)
+        seg_idx = jax.lax.axis_index(seg).astype(jnp.int32)
         # Widen BEFORE the multiply: seg_idx * n_local wraps int32 past
         # 2**31 even when every operand fits individually.
         seg_start = seg_idx.astype(coord) * n_local
@@ -200,17 +240,34 @@ def _allreduce_query_fn(mesh: Mesh, seg: str, qaxes: Tuple[str, ...],
         )
         inf = jnp.array(jnp.inf, dtype=m.dtype)
         m = jnp.where(nonempty, m, inf)
+        mins, win, p = _combine(m, p, nonempty, seg_idx, seg, nseg, track)
         if track:
-            p = jnp.where(nonempty, p.astype(coord) + seg_start, ident)
-            # Combine (value, pos) lexicographically across segments so
-            # ties stay leftmost: min on value, then min pos among argmin.
-            mins = jax.lax.pmin(m, seg)
-            p = jnp.where(m == mins, p, ident)
-            p = jax.lax.pmin(p, seg)
-            return mins, p
-        return jax.lax.pmin(m, seg), jnp.zeros_like(ls_l)
+            return mins, win.astype(coord) * n_local + p.astype(coord)
+        return mins, jnp.zeros_like(ls_l)
 
     return jax.jit(go)
+
+
+def _combine(m, p, nonempty, seg_idx, seg: str, nseg: int, track: bool):
+    """Combine per-segment ``(value, local position)`` candidates.
+
+    Returns the minimum and, when ``track``, the leftmost segment that
+    holds it and that segment's leftmost local position.  Segments are
+    ordered by their global starts, so (segment, local position) is the
+    leftmost global position.  The collectives carry values, segment ids
+    and segment-local positions, never global coordinates (int32 unless
+    one segment passes 2^31); the caller globalizes.  The scope names the
+    all-reduces in device traces.
+    """
+    with jax.named_scope("rmq_pmin"):
+        mins = jax.lax.pmin(m, seg)
+        if not track:
+            return mins, None, None
+        holds = nonempty & (m == mins)
+        win = jax.lax.pmin(jnp.where(holds, seg_idx, nseg), seg)
+        big = jnp.array(jnp.iinfo(p.dtype).max, p.dtype)
+        p = jax.lax.pmin(jnp.where(seg_idx == win, p, big), seg)
+    return mins, win, p
 
 
 @functools.lru_cache(maxsize=64)
@@ -349,6 +406,11 @@ class DistributedRMQ:
     ) -> "DistributedRMQ":
         """Build over ``x``; pass ``capacity > len(x)`` to allow appends.
 
+        ``x`` is host data or a ``jax.Array``; one already sharded over
+        ``segment_axis`` with the padded length is used in place, so an
+        array past one device's memory is made segment by segment on
+        the devices and handed in as it is.
+
         ``capacity`` is the *global* reservation: each segment reserves
         ``ceil(capacity / S)`` +inf-padded slots and the level geometry is
         derived from that, so appends up to ``capacity`` reuse every jit
@@ -366,7 +428,10 @@ class DistributedRMQ:
         summaries with exact recovery) — same semantics as
         ``make_plan``; ``None`` defers to the tuning cache.
         """
-        x = px.coerce_values(x)
+        if not isinstance(x, jax.Array):
+            x = np.asarray(x)
+        if x.ndim != 1:
+            raise ValueError(f"input must be rank-1, got shape {x.shape}")
         n = int(x.shape[0])
         s = _num_segments(mesh, segment_axis)
         if capacity is None:
@@ -380,15 +445,14 @@ class DistributedRMQ:
         # Without x64 the shared guard refuses loudly rather than wrap
         # (mirrors the engine's attach-time contract).
         px.check_capacity_limit(cap_padded, allow_x64=True)
-        if cap_padded != n:
-            x = jnp.pad(x, (0, cap_padded - n), constant_values=jnp.inf)
         local_plan = make_plan(
             cap_local, c=c, t=t,
             packed_pos=packed_pos, summary_dtype=summary_dtype,
         )
 
         backend = px.resolve_backend(backend)
-        x = jax.device_put(x, NamedSharding(mesh, P(segment_axis)))
+        x = _segment_sharded(
+            x, NamedSharding(mesh, P(segment_axis)), cap_padded)
         base, upper, pos = _build_fn(
             mesh, segment_axis, local_plan, with_positions, backend
         )(x)

@@ -82,7 +82,7 @@ __all__ = [
     "make_engine",
 ]
 
-_VALUE_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float64)
+VALUE_DTYPES = (jnp.float32, jnp.bfloat16, jnp.float64)
 
 
 # ---------------------------------------------------------------------------
@@ -107,9 +107,11 @@ def check_capacity_limit(capacity: int, allow_x64: bool = False) -> None:
     """Reject capacities past the int32 query index space.
 
     ``allow_x64=True`` marks call sites that *can* serve int64
-    coordinates (the jax walk, the distributed coordinate plane): they
-    pass when x64 mode is enabled.  Strict sites (the Pallas kernels,
-    the batched engine) always reject — their lowerings index in int32.
+    coordinates (the distributed build, and the engine over a sharded
+    index: its keys widen past 2^31 and its segment-local coordinates
+    stay int32): they pass when x64 mode is enabled.  Strict sites (the
+    Pallas kernels, the engine over a single hierarchy) always reject —
+    their lowerings index in int32.
     """
     if capacity < 2**31:
         return
@@ -262,7 +264,7 @@ def coerce_values(x) -> jax.Array:
     x = jnp.asarray(x)
     if x.ndim != 1:
         raise ValueError(f"input must be rank-1, got shape {x.shape}")
-    if x.dtype not in _VALUE_DTYPES:
+    if x.dtype not in VALUE_DTYPES:
         x = x.astype(jnp.float32)
     return x
 

@@ -62,12 +62,18 @@ def _argmin_kernel(x_ref, p_ref, o_ref, po_ref, *, c: int, rows_out: int):
         po_ref[pl.ds(r, 1), :] = pm
 
 
+def _row_block(i):
+    """Row block ``i``, column block 0, both int32 (a literal 0 would
+    trace as int64 under x64, which Mosaic refuses)."""
+    return i, jnp.zeros_like(i)
+
+
 def _specs(chunks: int, c: int, rows_out: int):
     """Grid and blocks: ``rows_out * c`` input rows -> ``rows_out`` rows."""
     assert chunks % (rows_out * c) == 0, (chunks, rows_out, c)
     grid = (chunks // (rows_out * c),)
-    in_spec = pl.BlockSpec((rows_out * c, c), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((rows_out, c), lambda i: (i, 0))
+    in_spec = pl.BlockSpec((rows_out * c, c), _row_block)
+    out_spec = pl.BlockSpec((rows_out, c), _row_block)
     return grid, in_spec, out_spec
 
 

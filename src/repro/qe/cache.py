@@ -12,14 +12,24 @@ so the cache never pins device memory):
   older array version can never be returned for the new one.  Stale
   generations age out of the LRU naturally.
 
-**Keys.** A query ``(l, r)`` is one int64, ``(l << 31) | r``
-(:func:`pack_keys`).  The engine refuses capacities of 2^31 or more, so
-``0 <= l, r < 2^31``, keys fit in 62 bits and sort in ``(l, r)``
+**Keys.** Below 2^31 a query ``(l, r)`` is one int64, ``(l << 31) | r``
+(:func:`pack_keys`): keys fit in 62 bits and sort in ``(l, r)``
 lexicographic order.  The same key serves the engine's dedup and the
 cache.  A cache entry's key folds the op in as one more low bit,
 ``(key << 1) | OP_BITS[op]`` (:func:`entry_keys`), so one array of
 entry keys can interleave value and index lookups and still sort by
 query first.
+
+**Wide keys.** An index of capacity 2^31 or more (a sharded one, under
+x64) has coordinates that do not fit that key.  The engine then splits
+each query into a *key space*, the bits of ``l`` and ``r`` from 2^31 up,
+and the packed key of the bits below (:func:`split_keys`,
+:func:`unique_wide`); dedup sorts by ``(space, key)``.  The cache takes
+the spaces beside the keys (``spaces=`` of :meth:`ResultCache.get_many`
+and :meth:`~ResultCache.put_many`) and gives each ``(generation,
+space)`` pair an id of its own, which stands where the generation stands
+for a narrow key.  Ids count down from -1, so they never meet a
+generation, and are never reused.  The narrow path is untouched.
 
 **Storage.** Arrays, not an ``OrderedDict``: one ``(n, 4)`` int64 table
 of generation, entry key, the value's raw bits and a recency stamp,
@@ -32,22 +42,22 @@ Whatever the number of generations held, a call is one lookup and at
 most one rebuild of the table.
 
 **Batched API.** :meth:`ResultCache.get_many` / :meth:`put_many` take
-one generation, an array of entry keys and the lock once per call.  A
-put names each entry key once (the engine puts deduplicated keys).  The
-scalar :meth:`get` / :meth:`put` are one-key calls of the same code;
-they hold Python numbers, a float for ``"value"`` and an int for
-``"index"``.
+one generation, an array of entry keys (with wide keys, their sorted
+spaces) and the lock once per call.  A put names each entry key once
+(the engine puts deduplicated keys).  The scalar :meth:`get` /
+:meth:`put` are one-key calls of the same code; they hold Python
+numbers, a float for ``"value"`` and an int for ``"index"``.
 
 **Exact LRU.** A batched call behaves exactly as the same scalar calls
-made in key order on an ``OrderedDict`` LRU: hits are refreshed in key
-order, puts are stamped in key order, evictions remove the oldest
-stamps, and ``hits``, ``misses``, ``evictions`` and ``len()`` match it
-after every call.  The contents of an LRU are the ``capacity`` most
-recently touched keys, so a put keeps the newest stamps; its eviction
-count is the number of puts that found their key absent less the growth
-in size.  A key found present before the call is absent at its put iff
-``capacity`` distinct other keys were touched since it last was
-(:meth:`ResultCache._put`).
+made in key order (with wide keys, ``(space, key)`` order) on an
+``OrderedDict`` LRU: hits are refreshed in key order, puts are stamped
+in key order, evictions remove the oldest stamps, and ``hits``,
+``misses``, ``evictions`` and ``len()`` match it after every call.  The
+contents of an LRU are the ``capacity`` most recently touched keys, so a
+put keeps the newest stamps; its eviction count is the number of puts
+that found their key absent less the growth in size.  A key found
+present before the call is absent at its put iff ``capacity`` distinct
+other keys were touched since it last was (:meth:`ResultCache._put`).
 
 The cache is shared between the serving tier's flusher thread and any
 caller thread that queries an engine directly, so every operation —
@@ -63,11 +73,13 @@ from typing import Tuple
 import numpy as np
 
 __all__ = ["OP_BITS", "ResultCache", "entry_keys", "from_bits",
-           "pack_keys", "to_bits", "unpack_keys"]
+           "join_keys", "pack_keys", "split_keys", "to_bits",
+           "unique_wide", "unpack_keys"]
 
 OP_BITS = {"value": 0, "index": 1}     # an entry key's low bit
 _SCALAR_DTYPES = {"value": np.float64, "index": np.int64}
 _R_MASK = np.int64((1 << 31) - 1)
+_SPACE_R_MASK = np.int64((1 << 32) - 1)
 _GEN, _KEY, _BITS, _STAMP = 0, 1, 2, 3      # columns of the table
 
 
@@ -81,6 +93,37 @@ def unpack_keys(keys) -> Tuple[np.ndarray, np.ndarray]:
     keys = np.asarray(keys, np.int64)
     return ((keys >> 31).astype(np.int32),
             (keys & _R_MASK).astype(np.int32))
+
+
+def split_keys(ls, rs) -> Tuple[np.ndarray, np.ndarray]:
+    """``(spaces, keys)`` of wide queries: the bits of ``l`` and ``r``
+    from 2^31 up as ``((l >> 31) << 32) | (r >> 31)``, and the packed key
+    of the bits below.  Exact for ``0 <= l, r < 2^62``."""
+    ls = np.asarray(ls, np.int64)
+    rs = np.asarray(rs, np.int64)
+    return (((ls >> 31) << 32) | (rs >> 31),
+            pack_keys(ls & _R_MASK, rs & _R_MASK))
+
+
+def join_keys(spaces, keys) -> Tuple[np.ndarray, np.ndarray]:
+    """The int64 ``(l, r)`` bounds of :func:`split_keys` output."""
+    spaces = np.asarray(spaces, np.int64)
+    keys = np.asarray(keys, np.int64)
+    return (((spaces >> 32) << 31) | (keys >> 31),
+            ((spaces & _SPACE_R_MASK) << 31) | (keys & _R_MASK))
+
+
+def unique_wide(ls, rs):
+    """``(spaces, keys, inverse)``: the distinct wide queries sorted by
+    ``(space, key)``, and each query's row among them."""
+    spaces, keys = split_keys(ls, rs)
+    order = np.lexsort((keys, spaces))
+    s, k = spaces[order], keys[order]
+    first = np.ones(s.shape, bool)
+    first[1:] = (s[1:] != s[:-1]) | (k[1:] != k[:-1])
+    inverse = np.empty(s.shape, np.intp)
+    inverse[order] = np.cumsum(first) - 1
+    return s[first], k[first], inverse
 
 
 def entry_keys(keys, op_bits) -> np.ndarray:
@@ -110,6 +153,17 @@ def _scalar_key(op: str, l: int, r: int) -> np.ndarray:
     if not (0 <= l < 2**31 and 0 <= r < 2**31):
         raise ValueError(f"bounds must lie in [0, 2^31), got ({l}, {r})")
     return entry_keys(pack_keys([l], [r]), OP_BITS[op])
+
+
+def _ascending(keys: np.ndarray) -> bool:
+    """Are ``keys`` strictly ascending (the engine's are)?  Raises on a
+    key named twice."""
+    if bool((keys[1:] > keys[:-1]).all()):
+        return True
+    s = np.sort(keys)
+    if (s[1:] == s[:-1]).any():
+        raise ValueError("put_many got a key twice")
+    return False
 
 
 def _as_items(table: np.ndarray) -> np.ndarray:
@@ -149,6 +203,8 @@ class ResultCache:
         self._lock = threading.Lock()
         self._table = np.zeros((0, 4), np.int64)
         self._clock = 0            # next recency stamp
+        self._space_ids = {}       # (generation, key space) -> id < 0
+        self._next_space_id = -1
         self.hits = 0
         self.misses = 0
         self.evictions = 0
@@ -175,21 +231,31 @@ class ResultCache:
             self._put(generation, key, bits)
 
     # -- batched API ------------------------------------------------------
-    def get_many(self, generation: int, keys, dtype=np.int64):
+    def get_many(self, generation: int, keys, dtype=np.int64,
+                 spaces=None):
         """Look up entry ``keys`` in order: ``(values, hit)``.
 
         ``values`` holds each hit's value decoded as ``dtype`` (the raw
         bits with the default; unspecified where ``hit`` is False).
+        ``spaces`` gives wide keys their key spaces (sorted, aligned
+        with ``keys``).
         """
         keys = np.asarray(keys, np.int64).ravel()
         with self._lock:
-            bits, hit = self._get(generation, keys)
+            if spaces is None:
+                bits, hit = self._get(generation, keys)
+            else:
+                bits = np.empty(keys.shape, np.int64)
+                hit = np.empty(keys.shape, bool)
+                for sid, a, b in self._space_runs(generation, spaces):
+                    bits[a:b], hit[a:b] = self._get(sid, keys[a:b])
         return from_bits(bits, dtype), hit
 
-    def put_many(self, generation: int, keys, values) -> None:
+    def put_many(self, generation: int, keys, values, spaces=None) -> None:
         """Insert entry ``keys`` in order, storing the raw bits of
         ``values`` (aligned with ``keys``; int64 values are taken as
-        bits already).  The keys must be distinct."""
+        bits already).  The keys (with ``spaces``, as in
+        :meth:`get_many`: the ``(space, key)`` pairs) must be distinct."""
         if self.capacity == 0:
             return
         keys = np.asarray(keys, np.int64).ravel()
@@ -197,17 +263,17 @@ class ResultCache:
         if bits.shape != keys.shape:
             raise ValueError(
                 f"values must match keys, got {bits.shape} vs {keys.shape}")
-        ascending = bool((keys[1:] > keys[:-1]).all())   # the engine's are
-        if not ascending:
-            s = np.sort(keys)
-            if (s[1:] == s[:-1]).any():
-                raise ValueError("put_many got a key twice")
         with self._lock:
-            self._put(generation, keys, bits, ascending)
+            runs = ([(generation, 0, keys.shape[0])] if spaces is None
+                    else self._space_runs(generation, spaces))
+            ascending = [_ascending(keys[a:b]) for _, a, b in runs]
+            for (sid, a, b), up in zip(runs, ascending):
+                self._put(sid, keys[a:b], bits[a:b], up)
 
     def clear(self) -> None:
         with self._lock:
             self._table = np.zeros((0, 4), np.int64)
+            self._space_ids = {}
 
     def hit_rate(self) -> float:
         """Hits / lookups over the cache's lifetime (0.0 when untouched)."""
@@ -226,6 +292,30 @@ class ResultCache:
             }
 
     # -- internals (lock held) --------------------------------------------
+    def _space_runs(self, generation: int, spaces):
+        """``[(id, lo, hi)]``: the cache id of each run of equal values
+        in the sorted ``spaces``.  Ids of pairs with no entry left are
+        forgotten once they outnumber twice the capacity (a fresh id
+        finds nothing either)."""
+        spaces = np.asarray(spaces, np.int64).ravel()
+        if spaces.shape[0] == 0:
+            return []
+        ids = self._space_ids
+        if len(ids) > 2 * self.capacity + 64:
+            live = set(np.unique(self._table[:, _GEN]).tolist())
+            self._space_ids = ids = {
+                k: v for k, v in ids.items() if v in live}
+        cut = (np.flatnonzero(spaces[1:] != spaces[:-1]) + 1).tolist()
+        runs = []
+        for a, b in zip([0] + cut, cut + [spaces.shape[0]]):
+            pair = (generation, int(spaces[a]))
+            sid = ids.get(pair)
+            if sid is None:
+                sid = ids[pair] = self._next_space_id
+                self._next_space_id -= 1
+            runs.append((sid, a, b))
+        return runs
+
     @staticmethod
     def _insert_at(table: np.ndarray, add: np.ndarray) -> np.ndarray:
         """Where the rows ``add`` of one generation, sorted by key, go in

@@ -20,7 +20,15 @@ Both paths produce values and leftmost-tie positions bit-identical to
 ``DistributedRMQ.query``/``query_index``.  Shapes are padded to powers of
 two (``(0, 0)`` sentinel queries, dropped at scatter-back) so the set of
 jit specializations stays bounded as batch composition shifts — the same
-discipline as the planner's buckets.
+discipline as the planner's buckets.  Each class dispatches all its
+buckets before it waits for the first answer.
+
+Global bounds and positions keep the engine's coordinate dtype (int64
+once the capacity passes 2^31); segment-local bounds are int32.
+
+Spans (``repro.obs.trace``): ``route`` (the host split; ``queries``,
+``seg_local``, ``crossing``), then per class an ``execute`` holding one
+``launch`` and one ``fetch`` per bucket, each with ``cls``.
 """
 
 from __future__ import annotations
@@ -29,6 +37,7 @@ from typing import Dict
 
 import numpy as np
 
+from repro.core.hierarchy import pos_dtype_for
 from repro.obs import trace
 from repro.qe.executors import INDEX
 from repro.qe.planner import _next_pow2
@@ -37,6 +46,13 @@ __all__ = ["SEG_LOCAL", "CROSSING", "DistributedExecutor"]
 
 SEG_LOCAL = "seg_local"
 CROSSING = "crossing"
+
+
+def _out_dtype(index, op: str):
+    """Positions in the global coordinate dtype, values in the index's."""
+    if op == INDEX:
+        return np.dtype(pos_dtype_for(index.capacity, strict=False))
+    return np.dtype(index.value_dtype)
 
 
 class DistributedExecutor:
@@ -51,19 +67,24 @@ class DistributedExecutor:
 
     def run(self, index, ls: np.ndarray, rs: np.ndarray,
             op: str) -> np.ndarray:
-        """Answer ``(ls, rs)`` (np.int32, deduped) against ``index``."""
+        """Answer ``(ls, rs)`` (deduped global bounds) against ``index``."""
         self.calls += 1
         m = ls.shape[0]
         self.queries += m
         cap = index.segment_capacity
-        out_dtype = np.int32 if op == INDEX else np.dtype(index.value_dtype)
+        out_dtype = _out_dtype(index, op)
         out = np.empty((m,), out_dtype)
-        owner = ls // cap
-        local = owner == (rs // cap)
-        self.class_counts[SEG_LOCAL] += int(local.sum())
-        self.class_counts[CROSSING] += int(m - local.sum())
 
         tr = trace.current()
+        sp = tr.begin("route") if tr is not None else None
+        owner = ls // cap
+        local = owner == (rs // cap)
+        n_local = int(local.sum())
+        self.class_counts[SEG_LOCAL] += n_local
+        self.class_counts[CROSSING] += m - n_local
+        if tr is not None:
+            tr.end(sp, queries=m, seg_local=n_local, crossing=m - n_local,
+                   op=op)
         cross_idx = np.nonzero(~local)[0]
         if cross_idx.shape[0]:
             sp = tr.begin("execute") if tr is not None else None
@@ -106,11 +127,11 @@ class DistributedExecutor:
         self.queries += m
         cap = index.segment_capacity
         c = index.plan.c
-        out_dtype = np.int32 if op == INDEX else np.dtype(index.value_dtype)
+        out_dtype = _out_dtype(index, op)
         out = np.empty((m,), out_dtype)
 
         tr = trace.current()
-        sp = tr.begin("plan") if tr is not None else None
+        sp = tr.begin("route") if tr is not None else None
         owner = ls // cap
         local = owner == (rs // cap)
         n_local = int(local.sum())
@@ -119,8 +140,8 @@ class DistributedExecutor:
         local_idx = np.nonzero(local)[0]
         lsub, rsub = ls[local_idx], rs[local_idx]
         osub = owner[local_idx]
-        lloc = lsub - osub.astype(np.int32) * cap
-        rloc = rsub - osub.astype(np.int32) * cap
+        lloc = lsub - osub * cap
+        rloc = rsub - osub * cap
         sort = np.lexsort((rloc // c, lloc // c, osub))
         if tr is not None:
             tr.end(sp, queries=m, seg_local=n_local,
@@ -155,16 +176,26 @@ class DistributedExecutor:
         shape = min(
             max(_next_pow2(k), self.min_bucket), self.max_bucket
         )
-        res = np.empty((k,), out_dtype)
+        tr = trace.current()
+        pending = []
         for lo in range(0, k, shape):
+            sp = tr.begin("launch") if tr is not None else None
             cnt = min(shape, k - lo)
-            pl = np.zeros((shape,), np.int32)
-            pr = np.zeros((shape,), np.int32)
+            pl = np.zeros((shape,), ls.dtype)
+            pr = np.zeros((shape,), rs.dtype)
             pl[:cnt] = ls[lo : lo + cnt]
             pr[:cnt] = rs[lo : lo + cnt]
             r = index.query_index(pl, pr) if op == INDEX \
                 else index.query(pl, pr)
+            pending.append((lo, cnt, r))
+            if tr is not None:
+                tr.end(sp, cls=CROSSING)
+        res = np.empty((k,), out_dtype)
+        for lo, cnt, r in pending:
+            sp = tr.begin("fetch") if tr is not None else None
             res[lo : lo + cnt] = np.asarray(r)[:cnt]
+            if tr is not None:
+                tr.end(sp, cls=CROSSING)
         return res
 
     # -- contained spans: grouped per owner, answered without collectives -
@@ -179,13 +210,16 @@ class DistributedExecutor:
         counts = np.bincount(so, minlength=s)
         starts = np.cumsum(counts) - counts
         row_pos = np.arange(so.shape[0]) - starts[so]
-        lloc = ls[order] - so.astype(np.int32) * cap
-        rloc = rs[order] - so.astype(np.int32) * cap
-        picked = np.empty((so.shape[0],), out_dtype)
+        # localize in the global dtype, then narrow: local bounds < cap
+        lloc = (ls[order] - so * cap).astype(np.int32)
+        rloc = (rs[order] - so * cap).astype(np.int32)
+        tr = trace.current()
+        pending = []
         # row width is bounded at max_bucket (same discipline as the
         # planner's buckets): a skewed batch runs in several rounds of
         # already-compiled shapes instead of tracing one giant one
         for lo in range(0, int(counts.max()), self.max_bucket):
+            sp = tr.begin("launch") if tr is not None else None
             sel = (row_pos >= lo) & (row_pos < lo + self.max_bucket)
             rp = row_pos[sel] - lo
             k = max(_next_pow2(int(rp.max()) + 1), self.min_bucket)
@@ -196,9 +230,16 @@ class DistributedExecutor:
             vals, poss = index._query_grouped(
                 gl, gr, track_pos=(op == INDEX)
             )
-            picked[sel] = np.asarray(
-                poss if op == INDEX else vals
-            )[so[sel], rp].astype(out_dtype, copy=False)
+            pending.append((sel, rp, poss if op == INDEX else vals))
+            if tr is not None:
+                tr.end(sp, cls=SEG_LOCAL)
+        picked = np.empty((so.shape[0],), out_dtype)
+        for sel, rp, r in pending:
+            sp = tr.begin("fetch") if tr is not None else None
+            picked[sel] = np.asarray(r)[so[sel], rp].astype(
+                out_dtype, copy=False)
+            if tr is not None:
+                tr.end(sp, cls=SEG_LOCAL)
         res = np.empty((ls.shape[0],), out_dtype)
         res[order] = picked
         return res

@@ -9,7 +9,8 @@ through persistent jitted callables (see :mod:`repro.qe.executors`).
 
 Execution pipeline per batch::
 
-    validate -> pack keys ((l << 31) | r, one int64 per query)
+    validate -> pack keys ((l << 31) | r, one int64 per query;
+                          past 2^31 a key space beside it)
              -> dedup (np.unique on the keys)
              -> LRU lookup (ResultCache.get_many: one call per batch)
              -> planner buckets -> per-class executors
@@ -55,6 +56,7 @@ from typing import Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.hierarchy import pos_dtype_for
 from repro.core.protocol import (
     check_capacity_limit,
     is_distributed,
@@ -71,8 +73,10 @@ from repro.qe.cache import (
     ResultCache,
     entry_keys,
     from_bits,
+    join_keys,
     pack_keys,
     to_bits,
+    unique_wide,
     unpack_keys,
 )
 from repro.qe.distributed import DistributedExecutor
@@ -146,6 +150,7 @@ class QueryEngine:
         self.planner: Optional[QueryPlanner] = None
         self.distributed: Optional[DistributedExecutor] = None
         self.metrics: Optional[Metrics] = None
+        self._coord = np.dtype(np.int32)  # coordinate dtype, per attach
         self._m_padding = None
         self._m_padded_lanes = None
         self._m_live_lanes = None
@@ -319,13 +324,16 @@ class QueryEngine:
         if reset_cache:
             self.cache.clear()
         plan = index.plan
-        # Query bounds/positions flow through int32 index space (planner
-        # packing, the short kernel's iota, and the bucket packing's
-        # numpy arithmetic alike — x64 does not lift this path).  Refuse
-        # loudly rather than wrap.  ``capacity`` is the total addressable
-        # space — for sharded indices that is segments * per-segment
-        # capacity, not the (per-segment) plan's.
-        check_capacity_limit(index.capacity)
+        # Over one hierarchy, query bounds and positions flow through
+        # int32 index space (planner packing, the short kernel's iota):
+        # refuse capacities past it loudly rather than wrap.  A sharded
+        # index under x64 serves any capacity: global coordinates are
+        # int64 (keys split as ``cache.split_keys``), segment-local ones
+        # int32.  ``capacity`` is the total addressable space — for
+        # sharded indices segments * per-segment capacity, not the
+        # (per-segment) plan's.
+        check_capacity_limit(index.capacity, allow_x64=is_distributed(index))
+        self._coord = np.dtype(pos_dtype_for(index.capacity, strict=False))
         if is_distributed(index):
             # Sharded index: routing is by segment containment, not span
             # class — the planner and span executors never run.
@@ -448,8 +456,8 @@ class QueryEngine:
         try:
             n = live_length(index)
             ls, rs = check_query_args(ls, rs, n)
-            ls = np.asarray(ls, np.int32).ravel()
-            rs = np.asarray(rs, np.int32).ravel()
+            ls = np.asarray(ls, self._coord).ravel()
+            rs = np.asarray(rs, self._coord).ravel()
             # bf16 summaries: the coalesced bulk sweep compares quantized
             # level-1 values with no exact-recovery pass, so bf16 indexes
             # always take the routed path (whose walks re-read level 0).
@@ -468,7 +476,7 @@ class QueryEngine:
             else:
                 res = self._bulk.run(index.hierarchy, ls, rs, op)
             out_dtype = (
-                np.int32 if op == INDEX else np.dtype(index.value_dtype)
+                self._coord if op == INDEX else np.dtype(index.value_dtype)
             )
             return jnp.asarray(
                 np.asarray(res).astype(out_dtype, copy=False))
@@ -508,8 +516,8 @@ class QueryEngine:
             )
         n = live_length(index)
         ls, rs = check_query_args(ls, rs, n)
-        ls = np.asarray(ls, np.int32).ravel()
-        rs = np.asarray(rs, np.int32).ravel()
+        ls = np.asarray(ls, self._coord).ravel()
+        rs = np.asarray(rs, self._coord).ravel()
         if ls.shape != is_index.shape:
             raise ValueError(
                 f"is_index must match the batch, got {is_index.shape} "
@@ -518,7 +526,7 @@ class QueryEngine:
         m = ls.shape[0]
         val_dtype = np.dtype(index.value_dtype)
         vals_out = np.zeros((m,), val_dtype)
-        pos_out = np.zeros((m,), np.int32)
+        pos_out = np.zeros((m,), self._coord)
         if m == 0:
             return vals_out, pos_out
 
@@ -653,11 +661,11 @@ class QueryEngine:
         index = self._index
         n = live_length(index)
         ls, rs = check_query_args(ls, rs, n)
-        ls = np.asarray(ls, np.int32).ravel()
-        rs = np.asarray(rs, np.int32).ravel()
+        ls = np.asarray(ls, self._coord).ravel()
+        rs = np.asarray(rs, self._coord).ravel()
         m = ls.shape[0]
         out_dtype = (
-            np.int32 if op == INDEX else np.dtype(index.value_dtype)
+            self._coord if op == INDEX else np.dtype(index.value_dtype)
         )
         if m == 0:
             return jnp.zeros((0,), out_dtype)
@@ -668,7 +676,12 @@ class QueryEngine:
         # -- within-batch dedup -------------------------------------------
         tr = trace.current()
         sp = tr.begin("dedup") if tr is not None else None
-        ukeys, inverse = np.unique(pack_keys(ls, rs), return_inverse=True)
+        spaces = None       # wide keys' key spaces (coordinates >= 2^31)
+        if self._coord == np.int32:
+            ukeys, inverse = np.unique(pack_keys(ls, rs),
+                                       return_inverse=True)
+        else:
+            spaces, ukeys, inverse = unique_wide(ls, rs)
         k = ukeys.shape[0]
         self.dedup_saved += m - k
         if tr is not None:
@@ -680,7 +693,7 @@ class QueryEngine:
         if self.cache.capacity > 0:
             sp = self._begin_cache_get(tr)
             ekeys = entry_keys(ukeys, OP_BITS[op])
-            vals, hit = self.cache.get_many(gen, ekeys, out_dtype)
+            vals, hit = self.cache.get_many(gen, ekeys, out_dtype, spaces)
             uniq_res[hit] = vals[hit]
             miss_idx = np.flatnonzero(~hit)
             if tr is not None:
@@ -690,7 +703,10 @@ class QueryEngine:
 
         # -- plan + execute the misses ------------------------------------
         if miss_idx.shape[0]:
-            mls, mrs = unpack_keys(ukeys[miss_idx])
+            if spaces is None:
+                mls, mrs = unpack_keys(ukeys[miss_idx])
+            else:
+                mls, mrs = join_keys(spaces[miss_idx], ukeys[miss_idx])
             if self.distributed is not None:
                 res = self.distributed.run(index, mls, mrs, op)
                 uniq_res[miss_idx] = res.astype(out_dtype, copy=False)
@@ -724,8 +740,9 @@ class QueryEngine:
                     uniq_res[miss_idx[bucket.idxs]] = res
             if self.cache.capacity > 0:
                 sp = tr.begin("cache_put") if tr is not None else None
-                self.cache.put_many(gen, ekeys[miss_idx],
-                                    uniq_res[miss_idx])
+                self.cache.put_many(
+                    gen, ekeys[miss_idx], uniq_res[miss_idx],
+                    None if spaces is None else spaces[miss_idx])
                 if tr is not None:
                     tr.end(sp, entries=int(miss_idx.shape[0]))
 
