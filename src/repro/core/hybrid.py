@@ -45,8 +45,11 @@ from repro.core.baselines import SparseTable
 from repro.core.constants import POS_INF_I32 as _POS_INF_I32
 from repro.core.hierarchy import Hierarchy
 from repro.core.plan import HierarchyPlan, make_plan
+# the shared lexicographic (value, leftmost-position) merge: the engine's
+# parity contract needs identical tie-breaking across all paths
+from repro.core.query import _masked_window_scan, _merge, row_view_rows
 
-__all__ = ["HybridRMQ"]
+__all__ = ["HybridRMQ", "row_levels"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -223,14 +226,26 @@ def _hybrid_batch(plan, base, upper, upper_pos, top_table, top_pos, ls, rs,
     )(ls, rs)
 
 
+def row_levels(plan: HierarchyPlan) -> int:
+    """Walk levels (of ``plan.num_levels - 1``) whose chunk windows
+    :func:`_hybrid_single` reads by a row gather, not a ``dynamic_slice``."""
+    lens = [plan.capacity] + [
+        plan.level_slice(level)[1] for level in range(1, plan.num_levels - 1)
+    ]
+    return sum(row_view_rows(m, plan.c, plan.c, aligned=True) > 0
+               for m in lens)
+
+
 def _hybrid_single(plan: HierarchyPlan, base, upper, upper_pos, top_table,
                    top_pos, l, r, track_pos):
-    """Branch-free walk for levels 0..L-2 + O(1) table lookup at the top."""
-    # shared lexicographic (value, leftmost-position) merge: the engine's
-    # parity contract needs identical tie-breaking across all paths
-    from repro.kernels.rmq_scan.ref import _merge, _window
+    """Branch-free walk for levels 0..L-2 + O(1) table lookup at the top.
 
+    Each boundary window is the ``c``-entry chunk at a multiple of ``c``:
+    one row of the level's ``(rows, c)`` view (:func:`row_levels`).
+    """
     c = plan.c
+    window = functools.partial(_masked_window_scan, window=c,
+                               track_pos=track_pos, row=c, aligned=True)
     l = l.astype(jnp.int32)
     r = (r + 1).astype(jnp.int32)
     m = jnp.float32(jnp.inf)
@@ -249,11 +264,10 @@ def _hybrid_single(plan: HierarchyPlan, base, upper, upper_pos, top_table,
             )
         next_l = ((l + c - 1) // c) * c
         prev_r = (r // c) * c
-        m2, p2 = _window(arr, pos_arr, (l // c) * c, l,
-                         jnp.minimum(next_l, r), c, track_pos)
+        m2, p2 = window(arr, pos_arr, (l // c) * c, l,
+                        jnp.minimum(next_l, r))
         m, p = _merge(m, p, m2, p2)
-        m2, p2 = _window(arr, pos_arr, prev_r, jnp.maximum(prev_r, l), r, c,
-                         track_pos)
+        m2, p2 = window(arr, pos_arr, prev_r, jnp.maximum(prev_r, l), r)
         m, p = _merge(m, p, m2, p2)
         l = (l + c - 1) // c
         r = r // c

@@ -98,21 +98,45 @@ def _merge(m, p, m2, p2):
 _ROW_VIEW_COPY = 1 << 20
 
 
+def row_view_rows(n: int, window: int, row: int,
+                  aligned: bool = False) -> int:
+    """Rows :func:`_masked_window_scan` gathers to read ``window`` entries
+    of an ``n``-entry array, or 0 where it reads by ``dynamic_slice``.
+
+    The ``(rows, row)`` view is used where it is free (whole ``(8,
+    row)`` tiles) or cheap (at most ``_ROW_VIEW_COPY`` entries).  An
+    unaligned start needs one row more than the window spans; an
+    ``aligned`` one (``start`` and ``window`` multiples of ``row``)
+    needs none.
+    """
+    window = min(window, n)
+    if aligned and window % row:
+        raise ValueError(
+            f"an aligned read needs a whole number of rows, got window "
+            f"{window} for rows of {row}")
+    k = -(-window // row) + (0 if aligned else 1)
+    if (n % row == 0 and n // row >= k
+            and (n % (8 * row) == 0 or n <= _ROW_VIEW_COPY)):
+        return k
+    return 0
+
+
 def _masked_window_scan(
     arr, pos_arr, start, lo, hi, window, track_pos, row,
-    coord=jnp.int32, exact_src=None,
+    coord=jnp.int32, exact_src=None, aligned=False,
 ):
     """min over ``arr[i]`` for ``i in [lo, hi) ∩ [start, start+window)``.
 
     ``start`` is clamped so that the window lies inside ``arr``; masking
     uses the *absolute* indices actually read, so clamping and reading
-    more than the window are both safe.  Where the ``(rows, row)`` view
-    is free (whole ``(8, row)`` tiles) or cheap (at most
-    ``_ROW_VIEW_COPY`` entries), the window is read by a gather of the
-    ``row``-entry rows that cover it, one per window for the whole batch
-    under the batch's ``vmap``; else by a ``dynamic_slice``, which lowers
-    on TPU to a loop over the queries (~13× slower on v5e, but an index
-    gather is slower still).
+    more than the window are both safe.  Where :func:`row_view_rows`
+    allows it, the window is read by a gather of the ``row``-entry rows
+    that cover it, one per window for the whole batch under the batch's
+    ``vmap``; else by a ``dynamic_slice``, which lowers on TPU to a loop
+    over the queries (~13× slower on v5e, but an index gather is slower
+    still).  ``aligned`` is the caller's promise that ``start`` and
+    ``window`` are multiples of ``row``: the gather then reads just the
+    window's rows, not one more.
     Returns ``(min_value, min_position)`` with +inf / INTmax identities;
     positions (and the scan coordinates) use dtype ``coord`` — int64 for
     capacities past 2^31 under x64.
@@ -127,9 +151,8 @@ def _masked_window_scan(
     n = arr.shape[0]
     window = min(window, n)
     start = jnp.clip(start, 0, max(n - window, 0)).astype(coord)
-    k = -(-window // row) + 1          # rows that cover any window
-    if (n % row == 0 and n // row >= k
-            and (n % (8 * row) == 0 or n <= _ROW_VIEW_COPY)):
+    k = row_view_rows(n, window, row, aligned)
+    if k:
         r0 = jnp.clip(start // row, 0, n // row - k)
         idx = r0 * row + jnp.arange(k * row, dtype=coord)
         rows = r0 + jnp.arange(k, dtype=coord)

@@ -42,9 +42,9 @@ from repro.core.baselines import SparseTable
 from repro.core.constants import POS_INF_I32 as _POS_INF_I32
 from repro.core.hierarchy import Hierarchy
 from repro.core.plan import HierarchyPlan
+from repro.core.query import _masked_window_scan, _merge
 from repro.kernels import common, profiling
 from repro.kernels.rmq_bulk import kernel as K
-from repro.kernels.rmq_scan.ref import _merge, _window
 
 __all__ = [
     "rmq_bulk_batch",
@@ -154,6 +154,10 @@ def _bulk_jnp(base, upper, upper_pos, ls, rs, plan, track_pos):
             return _merge(v1, pladder[k, chunk, a], v2, pladder[k, chunk, i2])
         return jnp.minimum(v1, v2), pos_inf
 
+    # interior windows are whole chunks: one row of a level's (rows, c) view
+    window = functools.partial(_masked_window_scan, window=c,
+                               track_pos=track_pos, row=c, aligned=True)
+
     def one(l, r):
         l = l.astype(jnp.int32)
         re = (r + 1).astype(jnp.int32)  # exclusive
@@ -180,11 +184,11 @@ def _bulk_jnp(base, upper, upper_pos, ls, rs, plan, track_pos):
             )
             next_l = ((li + c - 1) // c) * c
             prev_r = (ri // c) * c
-            m2, p2 = _window(arr, pos_arr, (li // c) * c, li,
-                             jnp.minimum(next_l, ri), c, track_pos)
+            m2, p2 = window(arr, pos_arr, (li // c) * c, li,
+                            jnp.minimum(next_l, ri))
             m, p = _merge(m, p, m2, p2)
-            m2, p2 = _window(arr, pos_arr, prev_r, jnp.maximum(prev_r, li),
-                             ri, c, track_pos)
+            m2, p2 = window(arr, pos_arr, prev_r, jnp.maximum(prev_r, li),
+                            ri)
             m, p = _merge(m, p, m2, p2)
             li = (li + c - 1) // c
             ri = ri // c

@@ -736,7 +736,8 @@ class QueryEngine:
                     if tr is not None:
                         tr.end(sub)
                         tr.end(sp, cls=bucket.cls, count=bucket.count,
-                               shape=bucket.shape, op=op)
+                               shape=bucket.shape, op=op,
+                               **self.executors[bucket.cls].span_args(h))
                     uniq_res[miss_idx[bucket.idxs]] = res
             if self.cache.capacity > 0:
                 sp = tr.begin("cache_put") if tr is not None else None

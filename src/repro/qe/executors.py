@@ -75,6 +75,10 @@ class _ExecutorBase:
     def invalidate(self) -> None:
         """Drop state tied to a particular index version (default: none)."""
 
+    def span_args(self, h: Hierarchy) -> dict:
+        """Args this executor adds to a bucket's ``execute`` span."""
+        return {}
+
 
 class ShortSpanExecutor(_ExecutorBase):
     """Two-chunk level-0 scan; never touches the hierarchy."""
@@ -151,6 +155,12 @@ class LongSpanExecutor(_ExecutorBase):
         if op == VALUE:
             return lambda h, ls, rs: self._hybrid_for(h).query(ls, rs)
         return lambda h, ls, rs: self._hybrid_for(h).query_index(ls, rs)
+
+    def span_args(self, h: Hierarchy) -> dict:
+        """``row_levels``: the walk levels read by a row gather, of L-1."""
+        from repro.core.hybrid import row_levels
+
+        return {"row_levels": row_levels(h.plan)}
 
 
 class FusedExecutor(_ExecutorBase):

@@ -7,6 +7,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from _hypothesis_compat import given, settings, st
+from _lowering import window_reads
 
 from repro.core import (
     RMQ,
@@ -376,3 +377,70 @@ class TestBf16Values:
             for l, r in zip(ls, rs)
         ])
         np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+
+
+# ---------------------------------------------------------------------------
+# The window reader shared by the jnp walk, the hybrid walk and rmq_bulk
+# ---------------------------------------------------------------------------
+class TestWindowReader:
+    """``_masked_window_scan`` against numpy, one case per lowering."""
+
+    @pytest.mark.parametrize("n,c,window,aligned,rows", [
+        ((1 << 20) + 8 * 16, 16, 16, False, 2),   # row view free
+        ((1 << 20) + 8 * 16, 16, 16, True, 1),    # ... one row, aligned
+        (16 * 13, 16, 32, False, 3),              # row view a cheap copy
+        (16 * 13, 16, 16, True, 1),               # ... one row, aligned
+        (16 * 13 + 5, 16, 16, False, 0),          # dynamic_slice
+        (16 * 13 + 5, 16, 16, True, 0),           # ... aligned changes nothing
+        ((1 << 20) + 16, 16, 16, True, 0),        # too big to copy
+    ])
+    @pytest.mark.parametrize("with_pos_plane", [False, True])
+    def test_matches_numpy(self, n, c, window, aligned, rows, with_pos_plane):
+        from repro.core.query import _masked_window_scan, row_view_rows
+
+        assert row_view_rows(n, window, c, aligned) == rows
+        rng = np.random.default_rng(n + window + aligned)
+        x = rng.integers(0, 4, n).astype(np.float32)   # ties everywhere
+        # a monotone position plane: its min over a tie is the leftmost
+        pos_plane = (np.arange(n) * 3 + 7).astype(np.int32)
+        m = 512
+        if aligned:
+            # chunk anchors, past both ends too (clamped in range)
+            starts = rng.integers(-2, n // c + 2, m) * c
+        else:
+            starts = rng.integers(-window, n + window, m)
+        lo = starts + rng.integers(-window, window + 1, m)
+        hi = lo + rng.integers(-4, window + 1, m)      # some empty masks
+        lo[:4] = [0, n - 1, n, 0]
+        hi[:4] = [n, n, n, 0]
+        starts[:4] = [0, n - window, n, -window]
+
+        pos_arr = jnp.asarray(pos_plane) if with_pos_plane else None
+        scan = jax.jit(jax.vmap(lambda s, a, b: _masked_window_scan(
+            jnp.asarray(x), pos_arr, s, a, b, window, True, c,
+            aligned=aligned)))
+        got_m, got_p = map(np.asarray, scan(
+            jnp.asarray(starts, jnp.int32), jnp.asarray(lo, jnp.int32),
+            jnp.asarray(hi, jnp.int32)))
+        reads = window_reads(jax.make_jaxpr(scan)(
+            jnp.asarray(starts, jnp.int32), jnp.asarray(lo, jnp.int32),
+            jnp.asarray(hi, jnp.int32)))
+        one = ("row", rows) if rows else ("slice", window)
+        assert reads == [one] * (2 if with_pos_plane else 1)
+
+        for i in range(m):
+            s = min(max(int(starts[i]), 0), n - window)
+            a, b = max(int(lo[i]), s), min(int(hi[i]), s + window)
+            if a >= b:
+                assert got_m[i] == np.inf
+                assert got_p[i] == np.iinfo(np.int32).max
+                continue
+            j = a + int(np.argmin(x[a:b]))
+            assert got_m[i] == x[j]
+            assert got_p[i] == (pos_plane[j] if with_pos_plane else j)
+
+    def test_aligned_needs_whole_rows(self):
+        from repro.core.query import row_view_rows
+
+        with pytest.raises(ValueError, match="whole number of rows"):
+            row_view_rows(1024, 24, 16, aligned=True)
