@@ -31,6 +31,7 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import bitpack
 from repro.core.hierarchy import Hierarchy, pos_dtype_for
@@ -42,6 +43,7 @@ __all__ = [
     "rmq_value_batch",
     "rmq_index_batch",
     "check_query_args",
+    "check_host_bounds",
 ]
 
 from repro.core.constants import POS_INF_I32 as _POS_INF_I32  # noqa: E402
@@ -61,6 +63,20 @@ def check_query_args(ls, rs, n: int, debug: bool = None):
     arrays.
     """
     ls, rs = jnp.asarray(ls), jnp.asarray(rs)
+    _check_bounds(ls, rs, n, debug)
+    return ls, rs
+
+
+def check_host_bounds(ls, rs, n: int, debug: bool = None):
+    """:func:`check_query_args` for bounds that stay on the host: the same
+    checks and errors, with no copy to the device.  Returns ``(ls, rs)``
+    as numpy arrays."""
+    ls, rs = np.asarray(ls), np.asarray(rs)
+    _check_bounds(ls, rs, n, debug)
+    return ls, rs
+
+
+def _check_bounds(ls, rs, n: int, debug):
     for name, a in (("ls", ls), ("rs", rs)):
         if not jnp.issubdtype(a.dtype, jnp.integer):
             raise TypeError(
@@ -75,8 +91,6 @@ def check_query_args(ls, rs, n: int, debug: bool = None):
     if debug and not (
         isinstance(ls, jax.core.Tracer) or isinstance(rs, jax.core.Tracer)
     ):
-        import numpy as np
-
         l_np, r_np = np.asarray(ls), np.asarray(rs)
         bad = (l_np < 0) | (l_np > r_np) | (r_np >= n)
         if bad.any():
@@ -85,7 +99,6 @@ def check_query_args(ls, rs, n: int, debug: bool = None):
                 f"query {i} = ({l_np.flat[i]}, {r_np.flat[i]}) violates "
                 f"0 <= l <= r < n with n={n}"
             )
-    return ls, rs
 
 
 def _merge(m, p, m2, p2):

@@ -37,7 +37,7 @@ that observation into an execution layer:
   whole batch endpoint-sorted by ``(chunk(l), chunk(r))`` and answered
   in single level-0-coalesced ``kernels/rmq_bulk`` dispatches that
   share chunk reads across queries, with an autotuned size crossover
-  back to the fused path for small batches.
+  back to the engine's routed path for smaller batches.
 """
 
 from repro.qe.cache import ResultCache

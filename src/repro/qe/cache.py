@@ -42,16 +42,16 @@ Whatever the number of generations held, a call is one lookup and at
 most one rebuild of the table.
 
 **Batched API.** :meth:`ResultCache.get_many` / :meth:`put_many` take
-one generation, an array of entry keys (with wide keys, their sorted
+one generation, an array of entry keys (with wide keys, their
 spaces) and the lock once per call.  A put names each entry key once
 (the engine puts deduplicated keys).  The scalar :meth:`get` /
 :meth:`put` are one-key calls of the same code; they hold Python
 numbers, a float for ``"value"`` and an int for ``"index"``.
 
 **Exact LRU.** A batched call behaves exactly as the same scalar calls
-made in key order (with wide keys, ``(space, key)`` order) on an
-``OrderedDict`` LRU: hits are refreshed in key order, puts are stamped
-in key order, evictions remove the oldest stamps, and ``hits``,
+made in the order of its keys (the engine's ascend within each op) on
+an ``OrderedDict`` LRU: hits are refreshed and puts stamped in that
+order, evictions remove the oldest stamps, and ``hits``,
 ``misses``, ``evictions`` and ``len()`` match it after every call.  The
 contents of an LRU are the ``capacity`` most recently touched keys, so a
 put keeps the newest stamps; its eviction count is the number of puts
@@ -237,8 +237,8 @@ class ResultCache:
 
         ``values`` holds each hit's value decoded as ``dtype`` (the raw
         bits with the default; unspecified where ``hit`` is False).
-        ``spaces`` gives wide keys their key spaces (sorted, aligned
-        with ``keys``).
+        ``spaces`` gives wide keys their key spaces (aligned with
+        ``keys``).
         """
         keys = np.asarray(keys, np.int64).ravel()
         with self._lock:
@@ -294,7 +294,7 @@ class ResultCache:
     # -- internals (lock held) --------------------------------------------
     def _space_runs(self, generation: int, spaces):
         """``[(id, lo, hi)]``: the cache id of each run of equal values
-        in the sorted ``spaces``.  Ids of pairs with no entry left are
+        in ``spaces``.  Ids of pairs with no entry left are
         forgotten once they outnumber twice the capacity (a fresh id
         finds nothing either)."""
         spaces = np.asarray(spaces, np.int64).ravel()

@@ -37,22 +37,14 @@ from typing import Dict
 
 import numpy as np
 
-from repro.core.hierarchy import pos_dtype_for
 from repro.obs import trace
-from repro.qe.executors import INDEX
+from repro.qe.executors import INDEX, dispatch, out_dtype
 from repro.qe.planner import _next_pow2
 
 __all__ = ["SEG_LOCAL", "CROSSING", "DistributedExecutor"]
 
 SEG_LOCAL = "seg_local"
 CROSSING = "crossing"
-
-
-def _out_dtype(index, op: str):
-    """Positions in the global coordinate dtype, values in the index's."""
-    if op == INDEX:
-        return np.dtype(pos_dtype_for(index.capacity, strict=False))
-    return np.dtype(index.value_dtype)
 
 
 class DistributedExecutor:
@@ -68,43 +60,7 @@ class DistributedExecutor:
     def run(self, index, ls: np.ndarray, rs: np.ndarray,
             op: str) -> np.ndarray:
         """Answer ``(ls, rs)`` (deduped global bounds) against ``index``."""
-        self.calls += 1
-        m = ls.shape[0]
-        self.queries += m
-        cap = index.segment_capacity
-        out_dtype = _out_dtype(index, op)
-        out = np.empty((m,), out_dtype)
-
-        tr = trace.current()
-        sp = tr.begin("route") if tr is not None else None
-        owner = ls // cap
-        local = owner == (rs // cap)
-        n_local = int(local.sum())
-        self.class_counts[SEG_LOCAL] += n_local
-        self.class_counts[CROSSING] += m - n_local
-        if tr is not None:
-            tr.end(sp, queries=m, seg_local=n_local, crossing=m - n_local,
-                   op=op)
-        cross_idx = np.nonzero(~local)[0]
-        if cross_idx.shape[0]:
-            sp = tr.begin("execute") if tr is not None else None
-            out[cross_idx] = self._run_crossing(
-                index, ls[cross_idx], rs[cross_idx], op, out_dtype
-            )
-            if tr is not None:
-                tr.end(sp, cls=CROSSING, count=int(cross_idx.shape[0]),
-                       op=op)
-        local_idx = np.nonzero(local)[0]
-        if local_idx.shape[0]:
-            sp = tr.begin("execute") if tr is not None else None
-            out[local_idx] = self._run_seg_local(
-                index, ls[local_idx], rs[local_idx], owner[local_idx], op,
-                out_dtype,
-            )
-            if tr is not None:
-                tr.end(sp, cls=SEG_LOCAL, count=int(local_idx.shape[0]),
-                       op=op)
-        return out
+        return self._route(index, ls, rs, op, bulk=False)
 
     def run_bulk(self, index, ls: np.ndarray, rs: np.ndarray,
                  op: str) -> np.ndarray:
@@ -122,13 +78,15 @@ class DistributedExecutor:
         a uniform batch) pay the ``pmin`` oracle.  No dedup, no LRU —
         bulk-scale batches bypass both by design.
         """
+        return self._route(index, ls, rs, op, bulk=True)
+
+    def _route(self, index, ls, rs, op, bulk: bool) -> np.ndarray:
         self.calls += 1
         m = ls.shape[0]
         self.queries += m
         cap = index.segment_capacity
-        c = index.plan.c
-        out_dtype = _out_dtype(index, op)
-        out = np.empty((m,), out_dtype)
+        dtype = out_dtype(index, op)
+        out = np.empty((m,), dtype)
 
         tr = trace.current()
         sp = tr.begin("route") if tr is not None else None
@@ -138,69 +96,59 @@ class DistributedExecutor:
         self.class_counts[SEG_LOCAL] += n_local
         self.class_counts[CROSSING] += m - n_local
         local_idx = np.nonzero(local)[0]
-        lsub, rsub = ls[local_idx], rs[local_idx]
-        osub = owner[local_idx]
-        lloc = lsub - osub * cap
-        rloc = rsub - osub * cap
-        sort = np.lexsort((rloc // c, lloc // c, osub))
-        if tr is not None:
-            tr.end(sp, queries=m, seg_local=n_local,
-                   crossing=m - n_local, op=op, strategy="bulk")
-
         cross_idx = np.nonzero(~local)[0]
+        if bulk:
+            c = index.plan.c
+            osub = owner[local_idx]
+            lloc = ls[local_idx] - osub * cap
+            rloc = rs[local_idx] - osub * cap
+            local_idx = local_idx[np.lexsort((rloc // c, lloc // c, osub))]
+        if tr is not None:
+            tr.end(sp, queries=m, seg_local=n_local, crossing=m - n_local,
+                   op=op, **({"strategy": "bulk"} if bulk else {}))
         if cross_idx.shape[0]:
-            sp = tr.begin("execute") if tr is not None else None
-            out[cross_idx] = self._run_crossing(
-                index, ls[cross_idx], rs[cross_idx], op, out_dtype
-            )
-            if tr is not None:
-                tr.end(sp, cls=CROSSING, count=int(cross_idx.shape[0]),
-                       op=op)
+            with trace.span("execute", cls=CROSSING,
+                            count=int(cross_idx.shape[0]), op=op):
+                out[cross_idx] = self._run_crossing(
+                    index, ls[cross_idx], rs[cross_idx], op, dtype)
         if local_idx.shape[0]:
-            sp = tr.begin("execute") if tr is not None else None
-            res = self._run_seg_local(
-                index, lsub[sort], rsub[sort], osub[sort], op, out_dtype
-            )
-            if tr is not None:
-                tr.end(sp, cls=SEG_LOCAL, count=int(local_idx.shape[0]),
-                       op=op)
-            sp = tr.begin("scatter") if tr is not None else None
-            out[local_idx[sort]] = res
-            if tr is not None:
+            with trace.span("execute", cls=SEG_LOCAL,
+                            count=int(local_idx.shape[0]), op=op):
+                res = self._run_seg_local(
+                    index, ls[local_idx], rs[local_idx], owner[local_idx],
+                    op, dtype)
+            sp = tr.begin("scatter") if tr is not None and bulk else None
+            out[local_idx] = res
+            if sp is not None:
                 tr.end(sp, queries=m, unique=m, op=op)
         return out
 
     # -- crossing spans: the pmin oracle, padded to bounded shapes --------
-    def _run_crossing(self, index, ls, rs, op, out_dtype) -> np.ndarray:
+    def _run_crossing(self, index, ls, rs, op, dtype) -> np.ndarray:
         k = ls.shape[0]
         shape = min(
             max(_next_pow2(k), self.min_bucket), self.max_bucket
         )
-        tr = trace.current()
-        pending = []
-        for lo in range(0, k, shape):
-            sp = tr.begin("launch") if tr is not None else None
+        res = np.empty((k,), dtype)
+
+        def launch(lo):
             cnt = min(shape, k - lo)
             pl = np.zeros((shape,), ls.dtype)
             pr = np.zeros((shape,), rs.dtype)
             pl[:cnt] = ls[lo : lo + cnt]
             pr[:cnt] = rs[lo : lo + cnt]
-            r = index.query_index(pl, pr) if op == INDEX \
+            return index.query_index(pl, pr) if op == INDEX \
                 else index.query(pl, pr)
-            pending.append((lo, cnt, r))
-            if tr is not None:
-                tr.end(sp, cls=CROSSING)
-        res = np.empty((k,), out_dtype)
-        for lo, cnt, r in pending:
-            sp = tr.begin("fetch") if tr is not None else None
+
+        def fetch(lo, r):
+            cnt = min(shape, k - lo)
             res[lo : lo + cnt] = np.asarray(r)[:cnt]
-            if tr is not None:
-                tr.end(sp, cls=CROSSING)
+
+        dispatch(range(0, k, shape), launch, fetch, cls=CROSSING)
         return res
 
     # -- contained spans: grouped per owner, answered without collectives -
-    def _run_seg_local(self, index, ls, rs, owner, op,
-                       out_dtype) -> np.ndarray:
+    def _run_seg_local(self, index, ls, rs, owner, op, dtype) -> np.ndarray:
         cap = index.segment_capacity
         s = index.num_segments
         # stable sort by owner -> contiguous per-segment runs; row_pos is
@@ -213,13 +161,12 @@ class DistributedExecutor:
         # localize in the global dtype, then narrow: local bounds < cap
         lloc = (ls[order] - so * cap).astype(np.int32)
         rloc = (rs[order] - so * cap).astype(np.int32)
-        tr = trace.current()
-        pending = []
+        picked = np.empty((so.shape[0],), dtype)
+
         # row width is bounded at max_bucket (same discipline as the
         # planner's buckets): a skewed batch runs in several rounds of
         # already-compiled shapes instead of tracing one giant one
-        for lo in range(0, int(counts.max()), self.max_bucket):
-            sp = tr.begin("launch") if tr is not None else None
+        def launch(lo):
             sel = (row_pos >= lo) & (row_pos < lo + self.max_bucket)
             rp = row_pos[sel] - lo
             k = max(_next_pow2(int(rp.max()) + 1), self.min_bucket)
@@ -230,17 +177,15 @@ class DistributedExecutor:
             vals, poss = index._query_grouped(
                 gl, gr, track_pos=(op == INDEX)
             )
-            pending.append((sel, rp, poss if op == INDEX else vals))
-            if tr is not None:
-                tr.end(sp, cls=SEG_LOCAL)
-        picked = np.empty((so.shape[0],), out_dtype)
-        for sel, rp, r in pending:
-            sp = tr.begin("fetch") if tr is not None else None
-            picked[sel] = np.asarray(r)[so[sel], rp].astype(
-                out_dtype, copy=False)
-            if tr is not None:
-                tr.end(sp, cls=SEG_LOCAL)
-        res = np.empty((ls.shape[0],), out_dtype)
+            return sel, rp, poss if op == INDEX else vals
+
+        def fetch(lo, pending):
+            sel, rp, r = pending
+            picked[sel] = np.asarray(r)[so[sel], rp]
+
+        dispatch(range(0, int(counts.max()), self.max_bucket), launch,
+                 fetch, cls=SEG_LOCAL)
+        res = np.empty((ls.shape[0],), dtype)
         res[order] = picked
         return res
 

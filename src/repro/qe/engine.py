@@ -7,32 +7,26 @@ One engine serves one index — anything implementing the
 bookkeeping run in numpy; only the packed buckets touch the device,
 through persistent jitted callables (see :mod:`repro.qe.executors`).
 
-Execution pipeline per batch::
+Every query method (``query``, ``query_index``, ``query_mixed``, and
+``query_bulk`` below its crossover) runs one pipeline, ``_answer``::
 
-    validate -> pack keys ((l << 31) | r, one int64 per query;
-                          past 2^31 a key space beside it)
-             -> dedup (np.unique on the keys)
-             -> LRU lookup (ResultCache.get_many: one call per batch)
-             -> planner buckets -> per-class executors
-             -> LRU insert (ResultCache.put_many) -> scatter-back
+    validate on the host -> pack keys ((l << 31) | r, one int64 per
+                            query; past 2^31 a key space beside it)
+             -> dedup on (l, r) pairs (np.unique on the keys)
+             -> LRU lookup of the (pair, op) entries needed (get_many)
+             -> the misses to the miss executor, once per op
+             -> LRU insert (put_many) -> scatter-back
 
 Every host step is an array operation over the batch; none runs Python
-per query.
-
-For single-hierarchy indices the miss classes are short / mid / long span
-buckets; for distributed indices the planner is replaced by the
-segment-aware :class:`repro.qe.distributed.DistributedExecutor`
-(segment-contained spans answered shard-locally with no all-reduce,
-crossing spans through the ``pmin`` path).
-
-With the **fused** runtime backend the engine prefers the
-:class:`repro.qe.executors.FusedExecutor`: the planner degrades to a
-single bucket class (``kernels/rmq_fused`` decomposes spans in-kernel,
-so the short/mid/long split buys nothing) and each bucket is one
-launch; :meth:`QueryEngine.query_mixed` additionally serves a batch
-mixing value and index ops from that same single launch (both output
-planes come out of one kernel call).  Dedup, the LRU result cache, and
-the service's coalescing all operate unchanged on top.
+per query.  :meth:`attach` binds the miss executor: over one hierarchy
+:class:`repro.qe.executors.RoutedExecutor` (short / mid / long span
+buckets); over a sharded index the segment-aware
+:class:`repro.qe.distributed.DistributedExecutor` (contained spans
+shard-locally with no all-reduce, crossing spans through ``pmin``).
+With the **fused** runtime backend the planner degrades to a single
+bucket class (``kernels/rmq_fused`` decomposes spans in-kernel) and a
+batch mixing value and index ops runs its misses as one launch per
+bucket with both output planes.
 
 Results are bit-identical — values *and* leftmost-tie positions — to
 the index's monolithic oracles (``rmq_value_batch``/``rmq_index_batch``,
@@ -63,7 +57,7 @@ from repro.core.protocol import (
     live_length,
     runtime_backend,
 )
-from repro.core.query import check_query_args
+from repro.core.query import check_host_bounds
 from repro.kernels.common import check_query_vmem, resolve_interpret
 from repro.kernels.profiling import record_config
 from repro.obs import trace
@@ -82,12 +76,11 @@ from repro.qe.cache import (
 from repro.qe.distributed import DistributedExecutor
 from repro.qe.executors import (
     INDEX,
+    MIXED,
     VALUE,
     BulkExecutor,
-    FusedExecutor,
-    LongSpanExecutor,
-    MidSpanExecutor,
-    ShortSpanExecutor,
+    RoutedExecutor,
+    out_dtype,
 )
 from repro.qe.planner import FUSED, LONG, MID, SHORT, QueryPlanner
 
@@ -99,6 +92,19 @@ def _quantized(index) -> bool:
     return (
         getattr(index.plan, "summary_dtype", "float32") == "bfloat16"
     )
+
+
+def _pick(a, rows):
+    """``a[rows]`` for ascending distinct ``rows``, without the copy where
+    they are all of ``a``."""
+    return a if rows.shape[0] == a.shape[0] else a[rows]
+
+
+def _join(parts):
+    """``np.concatenate(parts)``, without the copy where only one part
+    holds anything."""
+    full = [p for p in parts if p.shape[0]]
+    return full[0] if len(full) == 1 else np.concatenate(parts)
 
 
 class QueryEngine:
@@ -141,19 +147,15 @@ class QueryEngine:
         self.cache = ResultCache(cache_size)
         self.tuned: Optional[dict] = None  # resolved config provenance
         self.backend = self._resolve_backend(index)
-        self._configure_executors(self.backend)
+        self._routed = RoutedExecutor(self.backend, interpret=interpret)
         self.batches = 0
         self.queries_in = 0
         self.dedup_saved = 0
-        self.class_counts = {SHORT: 0, MID: 0, LONG: 0, FUSED: 0}
         self._index = None
-        self.planner: Optional[QueryPlanner] = None
         self.distributed: Optional[DistributedExecutor] = None
+        self._miss = self._routed      # the bound miss executor
         self.metrics: Optional[Metrics] = None
         self._coord = np.dtype(np.int32)  # coordinate dtype, per attach
-        self._m_padding = None
-        self._m_padded_lanes = None
-        self._m_live_lanes = None
         self._m_tuned = None
         if metrics is not None:
             self._register_metrics(metrics)
@@ -222,7 +224,7 @@ class QueryEngine:
         }
 
     def _resolve_bulk_crossover(self, index) -> int:
-        """Batch size at which :meth:`query_bulk` leaves the fused path.
+        """Batch size at which :meth:`query_bulk` leaves the routed path.
 
         Same precedence as the rest of the config: explicit ctor kwarg >
         tuned cache (``bulk_crossover`` measured by the Autotuner) >
@@ -240,20 +242,6 @@ class QueryEngine:
         plan = index.plan
         rows = max(index.capacity // plan.c, 1)
         return max(1024, rows * max(plan.c.bit_length() - 1, 1))
-
-    def _configure_executors(self, backend: str) -> None:
-        """(Re)build the executor table for ``backend`` — called at
-        construction and when an attach adopts a different tuned
-        backend (dropping the old backend's compiled tables)."""
-        self.executors = {
-            SHORT: ShortSpanExecutor(backend, interpret=self._interpret),
-            MID: MidSpanExecutor(backend, interpret=self._interpret),
-            LONG: LongSpanExecutor(),
-        }
-        if backend == "fused":
-            # the whole span mix in one launch per bucket — the per-class
-            # executors above never run (the planner emits FUSED only)
-            self.executors[FUSED] = FusedExecutor(interpret=self._interpret)
 
     def _register_metrics(self, metrics: Metrics) -> None:
         """Export engine state into ``metrics``.
@@ -273,24 +261,17 @@ class QueryEngine:
         metrics.gauge("batches", fn=lambda: self.batches)
         metrics.gauge("queries", fn=lambda: self.queries_in)
         metrics.gauge("dedup_saved", fn=lambda: self.dedup_saved)
+        counts = self._routed.class_counts
         for cls in (SHORT, MID, LONG, FUSED):
-            metrics.gauge(f"span_class_{cls}",
-                          fn=lambda c=cls: self.class_counts[c])
-        self._m_padding = metrics.histogram(
-            "bucket_padding_waste", SIZE_BUCKETS)
-        self._m_padded_lanes = metrics.counter("padded_lanes")
-        self._m_live_lanes = metrics.counter("live_lanes")
+            metrics.gauge(f"span_class_{cls}", fn=lambda c=cls: counts[c])
+        self._routed.lanes = (
+            metrics.histogram("bucket_padding_waste", SIZE_BUCKETS),
+            metrics.counter("padded_lanes"),
+            metrics.counter("live_lanes"),
+        )
         self._m_tuned = metrics.info("tuned_config")
         if self.tuned is not None:
             self._m_tuned.set({k: str(v) for k, v in self.tuned.items()})
-
-    def _note_bucket(self, bucket) -> None:
-        """Per-bucket accounting shared by both execution paths."""
-        self.class_counts[bucket.cls] += bucket.count
-        if self._m_padding is not None:
-            self._m_padding.record(bucket.padding)
-            self._m_padded_lanes.inc(bucket.padding)
-            self._m_live_lanes.inc(bucket.count)
 
     @classmethod
     def for_index(cls, index, **kwargs) -> "QueryEngine":
@@ -304,6 +285,11 @@ class QueryEngine:
     @property
     def generation(self) -> int:
         return getattr(self._index, "generation", 0)
+
+    @property
+    def planner(self) -> Optional[QueryPlanner]:
+        """The routed executor's planner (``None`` on a sharded index)."""
+        return self._routed.planner
 
     def attach(self, index, reset_cache: Optional[bool] = None) -> None:
         """Bind a (successor) index.
@@ -337,7 +323,7 @@ class QueryEngine:
         if is_distributed(index):
             # Sharded index: routing is by segment containment, not span
             # class — the planner and span executors never run.
-            self.planner = None
+            self._routed.planner = None
             self.tuned = None
             self.bulk_crossover = self._resolve_bulk_crossover(index)
             if self.distributed is None:
@@ -345,8 +331,10 @@ class QueryEngine:
                     min_bucket=self._min_bucket,
                     max_bucket=self._max_bucket,
                 )
+            self._miss = self.distributed
         else:
             self.distributed = None
+            self._miss = self._routed
             # Re-resolve the tuned config against the new binding: a
             # successor index may carry a different plan (and cache
             # lookups key on the live length).  Adopting a different
@@ -354,7 +342,7 @@ class QueryEngine:
             backend = self._resolve_backend(index)
             if backend != self.backend:
                 self.backend = backend
-                self._configure_executors(backend)
+                self._routed.configure(backend)
             if backend in ("pallas", "fused") and not resolve_interpret(
                 self._interpret
             ):
@@ -367,7 +355,7 @@ class QueryEngine:
             resolved = self._resolve_config(index)
             self.bulk_crossover = self._resolve_bulk_crossover(index)
             resolved["bulk_crossover"] = self.bulk_crossover
-            planner = QueryPlanner(
+            self._routed.planner = QueryPlanner(
                 c=plan.c,
                 num_levels=plan.num_levels,
                 long_cutoff=resolved["long_cutoff"],
@@ -377,11 +365,9 @@ class QueryEngine:
                 fused=self.backend == "fused",
                 scan_chunks=resolved["scan_chunks"],
             )
-            if planner != self.planner:
-                self.planner = planner
             self._record_tuned(index, resolved)
         self._index = index
-        self.executors[LONG].invalidate()
+        self._routed.invalidate()
 
     def _record_tuned(self, index, resolved: dict) -> None:
         """Expose the chosen config: ``stats()["tuned"]``, the launch
@@ -408,16 +394,12 @@ class QueryEngine:
     # -- public query surface ---------------------------------------------
     def query(self, ls, rs) -> jnp.ndarray:
         """Batched ``RMQ_value``; bit-identical to the index's oracle."""
-        return self._execute(ls, rs, VALUE)
+        return self._answer(*self._bounds(ls, rs), VALUE)
 
     def query_index(self, ls, rs) -> jnp.ndarray:
         """Batched ``RMQ_index``; bit-identical to the index's oracle."""
-        if not self._index.with_positions:
-            raise ValueError(
-                "index was built without positions; rebuild it with "
-                "with_positions=True to serve RMQ_index queries"
-            )
-        return self._execute(ls, rs, INDEX)
+        self._require_positions()
+        return self._answer(*self._bounds(ls, rs), INDEX)
 
     def query_bulk(self, ls, rs, op: str = VALUE) -> jnp.ndarray:
         """Offline bulk-analytics batch (``op`` = ``"value"``/``"index"``).
@@ -431,8 +413,9 @@ class QueryEngine:
         positions — at any batch size.
 
         Batches below :attr:`bulk_crossover` (explicit kwarg > autotuned
-        cache > analytic model) take the standard fused path instead:
-        below the crossover the bulk pass's fixed ladder cost loses, and
+        cache > analytic model) take the routed pipeline instead (dedup,
+        the LRU, the miss executor; fused only on a fused engine): below
+        the crossover the bulk pass's fixed ladder cost loses, and
         dedup + the LRU still pay for themselves.  At and above it both
         are skipped — per-query caching is pure overhead at bulk scale.
         On a distributed index the endpoint sort also groups queries by
@@ -444,20 +427,14 @@ class QueryEngine:
             raise ValueError(
                 f"op must be {VALUE!r} or {INDEX!r}, got {op!r}"
             )
+        if op == INDEX:
+            self._require_positions()
         index = self._index
-        if op == INDEX and not index.with_positions:
-            raise ValueError(
-                "index was built without positions; rebuild it with "
-                "with_positions=True to serve RMQ_index queries"
-            )
         # the root span of one batch: every engine span below nests in it
         tr = trace.current()
         sp = tr.begin("query_bulk") if tr is not None else None
         try:
-            n = live_length(index)
-            ls, rs = check_query_args(ls, rs, n)
-            ls = np.asarray(ls, self._coord).ravel()
-            rs = np.asarray(rs, self._coord).ravel()
+            ls, rs = self._bounds(ls, rs)
             # bf16 summaries: the coalesced bulk sweep compares quantized
             # level-1 values with no exact-recovery pass, so bf16 indexes
             # always take the routed path (whose walks re-read level 0).
@@ -468,18 +445,15 @@ class QueryEngine:
                 sp.args.update(queries=int(ls.shape[0]),
                                route="routed" if routed else "bulk")
             if routed:
-                return self._execute(ls, rs, op)
+                return self._answer(ls, rs, op)
             self.batches += 1
             self.queries_in += ls.shape[0]
             if self.distributed is not None:
                 res = self.distributed.run_bulk(index, ls, rs, op)
             else:
                 res = self._bulk.run(index.hierarchy, ls, rs, op)
-            out_dtype = (
-                self._coord if op == INDEX else np.dtype(index.value_dtype)
-            )
-            return jnp.asarray(
-                np.asarray(res).astype(out_dtype, copy=False))
+            return jnp.asarray(np.asarray(res).astype(
+                out_dtype(index, op), copy=False))
         finally:
             if sp is not None:
                 tr.end(sp)
@@ -493,7 +467,7 @@ class QueryEngine:
         coalesce a registered index's value and index groups into one
         execution instead of two.
         """
-        return FUSED in self.executors and self.distributed is None
+        return self.distributed is None and FUSED in self._routed.executors
 
     def query_mixed(self, ls, rs, is_index) -> tuple:
         """Answer a batch mixing ``RMQ_value`` and ``RMQ_index`` ops.
@@ -501,179 +475,53 @@ class QueryEngine:
         ``is_index[i]`` selects row ``i``'s op.  Returns ``(values,
         positions)`` numpy arrays of the batch length; only the plane
         selected by ``is_index`` is meaningful per row (the other
-        plane's entry is unspecified).  On a fused engine the whole
-        deduped miss batch executes through :class:`FusedExecutor` with
-        both planes from the same launch; elsewhere it falls back to one
-        standard execution per op.  Results are bit-identical to
-        :meth:`query` / :meth:`query_index` row-wise.
+        plane's entry is unspecified).  On a fused engine the misses of
+        a batch holding both ops run with both planes from one launch;
+        elsewhere the miss executor runs once per op.  Results (and
+        cache entries) are those of :meth:`query` / :meth:`query_index`.
         """
-        index = self._index
         is_index = np.asarray(is_index, bool).ravel()
-        if is_index.any() and not index.with_positions:
-            raise ValueError(
-                "index was built without positions; rebuild it with "
-                "with_positions=True to serve RMQ_index queries"
-            )
-        n = live_length(index)
-        ls, rs = check_query_args(ls, rs, n)
-        ls = np.asarray(ls, self._coord).ravel()
-        rs = np.asarray(rs, self._coord).ravel()
+        if is_index.any():
+            self._require_positions()
+        ls, rs = self._bounds(ls, rs)
         if ls.shape != is_index.shape:
             raise ValueError(
                 f"is_index must match the batch, got {is_index.shape} "
                 f"vs {ls.shape}"
             )
-        m = ls.shape[0]
-        val_dtype = np.dtype(index.value_dtype)
-        vals_out = np.zeros((m,), val_dtype)
-        pos_out = np.zeros((m,), self._coord)
-        if m == 0:
-            return vals_out, pos_out
-
-        single_op = is_index.all() or not is_index.any()
-        if not self.supports_mixed or single_op:
-            # per-op path: also taken by genuinely single-op batches on
-            # fused engines — the dual-plane launch would waste the
-            # unused plane (and track positions value-only builds lack)
-            vi = np.nonzero(~is_index)[0]
-            ii = np.nonzero(is_index)[0]
-            if vi.shape[0]:
-                vals_out[vi] = np.asarray(
-                    self._execute(ls[vi], rs[vi], VALUE)
-                )
-            if ii.shape[0]:
-                pos_out[ii] = np.asarray(
-                    self._execute(ls[ii], rs[ii], INDEX)
-                )
-            return vals_out, pos_out
-
-        self.batches += 1
-        self.queries_in += m
-
-        # Dedup on (l, r) pairs — the fused launch computes both planes
-        # for every query anyway, so value and index requests for the
-        # same range share one execution.
-        tr = trace.current()
-        sp = tr.begin("dedup") if tr is not None else None
-        ukeys, inverse = np.unique(pack_keys(ls, rs), return_inverse=True)
-        k = ukeys.shape[0]
-        self.dedup_saved += m - k
-        if tr is not None:
-            tr.end(sp, queries=m, unique=k)
-        uv = np.zeros((k,), val_dtype)
-        up = np.zeros((k,), np.int32)
-        need_val = np.zeros((k,), bool)
-        need_pos = np.zeros((k,), bool)
-        need_val[inverse[~is_index]] = True
-        need_pos[inverse[is_index]] = True
-
-        # one cache entry per (op, pair) needed: pair i's value entry,
-        # then its index entry, in pair order.  Flat index e = 2 * pair
-        # + op bit (OP_BITS: value 0, index 1), so the entry keys
-        # 2 * key + bit ascend with e.
-        need = np.stack([need_val, need_pos], axis=1)
-        gen = self.generation
-        if self.cache.capacity > 0:
-            sp = self._begin_cache_get(tr)
-            e = np.flatnonzero(need)
-            rows, is_pos = e >> 1, (e & 1).astype(bool)
-            bits, hit = self.cache.get_many(
-                gen, entry_keys(ukeys[rows], e & 1))
-            sel = hit & ~is_pos
-            uv[rows[sel]] = from_bits(bits[sel], val_dtype)
-            sel = hit & is_pos
-            up[rows[sel]] = from_bits(bits[sel], np.int32)
-            missing = np.zeros((k,), bool)
-            missing[rows[~hit]] = True
-            miss_idx = np.flatnonzero(missing)
-            if tr is not None:
-                self._end_cache_get(tr, sp)
-        else:
-            miss_idx = np.arange(k)
-
-        if miss_idx.shape[0]:
-            h = index.hierarchy
-            fused = self.executors[FUSED]
-            mls, mrs = unpack_keys(ukeys[miss_idx])
-            sp = tr.begin("plan") if tr is not None else None
-            buckets = self.planner.plan(mls, mrs)
-            if tr is not None:
-                tr.end(sp, misses=int(miss_idx.shape[0]),
-                       buckets=len(buckets), op="mixed")
-            for bucket in buckets:
-                if bucket.count == 0:
-                    continue
-                self._note_bucket(bucket)
-                sp = tr.begin("execute") if tr is not None else None
-                sub = tr.begin("launch") if tr is not None else None
-                bv, bp = fused.run_mixed(
-                    h, jnp.asarray(bucket.ls), jnp.asarray(bucket.rs)
-                )
-                if tr is not None:
-                    tr.end(sub)
-                    sub = tr.begin("fetch")
-                rows = miss_idx[bucket.idxs]
-                uv[rows] = np.asarray(bv)[: bucket.count].astype(
-                    val_dtype, copy=False
-                )
-                up[rows] = np.asarray(bp)[: bucket.count]
-                if tr is not None:
-                    tr.end(sub)
-                    tr.end(sp, cls=bucket.cls, count=bucket.count,
-                           shape=bucket.shape, op="mixed")
-            if self.cache.capacity > 0:
-                sp = tr.begin("cache_put") if tr is not None else None
-                e = np.flatnonzero(need[miss_idx])
-                rows = miss_idx[e >> 1]
-                bits = np.where(e & 1, to_bits(up[rows]),
-                                to_bits(uv[rows]))
-                self.cache.put_many(gen, entry_keys(ukeys[rows], e & 1),
-                                    bits)
-                if tr is not None:
-                    tr.end(sp, entries=int(miss_idx.shape[0]))
-
-        sp = tr.begin("scatter") if tr is not None else None
-        out = uv[inverse], up[inverse]
-        if tr is not None:
-            tr.end(sp, queries=m, unique=k, op="mixed")
-        return out
+        return self._answer(ls, rs, is_index)
 
     # -- execution --------------------------------------------------------
-    def _begin_cache_get(self, tr):
-        """Open the ``cache_get`` span holding the cache's counters; its
-        end replaces them by their deltas (nothing counts per query)."""
-        if tr is None:
-            return None
-        sp = tr.begin("cache_get")
-        sp.args.update(hits=self.cache.hits, misses=self.cache.misses)
-        return sp
+    def _require_positions(self) -> None:
+        if not self._index.with_positions:
+            raise ValueError(
+                "index was built without positions; rebuild it with "
+                "with_positions=True to serve RMQ_index queries"
+            )
 
-    def _end_cache_get(self, tr, sp) -> None:
-        hits = self.cache.hits - sp.args["hits"]
-        misses = self.cache.misses - sp.args["misses"]
-        tr.end(sp, lookups=hits + misses, hits=hits, misses=misses)
+    def _bounds(self, ls, rs):
+        """The batch's bounds checked on the host (no device copy) and
+        flattened in the coordinate dtype."""
+        ls, rs = check_host_bounds(ls, rs, live_length(self._index))
+        return (ls.astype(self._coord, copy=False).ravel(),
+                rs.astype(self._coord, copy=False).ravel())
 
-    # NOTE: query_mixed above carries a dual-plane variant of this
-    # dedup -> LRU -> bucket-execute -> cache-writeback pipeline (its
-    # cache entries are per-op, its execution per-(l,r) pair); cache or
-    # dedup semantics changed here must change there too.
-    def _execute(self, ls, rs, op: str) -> jnp.ndarray:
+    def _answer(self, ls, rs, ops):
+        """The pipeline of every query method over validated bounds.
+        ``ops``: ``VALUE`` or ``INDEX`` for the whole batch (one device
+        array back), or a bool array, ``True`` where a row asks for its
+        position (``(values, positions)`` numpy arrays back)."""
         index = self._index
-        n = live_length(index)
-        ls, rs = check_query_args(ls, rs, n)
-        ls = np.asarray(ls, self._coord).ravel()
-        rs = np.asarray(rs, self._coord).ravel()
+        mixed = not isinstance(ops, str)
+        # one plane per op, in OP_BITS order
+        dtypes = [out_dtype(index, VALUE), out_dtype(index, INDEX)]
         m = ls.shape[0]
-        out_dtype = (
-            self._coord if op == INDEX else np.dtype(index.value_dtype)
-        )
         if m == 0:
-            return jnp.zeros((0,), out_dtype)
-
+            out = [np.zeros((0,), dt) for dt in dtypes]
+            return tuple(out) if mixed else jnp.asarray(out[OP_BITS[ops]])
         self.batches += 1
         self.queries_in += m
 
-        # -- within-batch dedup -------------------------------------------
         tr = trace.current()
         sp = tr.begin("dedup") if tr is not None else None
         spaces = None       # wide keys' key spaces (coordinates >= 2^31)
@@ -686,81 +534,80 @@ class QueryEngine:
         self.dedup_saved += m - k
         if tr is not None:
             tr.end(sp, queries=m, unique=k)
-        uniq_res = np.empty((k,), out_dtype)
 
-        # -- LRU lookup ---------------------------------------------------
+        # the pairs asked for each op (in OP_BITS order), ascending; a
+        # pair's cache entry for an op is entry_keys(its key, op bit)
+        need = np.zeros((2, k), bool)
+        if mixed:
+            need[ops.astype(np.intp), inverse] = True
+        else:
+            need[OP_BITS[ops]] = True
+        want = [np.flatnonzero(row) for row in need]
+        planes = [np.zeros((k,), dt) for dt in dtypes]
+        missing = want
         gen = self.generation
         if self.cache.capacity > 0:
-            sp = self._begin_cache_get(tr)
-            ekeys = entry_keys(ukeys, OP_BITS[op])
-            vals, hit = self.cache.get_many(gen, ekeys, out_dtype, spaces)
-            uniq_res[hit] = vals[hit]
-            miss_idx = np.flatnonzero(~hit)
+            # one lookup: the value entries, then the index entries
+            sp = tr.begin("cache_get") if tr is not None else None
+            ekeys = _join([entry_keys(_pick(ukeys, w), b)
+                           for b, w in enumerate(want)])
+            espaces = None if spaces is None else _join(
+                [_pick(spaces, w) for w in want])
+            raw, hit = self.cache.get_many(gen, ekeys, spaces=espaces)
+            cut = [want[0].shape[0]]
+            missing = []
+            for w, plane, r, h in zip(want, planes, np.split(raw, cut),
+                                      np.split(hit, cut)):
+                plane[_pick(w, np.flatnonzero(h))] = from_bits(r[h],
+                                                               plane.dtype)
+                missing.append(_pick(w, np.flatnonzero(~h)))
             if tr is not None:
-                self._end_cache_get(tr, sp)
-        else:
-            miss_idx = np.arange(k)
+                hits = int(hit.sum())
+                tr.end(sp, lookups=hit.shape[0], hits=hits,
+                       misses=hit.shape[0] - hits)
 
-        # -- plan + execute the misses ------------------------------------
-        if miss_idx.shape[0]:
-            if spaces is None:
-                mls, mrs = unpack_keys(ukeys[miss_idx])
+        if missing[0].shape[0] or missing[1].shape[0]:
+            def bounds(pairs):
+                if spaces is None:
+                    return unpack_keys(_pick(ukeys, pairs))
+                return join_keys(_pick(spaces, pairs), _pick(ukeys, pairs))
+
+            if mixed and self.supports_mixed and 0 < ops.sum() < m:
+                # both planes of every missed pair from one launch
+                pairs = np.union1d(*missing)
+                planes[0][pairs], planes[1][pairs] = self._miss.run_mixed(
+                    index, *bounds(pairs))
             else:
-                mls, mrs = join_keys(spaces[miss_idx], ukeys[miss_idx])
-            if self.distributed is not None:
-                res = self.distributed.run(index, mls, mrs, op)
-                uniq_res[miss_idx] = res.astype(out_dtype, copy=False)
-            else:
-                h = index.hierarchy
-                sp = tr.begin("plan") if tr is not None else None
-                buckets = self.planner.plan(mls, mrs)
-                if tr is not None:
-                    tr.end(sp, misses=int(miss_idx.shape[0]),
-                           buckets=len(buckets), op=op)
-                for bucket in buckets:
-                    if bucket.count == 0:
-                        continue
-                    self._note_bucket(bucket)
-                    sp = tr.begin("execute") if tr is not None else None
-                    sub = tr.begin("launch") if tr is not None else None
-                    res = self.executors[bucket.cls].run(
-                        h, jnp.asarray(bucket.ls), jnp.asarray(bucket.rs),
-                        op,
-                    )
-                    if tr is not None:
-                        tr.end(sub)
-                        sub = tr.begin("fetch")
-                    res = np.asarray(res)[: bucket.count].astype(
-                        out_dtype, copy=False
-                    )
-                    if tr is not None:
-                        tr.end(sub)
-                        tr.end(sp, cls=bucket.cls, count=bucket.count,
-                               shape=bucket.shape, op=op,
-                               **self.executors[bucket.cls].span_args(h))
-                    uniq_res[miss_idx[bucket.idxs]] = res
+                for b, (pairs, op) in enumerate(zip(missing, OP_BITS)):
+                    if pairs.shape[0] == k:     # every pair: no scatter
+                        planes[b] = self._miss.run(index, *bounds(pairs), op)
+                    elif pairs.shape[0]:
+                        planes[b][pairs] = self._miss.run(
+                            index, *bounds(pairs), op)
             if self.cache.capacity > 0:
                 sp = tr.begin("cache_put") if tr is not None else None
+                put = _join([to_bits(_pick(plane, pairs))
+                             for plane, pairs in zip(planes, missing)])
                 self.cache.put_many(
-                    gen, ekeys[miss_idx], uniq_res[miss_idx],
-                    None if spaces is None else spaces[miss_idx])
+                    gen, ekeys[~hit], put,
+                    None if espaces is None else espaces[~hit])
                 if tr is not None:
-                    tr.end(sp, entries=int(miss_idx.shape[0]))
+                    tr.end(sp, entries=int(put.shape[0]))
 
         sp = tr.begin("scatter") if tr is not None else None
-        out = jnp.asarray(uniq_res[inverse])
+        if mixed:
+            out = planes[0][inverse], planes[1][inverse]
+        else:
+            out = jnp.asarray(planes[OP_BITS[ops]][inverse])
         if tr is not None:
-            tr.end(sp, queries=m, unique=k, op=op)
+            tr.end(sp, queries=m, unique=k, op=MIXED if mixed else ops)
         return out
 
     # -- introspection ----------------------------------------------------
     def stats(self) -> dict:
-        counts = dict(self.class_counts)
-        executors = {
-            cls: ex.stats() for cls, ex in self.executors.items()
-        }
+        counts = dict(self._miss.class_counts)
+        executors = self._routed.stats()
         if self.distributed is not None:
-            counts = dict(self.distributed.class_counts)
             executors = {"distributed": self.distributed.stats()}
         if self._bulk.calls:
             executors["bulk"] = self._bulk.stats()

@@ -15,27 +15,34 @@ O(1) top — not a lowering).
 
 ``backend="fused"`` replaces the whole per-class trio with
 :class:`FusedExecutor`: one ``kernels/rmq_fused`` dispatch answers the
-entire bucket — every span class, and (via :meth:`FusedExecutor.run_mixed`)
-value and index ops in the same launch.
+entire bucket — every span class, and (with op ``"mixed"``) value and
+index ops in the same launch.
+
+:class:`RoutedExecutor` answers the engine's misses over one hierarchy.
+Every bucket loop of the package runs through :func:`dispatch`.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.hierarchy import Hierarchy
+from repro.core.hierarchy import Hierarchy, pos_dtype_for
 from repro.obs import trace
+from repro.qe.planner import FUSED, LONG, MID, SHORT, _next_pow2
 
 __all__ = [
     "ShortSpanExecutor",
     "MidSpanExecutor",
     "LongSpanExecutor",
     "FusedExecutor",
+    "RoutedExecutor",
     "BulkExecutor",
+    "dispatch",
 ]
 
 VALUE = "value"
@@ -43,10 +50,45 @@ INDEX = "index"
 MIXED = "mixed"
 
 
+def out_dtype(index, op: str) -> np.dtype:
+    """Positions in the index's coordinate dtype, values in its own."""
+    if op == INDEX:
+        return np.dtype(pos_dtype_for(index.capacity, strict=False))
+    return np.dtype(index.value_dtype)
+
+
+def dispatch(jobs, launch, fetch, execute=None, **args) -> None:
+    """The one bucket loop: ``launch(job)`` starts a job on the device,
+    ``fetch(job, result)`` waits for it and copies it back, each in a
+    ``launch`` / ``fetch`` span holding ``args``.  With ``execute`` (a
+    job's ``execute`` span args) each job runs in its own ``execute``
+    span and is fetched before the next launches; without it all jobs
+    launch before the first fetch, in the caller's ``execute`` span."""
+    tr = trace.current()
+    for group in ([job] for job in jobs) if execute else [jobs]:
+        sp = tr.begin("execute") if tr is not None and execute else None
+        pending = []
+        for job in group:
+            sub = tr.begin("launch") if tr is not None else None
+            pending.append(launch(job))
+            if tr is not None:
+                tr.end(sub, **args)
+        for job, res in zip(group, pending):
+            sub = tr.begin("fetch") if tr is not None else None
+            fetch(job, res)
+            if tr is not None:
+                tr.end(sub, **args)
+        if sp is not None:
+            tr.end(sp, **execute(group[0]))
+
+
 class _ExecutorBase:
     """Shared bookkeeping: the (op, shape) -> callable table and stats."""
 
-    def __init__(self):
+    def __init__(self, backend: Optional[str] = None,
+                 interpret: Optional[bool] = None):
+        self.backend = backend
+        self.interpret = interpret
         self._compiled: Dict[Tuple[str, int], Callable] = {}
         self.calls = 0
         self.queries = 0
@@ -83,22 +125,14 @@ class _ExecutorBase:
 class ShortSpanExecutor(_ExecutorBase):
     """Two-chunk level-0 scan; never touches the hierarchy."""
 
-    def __init__(self, backend: str, interpret: Optional[bool] = None):
-        super().__init__()
-        self.backend = backend
-        self.interpret = interpret
-
     def _make(self, h: Hierarchy, op: str) -> Callable:
         from repro.kernels.rmq_short import ops as short_ops
 
         if self.backend == "pallas":
-            if op == VALUE:
-                return lambda h, ls, rs: short_ops.rmq_short_value_batch_pallas(
-                    h, ls, rs, interpret=self.interpret
-                )
-            return lambda h, ls, rs: short_ops.rmq_short_index_batch_pallas(
-                h, ls, rs, interpret=self.interpret
-            )
+            return functools.partial(
+                short_ops.rmq_short_value_batch_pallas if op == VALUE
+                else short_ops.rmq_short_index_batch_pallas,
+                interpret=self.interpret)
         if op == VALUE:
             return short_ops.rmq_short_value_batch
         return short_ops.rmq_short_index_batch
@@ -107,22 +141,14 @@ class ShortSpanExecutor(_ExecutorBase):
 class MidSpanExecutor(_ExecutorBase):
     """The standard full hierarchy walk (the previous monolithic path)."""
 
-    def __init__(self, backend: str, interpret: Optional[bool] = None):
-        super().__init__()
-        self.backend = backend
-        self.interpret = interpret
-
     def _make(self, h: Hierarchy, op: str) -> Callable:
         if self.backend == "pallas":
             from repro.kernels.rmq_scan import ops as scan_ops
 
-            if op == VALUE:
-                return lambda h, ls, rs: scan_ops.rmq_value_batch_pallas(
-                    h, ls, rs, interpret=self.interpret
-                )
-            return lambda h, ls, rs: scan_ops.rmq_index_batch_pallas(
-                h, ls, rs, interpret=self.interpret
-            )
+            return functools.partial(
+                scan_ops.rmq_value_batch_pallas if op == VALUE
+                else scan_ops.rmq_index_batch_pallas,
+                interpret=self.interpret)
         from repro.core.query import rmq_index_batch, rmq_value_batch
 
         return rmq_value_batch if op == VALUE else rmq_index_batch
@@ -168,43 +194,96 @@ class FusedExecutor(_ExecutorBase):
 
     No class routing: the kernel decomposes each span internally
     (prefix-chunk scan + offset-table level lookups + suffix-chunk scan;
-    short spans resolve entirely on its level-0 path).  ``run`` serves
-    the engine's per-op path; :meth:`run_mixed` returns *both* output
-    planes from one launch, which is how a batch mixing value and index
-    ops avoids a second dispatch.
+    short spans resolve entirely on its level-0 path).  Op ``"mixed"``
+    returns *both* output planes from one launch, which is how a batch
+    mixing value and index ops avoids a second dispatch.
     """
-
-    def __init__(self, interpret: Optional[bool] = None):
-        super().__init__()
-        self.interpret = interpret
 
     def _make(self, h: Hierarchy, op: str) -> Callable:
         from repro.kernels.rmq_fused import ops as fused_ops
 
         if op == MIXED:
             # one launch, both planes (positions imply track_pos)
-            return lambda h, ls, rs: fused_ops.rmq_fused_batch(
-                h, ls, rs, track_pos=True, interpret=self.interpret
-            )
-        if op == VALUE:
-            return lambda h, ls, rs: fused_ops.rmq_fused_value_batch(
-                h, ls, rs, interpret=self.interpret
-            )
-        return lambda h, ls, rs: fused_ops.rmq_fused_index_batch(
-            h, ls, rs, interpret=self.interpret
-        )
-
-    def run_mixed(self, h: Hierarchy, ls, rs):
-        """``(values, positions)`` for the whole bucket, one launch."""
-        self.calls += 1
-        self.queries += int(ls.shape[0])
-        fn = self._bind(MIXED, int(ls.shape[0]),
-                        lambda: self._make(h, MIXED))
-        return fn(h, ls, rs)
+            return functools.partial(fused_ops.rmq_fused_batch,
+                                     track_pos=True,
+                                     interpret=self.interpret)
+        return functools.partial(
+            fused_ops.rmq_fused_value_batch if op == VALUE
+            else fused_ops.rmq_fused_index_batch,
+            interpret=self.interpret)
 
 
-def _next_pow2(x: int) -> int:
-    return 1 << max(int(x) - 1, 0).bit_length()
+class RoutedExecutor:
+    """The engine's miss executor over one hierarchy: its ``planner``
+    (set at each attach) packs a deduped miss batch into span-class
+    buckets, each run on its class's executor and waited for before the
+    next launches.  ``lanes``: the engine's padding metrics, if any."""
+
+    def __init__(self, backend: str, interpret: Optional[bool] = None):
+        self.interpret = interpret
+        self.planner = None
+        self.lanes = None
+        self.class_counts = {SHORT: 0, MID: 0, LONG: 0, FUSED: 0}
+        self.configure(backend)
+
+    def configure(self, backend: str) -> None:
+        """(Re)build the per-class table for ``backend``, dropping the
+        old backend's compiled callables."""
+        self.executors = {
+            SHORT: ShortSpanExecutor(backend, self.interpret),
+            MID: MidSpanExecutor(backend, self.interpret),
+            LONG: LongSpanExecutor(),
+        }
+        if backend == "fused":
+            # the whole span mix in one launch per bucket: the planner
+            # emits FUSED buckets only, so the trio above never runs
+            self.executors[FUSED] = FusedExecutor(interpret=self.interpret)
+
+    def run(self, index, ls, rs, op: str) -> np.ndarray:
+        """Answers of ``(ls, rs)`` for ``op``, in order."""
+        return self._run(index, ls, rs, op)[0]
+
+    def run_mixed(self, index, ls, rs):
+        """``(values, positions)`` of ``(ls, rs)``, both planes from one
+        fused launch per bucket."""
+        return tuple(self._run(index, ls, rs, MIXED))
+
+    def _run(self, index, ls, rs, op):
+        h = index.hierarchy
+        ops = (VALUE, INDEX) if op == MIXED else (op,)
+        outs = [np.empty(ls.shape, out_dtype(index, o)) for o in ops]
+        tr = trace.current()
+        sp = tr.begin("plan") if tr is not None else None
+        buckets = self.planner.plan(ls, rs)
+        if tr is not None:
+            tr.end(sp, misses=int(ls.shape[0]), buckets=len(buckets), op=op)
+        for b in buckets:
+            self.class_counts[b.cls] += b.count
+            if self.lanes is not None:
+                waste, padded, live = self.lanes
+                waste.record(b.padding)
+                padded.inc(b.padding)
+                live.inc(b.count)
+
+        def launch(b):
+            return self.executors[b.cls].run(
+                h, jnp.asarray(b.ls), jnp.asarray(b.rs), op)
+
+        def fetch(b, res):
+            for out, plane in zip(outs, res if op == MIXED else [res]):
+                out[b.idxs] = np.asarray(plane)[: b.count]
+
+        dispatch(buckets, launch, fetch,
+                 lambda b: dict(cls=b.cls, count=b.count, shape=b.shape,
+                                op=op,
+                                **self.executors[b.cls].span_args(h)))
+        return outs
+
+    def stats(self) -> dict:
+        return {cls: ex.stats() for cls, ex in self.executors.items()}
+
+    def invalidate(self) -> None:
+        self.executors[LONG].invalidate()
 
 
 class BulkExecutor(_ExecutorBase):
@@ -221,8 +300,9 @@ class BulkExecutor(_ExecutorBase):
     CI-gated contract.
 
     No dedup and no LRU interplay here — at the 10^6+ batch sizes where
-    bulk beats fused, per-query caching is pure overhead; the engine's
-    ``query_bulk`` routes small batches to the fused path instead.
+    the bulk pass wins, per-query caching is pure overhead; the engine's
+    ``query_bulk`` sends smaller batches down its routed path instead
+    (dedup, the LRU and the bound miss executor).
 
     ``max_bucket`` is deliberately large (default 2^20): the jnp
     lowering rebuilds the shared chunk ladder per dispatch, so bigger
@@ -236,35 +316,31 @@ class BulkExecutor(_ExecutorBase):
         max_bucket: int = 1 << 20,
         min_bucket: int = 16,
     ):
-        super().__init__()
+        super().__init__(interpret=interpret)
         if max_bucket < min_bucket or min_bucket < 1:
             raise ValueError(
                 f"need max_bucket >= min_bucket >= 1, got "
                 f"{max_bucket}, {min_bucket}"
             )
-        self.interpret = interpret
         self.max_bucket = int(max_bucket)
         self.min_bucket = int(min_bucket)
 
     def _make(self, h: Hierarchy, op: str) -> Callable:
         from repro.kernels.rmq_bulk import ops as bulk_ops
 
-        if op == VALUE:
-            return lambda h, ls, rs: bulk_ops.rmq_bulk_value_batch(
-                h, ls, rs, interpret=self.interpret
-            )
-        return lambda h, ls, rs: bulk_ops.rmq_bulk_index_batch(
-            h, ls, rs, interpret=self.interpret
-        )
+        return functools.partial(
+            bulk_ops.rmq_bulk_value_batch if op == VALUE
+            else bulk_ops.rmq_bulk_index_batch,
+            interpret=self.interpret)
 
     def run(self, h: Hierarchy, ls, rs, op: str) -> np.ndarray:
         """Answer the whole batch; returns results in submission order."""
         ls = np.asarray(ls, np.int32).ravel()
         rs = np.asarray(rs, np.int32).ravel()
         m = ls.shape[0]
-        out_dtype = np.int32 if op == INDEX else np.dtype(h.base.dtype)
+        dtype = np.int32 if op == INDEX else np.dtype(h.base.dtype)
         if m == 0:
-            return np.zeros((0,), out_dtype)
+            return np.zeros((0,), dtype)
         c = h.plan.c
         self.queries += m
 
@@ -273,37 +349,37 @@ class BulkExecutor(_ExecutorBase):
         # last lexsort key is primary: chunk(l) major, chunk(r) minor
         order = np.lexsort((rs // c, ls // c))
         sls, srs = ls[order], rs[order]
-        n_buckets = -(-m // self.max_bucket)
+        # (start, live count, padded shape) of each bucket
+        starts = range(0, m, self.max_bucket)
+        counts = [min(self.max_bucket, m - start) for start in starts]
+        jobs = [(start, cnt, max(_next_pow2(cnt), self.min_bucket))
+                for start, cnt in zip(starts, counts)]
         if tr is not None:
-            tr.end(sp, queries=m, buckets=n_buckets, op=op,
+            tr.end(sp, queries=m, buckets=len(jobs), op=op,
                    strategy="bulk")
 
-        sorted_res = np.empty((m,), out_dtype)
-        for start in range(0, m, self.max_bucket):
-            stop = min(start + self.max_bucket, m)
-            count = stop - start
-            k = max(_next_pow2(count), self.min_bucket)
+        def launch(job):
+            start, count, k = job
             bl = np.zeros((k,), np.int32)
             br = np.zeros((k,), np.int32)
-            bl[:count] = sls[start:stop]
-            br[:count] = srs[start:stop]
+            bl[:count] = sls[start:start + count]
+            br[:count] = srs[start:start + count]
             self.calls += 1
             fn = self._bind(op, k, lambda: self._make(h, op))
-            sp = tr.begin("execute") if tr is not None else None
-            sub = tr.begin("launch") if tr is not None else None
-            res = fn(h, jnp.asarray(bl), jnp.asarray(br))
-            if tr is not None:
-                tr.end(sub)
-                sub = tr.begin("fetch")
-            sorted_res[start:stop] = np.asarray(res)[:count].astype(
-                out_dtype, copy=False
-            )
-            if tr is not None:
-                tr.end(sub)
-                tr.end(sp, cls="bulk", count=count, shape=k, op=op)
+            return fn(h, jnp.asarray(bl), jnp.asarray(br))
+
+        sorted_res = np.empty((m,), dtype)
+
+        def fetch(job, res):
+            start, count, _ = job
+            sorted_res[start:start + count] = np.asarray(res)[:count]
+
+        dispatch(jobs, launch, fetch,
+                 lambda job: dict(cls="bulk", count=job[1], shape=job[2],
+                                  op=op))
 
         sp = tr.begin("scatter") if tr is not None else None
-        out = np.empty((m,), out_dtype)
+        out = np.empty((m,), dtype)
         out[order] = sorted_res
         if tr is not None:
             tr.end(sp, queries=m, unique=m, op=op)

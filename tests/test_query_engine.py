@@ -203,6 +203,27 @@ class TestEngineParity:
         with pytest.raises(ValueError, match="without positions"):
             rmq.engine().query_index(np.array([0]), np.array([10]))
 
+    @pytest.mark.parametrize("crossover", [1 << 20, 1])
+    @pytest.mark.parametrize("case", ["float", "shape", "range"])
+    def test_query_bulk_rejects_bad_bounds(self, case, crossover,
+                                           monkeypatch):
+        """query_bulk checks its bounds on the host before routing."""
+        _, _, rmq = _build(1000, 16, 4)
+        engine = rmq.engine(bulk_crossover=crossover)
+        ls = np.array([0, 5, 17], np.int32)
+        rs = np.array([9, 700, 999], np.int32)
+        if case == "float":
+            with pytest.raises(TypeError, match="integers"):
+                engine.query_bulk(ls, rs.astype(np.float32))
+        elif case == "shape":
+            with pytest.raises(ValueError, match="shape"):
+                engine.query_bulk(ls, rs[:2])
+        else:
+            monkeypatch.setenv("REPRO_RMQ_DEBUG", "1")
+            with pytest.raises(ValueError, match="violates"):
+                engine.query_bulk(ls, rs + 1, "index")
+        assert engine.batches == 0
+
     def test_empty_batch(self):
         _, _, rmq = _build(1000, 16, 4)
         out = rmq.engine().query(np.zeros((0,), np.int32),
@@ -418,26 +439,43 @@ class TestPackedKeys:
         want = oracle(rmq.hierarchy, jnp.asarray(ls), jnp.asarray(rs))
         np.testing.assert_array_equal(_bits(executed), _bits(want))
 
-    def test_query_mixed_cache_hits_bit_identical(self):
+    @pytest.mark.parametrize("backend", ["fused", "jax"])
+    def test_query_mixed_cache_hits_bit_identical(self, backend):
+        """A mixed batch is deduped once and its entries are the ones
+        ``query`` / ``query_index`` read: after it, both are all hits."""
         rng = np.random.default_rng(13)
         n = 3000
         x = _signed_zero_values(rng, n)
-        rmq = RMQ.build(x, c=8, t=8, with_positions=True, backend="fused")
+        rmq = RMQ.build(x, c=8, t=8, with_positions=True, backend=backend)
         engine = rmq.engine()
-        assert engine.supports_mixed
+        assert engine.supports_mixed == (backend == "fused")
         ls, rs = _mixed_queries(rng, n, 8, 300)
         is_index = rng.random(ls.shape[0]) < 0.5
-        v1, p1 = engine.query_mixed(ls, rs, is_index)
+        # pairs asked for both ops in one batch, and repeated
+        ls[:40], rs[:40] = ls[40:80], rs[40:80]
+        is_index[:40] = ~is_index[40:80]
         keys = pack_keys(ls, rs)
+        b0 = engine.batches
+        v1, p1 = engine.query_mixed(ls, rs, is_index)
+        assert engine.batches == b0 + 1
+        assert engine.dedup_saved == ls.shape[0] - np.unique(keys).shape[0]
         needed = (np.unique(keys[~is_index]).shape[0]
                   + np.unique(keys[is_index]).shape[0])
         h0, m0 = engine.cache.hits, engine.cache.misses
+        assert m0 == needed
         v2, p2 = engine.query_mixed(ls, rs, is_index)
         assert engine.cache.hits - h0 == needed
         assert engine.cache.misses == m0
         np.testing.assert_array_equal(_bits(v2[~is_index]),
                                       _bits(v1[~is_index]))
         np.testing.assert_array_equal(p2[is_index], p1[is_index])
+        h0 = engine.cache.hits
+        v3 = np.asarray(engine.query(ls[~is_index], rs[~is_index]))
+        p3 = np.asarray(engine.query_index(ls[is_index], rs[is_index]))
+        assert engine.cache.hits - h0 == needed
+        assert engine.cache.misses == m0
+        np.testing.assert_array_equal(_bits(v3), _bits(v1[~is_index]))
+        np.testing.assert_array_equal(p3, p1[is_index])
         lsj, rsj = jnp.asarray(ls), jnp.asarray(rs)
         np.testing.assert_array_equal(
             v1[~is_index],

@@ -90,29 +90,45 @@ def _reference_order(ls, rs):
     return l[order], r[order]
 
 
-@pytest.mark.parametrize("op", ["value", "index"])
+@pytest.mark.parametrize("op", ["value", "index", "mixed"])
 def test_wide_engine_keys_match_ordered_dict_lru(op):
     """Dedup and the exact LRU over coordinates in [2^31, 2^33): answers,
-    hits, misses, evictions and size equal the scalar reference's."""
+    hits, misses, evictions and size equal the scalar reference's.  A
+    mixed batch looks up its value entries, then its index entries."""
     rng = np.random.default_rng(3)
     with jax.enable_x64(True):
         stub = WideStub()
         eng = QueryEngine(stub, cache_size=48)
         ref = RefLRU(48)
-        run = eng.query if op == "value" else eng.query_index
-        want_fn = _value if op == "value" else _position
         pool_l, pool_r = _wide_queries(rng, 90)
         for _ in range(12):
             pick = rng.integers(0, pool_l.shape[0], 40)   # repeats inside
             ls, rs = pool_l[pick], pool_r[pick]
-            got = np.asarray(run(ls, rs))
-            np.testing.assert_array_equal(got, want_fn(ls, rs))
-            assert got.dtype == (np.float32 if op == "value" else np.int64)
+            if op == "mixed":
+                flags = rng.random(40) < 0.5
+                vals, poss = eng.query_mixed(ls, rs, flags)
+                np.testing.assert_array_equal(vals[~flags],
+                                              _value(ls, rs)[~flags])
+                np.testing.assert_array_equal(poss[flags],
+                                              _position(ls, rs)[flags])
+                assert (vals.dtype, poss.dtype) == (np.float32, np.int64)
+            else:
+                run = eng.query if op == "value" else eng.query_index
+                want_fn = _value if op == "value" else _position
+                got = np.asarray(run(ls, rs))
+                np.testing.assert_array_equal(got, want_fn(ls, rs))
+                assert got.dtype == (np.float32 if op == "value"
+                                     else np.int64)
+                flags = np.full(40, op == "index")
             ul, ur = _reference_order(ls, rs)
-            missed = [(l, r) for l, r in zip(ul.tolist(), ur.tolist())
-                      if ref.get(op, 0, l, r) is None]
-            for l, r in missed:
-                ref.put(op, 0, l, r, 0)
+            asked = set(zip(ls.tolist(), rs.tolist(), flags.tolist()))
+            entries = [(o, l, r) for o, f in (("value", False),
+                                              ("index", True))
+                       for l, r in zip(ul.tolist(), ur.tolist())
+                       if (l, r, f) in asked]
+            missed = [e for e in entries if ref.get(e[0], 0, *e[1:]) is None]
+            for o, l, r in missed:
+                ref.put(o, 0, l, r, 0)
             c = eng.cache
             assert (c.hits, c.misses, c.evictions, len(c)) == (
                 ref.hits, ref.misses, ref.evictions, len(ref))
@@ -227,6 +243,50 @@ for src in (sharded, x):
         == [150, 1150]
 print("SUBPROCESS_OK")
 """
+
+
+_MIXED_PROG = r"""
+import numpy as np, jax
+jax.config.update("jax_enable_x64", True)
+from repro.core.distributed import DistributedRMQ
+
+mesh = jax.make_mesh((1, 4), ("data", "model"))
+rng = np.random.default_rng(12)
+n = 4000                                     # 4 segments of 1000
+x = rng.integers(0, 40, n).astype(np.float32)
+x[[150, 1150, 2150, 3150]] = -5.0
+d = DistributedRMQ.build(x, mesh, c=16, t=4, with_positions=True)
+ls = rng.integers(0, n, 500)
+rs = np.minimum(ls + rng.integers(0, 1500, 500), n - 1)    # both classes
+ls, rs = np.concatenate([ls, ls[:60]]), np.concatenate([rs, rs[:60]])
+flags = rng.random(ls.shape[0]) < 0.5
+flags[500:] = ~flags[:60]                    # pairs asked for both ops
+want_v = np.asarray(d.query(ls, rs))
+want_p = np.asarray(d.query_index(ls, rs))
+eng = d.engine(cache_size=4096)
+assert not eng.supports_mixed
+for _ in range(2):                           # executed, then cached
+    v, p = eng.query_mixed(ls, rs, flags)
+    assert v.view(np.uint32)[~flags].tolist() \
+        == want_v.view(np.uint32)[~flags].tolist()
+    np.testing.assert_array_equal(p[flags], want_p[flags])
+keys = ls * n + rs
+needed = np.unique(keys[~flags]).size + np.unique(keys[flags]).size
+c = eng.cache
+assert (c.hits, c.misses) == (needed, needed), (c.hits, c.misses, needed)
+assert eng.stats()["batches"] == 2
+assert eng.stats()["dedup_saved"] == 2 * (ls.size - np.unique(keys).size)
+cc = eng.stats()["class_counts"]
+assert cc["seg_local"] > 0 and cc["crossing"] > 0, cc
+print("SUBPROCESS_OK")
+"""
+
+
+def test_sharded_query_mixed_under_x64_matches_the_index():
+    """query_mixed on the fake four-device mesh under x64: one dedup,
+    the router run once per op, answers bit-identical to
+    ``DistributedRMQ.query`` / ``query_index``, cached after one pass."""
+    _run(_MIXED_PROG)
 
 
 def test_sharded_engine_under_x64_matches_reference():
