@@ -144,7 +144,8 @@ class DistributedExecutor:
             cnt = min(shape, k - lo)
             res[lo : lo + cnt] = np.asarray(r)[:cnt]
 
-        dispatch(range(0, k, shape), launch, fetch, cls=CROSSING)
+        dispatch(range(0, k, shape), launch, fetch,
+                 lambda lo: {"cls": CROSSING})
         return res
 
     # -- contained spans: grouped per owner, answered without collectives -
@@ -184,7 +185,7 @@ class DistributedExecutor:
             picked[sel] = np.asarray(r)[so[sel], rp]
 
         dispatch(range(0, int(counts.max()), self.max_bucket), launch,
-                 fetch, cls=SEG_LOCAL)
+                 fetch, lambda lo: {"cls": SEG_LOCAL})
         res = np.empty((ls.shape[0],), dtype)
         res[order] = picked
         return res

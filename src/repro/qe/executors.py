@@ -19,7 +19,9 @@ entire bucket — every span class, and (with op ``"mixed"``) value and
 index ops in the same launch.
 
 :class:`RoutedExecutor` answers the engine's misses over one hierarchy.
-Every bucket loop of the package runs through :func:`dispatch`.
+Every bucket loop of the package runs through :func:`dispatch`, which
+launches every bucket of a batch before it fetches the first: the host's
+launches overlap the device's work instead of alternating with it.
 """
 
 from __future__ import annotations
@@ -57,29 +59,34 @@ def out_dtype(index, op: str) -> np.dtype:
     return np.dtype(index.value_dtype)
 
 
-def dispatch(jobs, launch, fetch, execute=None, **args) -> None:
-    """The one bucket loop: ``launch(job)`` starts a job on the device,
-    ``fetch(job, result)`` waits for it and copies it back, each in a
-    ``launch`` / ``fetch`` span holding ``args``.  With ``execute`` (a
-    job's ``execute`` span args) each job runs in its own ``execute``
-    span and is fetched before the next launches; without it all jobs
-    launch before the first fetch, in the caller's ``execute`` span."""
+def dispatch(jobs, launch, fetch, args) -> None:
+    """The one bucket loop: ``launch(job)`` starts each job on the
+    device, all of them before ``fetch(job, result)`` waits for the first
+    and copies it back.  Each call runs in a ``launch`` / ``fetch`` span
+    holding ``args(job)``; the caller holds the ``execute`` span."""
     tr = trace.current()
-    for group in ([job] for job in jobs) if execute else [jobs]:
-        sp = tr.begin("execute") if tr is not None and execute else None
-        pending = []
-        for job in group:
-            sub = tr.begin("launch") if tr is not None else None
-            pending.append(launch(job))
-            if tr is not None:
-                tr.end(sub, **args)
-        for job, res in zip(group, pending):
-            sub = tr.begin("fetch") if tr is not None else None
-            fetch(job, res)
-            if tr is not None:
-                tr.end(sub, **args)
-        if sp is not None:
-            tr.end(sp, **execute(group[0]))
+    jobs = list(jobs)
+    pending, spans = [], []
+    for job in jobs:
+        sp = tr.begin("launch") if tr is not None else None
+        pending.append(launch(job))
+        if tr is not None:
+            spans.append(args(job))
+            tr.end(sp, **spans[-1])
+    for i, (job, res) in enumerate(zip(jobs, pending)):
+        sp = tr.begin("fetch") if tr is not None else None
+        fetch(job, res)
+        if tr is not None:
+            tr.end(sp, **spans[i])
+
+
+def _copy_back(res):
+    """Start the device-to-host copy of each of ``res``'s planes (an
+    array or a tuple of them), so a later ``np.asarray`` finds the bytes
+    on the host; returns ``res``."""
+    for plane in res if isinstance(res, tuple) else (res,):
+        plane.copy_to_host_async()
+    return res
 
 
 class _ExecutorBase:
@@ -118,7 +125,7 @@ class _ExecutorBase:
         """Drop state tied to a particular index version (default: none)."""
 
     def span_args(self, h: Hierarchy) -> dict:
-        """Args this executor adds to a bucket's ``execute`` span."""
+        """Args this executor adds to a bucket's ``launch`` span."""
         return {}
 
 
@@ -216,8 +223,12 @@ class FusedExecutor(_ExecutorBase):
 class RoutedExecutor:
     """The engine's miss executor over one hierarchy: its ``planner``
     (set at each attach) packs a deduped miss batch into span-class
-    buckets, each run on its class's executor and waited for before the
-    next launches.  ``lanes``: the engine's padding metrics, if any."""
+    buckets, each run on its class's executor.  Every bucket launches,
+    and starts the copy of its answers back, before the first is
+    fetched; so the whole batch is in flight on the device at once: at
+    most 12 B a padded query for a value or index op (bounds in, answer
+    out), 16 B for a mixed one (12.6 MB at 2^20 value queries).
+    ``lanes``: the engine's padding metrics, if any."""
 
     def __init__(self, backend: str, interpret: Optional[bool] = None):
         self.interpret = interpret
@@ -266,17 +277,20 @@ class RoutedExecutor:
                 live.inc(b.count)
 
         def launch(b):
-            return self.executors[b.cls].run(
-                h, jnp.asarray(b.ls), jnp.asarray(b.rs), op)
+            return _copy_back(self.executors[b.cls].run(
+                h, jnp.asarray(b.ls), jnp.asarray(b.rs), op))
 
         def fetch(b, res):
             for out, plane in zip(outs, res if op == MIXED else [res]):
                 out[b.idxs] = np.asarray(plane)[: b.count]
 
+        sp = tr.begin("execute") if tr is not None else None
         dispatch(buckets, launch, fetch,
                  lambda b: dict(cls=b.cls, count=b.count, shape=b.shape,
                                 op=op,
                                 **self.executors[b.cls].span_args(h)))
+        if tr is not None:
+            tr.end(sp, buckets=len(buckets), count=int(ls.shape[0]), op=op)
         return outs
 
     def stats(self) -> dict:
@@ -297,7 +311,9 @@ class BulkExecutor(_ExecutorBase):
     traces — come from a tiny set), each bucket answered by a single
     level-0-coalesced dispatch, and the results inverse-permuted back to
     submission order.  One ``rmq_bulk`` launch per bucket is the
-    CI-gated contract.
+    CI-gated contract.  As in :class:`RoutedExecutor`, every bucket
+    launches and starts its copy back before the first is fetched (12 B
+    a padded query in flight).
 
     No dedup and no LRU interplay here — at the 10^6+ batch sizes where
     the bulk pass wins, per-query caching is pure overhead; the engine's
@@ -366,7 +382,7 @@ class BulkExecutor(_ExecutorBase):
             br[:count] = srs[start:start + count]
             self.calls += 1
             fn = self._bind(op, k, lambda: self._make(h, op))
-            return fn(h, jnp.asarray(bl), jnp.asarray(br))
+            return _copy_back(fn(h, jnp.asarray(bl), jnp.asarray(br)))
 
         sorted_res = np.empty((m,), dtype)
 
@@ -374,9 +390,12 @@ class BulkExecutor(_ExecutorBase):
             start, count, _ = job
             sorted_res[start:start + count] = np.asarray(res)[:count]
 
+        sp = tr.begin("execute") if tr is not None else None
         dispatch(jobs, launch, fetch,
                  lambda job: dict(cls="bulk", count=job[1], shape=job[2],
                                   op=op))
+        if tr is not None:
+            tr.end(sp, buckets=len(jobs), count=m, op=op)
 
         sp = tr.begin("scatter") if tr is not None else None
         out = np.empty((m,), dtype)

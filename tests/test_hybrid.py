@@ -105,7 +105,7 @@ def test_hybrid_index_tracking_matches_naive(n, c, t, packed_pos):
 @pytest.mark.parametrize("n,c,t", LOWERINGS)
 def test_hybrid_walk_reads_chunk_rows(n, c, t):
     """Each level's windows are one row of its (rows, c) view where the
-    view is free or cheap; the long ``execute`` span counts those levels."""
+    view is free or cheap; the long ``launch`` span counts those levels."""
     from repro.obs.trace import Tracer, use_tracer
 
     x = np.random.default_rng(1).random(n).astype(np.float32)
@@ -135,7 +135,7 @@ def test_hybrid_walk_reads_chunk_rows(n, c, t):
     with use_tracer(tr):
         engine.query(ls, rs)
     (long_span,) = [s for s in tr.spans()
-                    if s.name == "execute" and s.args["cls"] == "long"]
+                    if s.name == "launch" and s.args["cls"] == "long"]
     assert long_span.args["row_levels"] == rows
 
 

@@ -437,11 +437,15 @@ class TestProgramSpans:
         assert root.name == "query_bulk"
         assert root.args == {"queries": 350, "route": "routed"}
         names = [s.name for s in kids[root.span_id]]
-        assert names[:3] == ["dedup", "cache_get", "plan"]
-        assert names[-2:] == ["cache_put", "scatter"]
-        assert set(names[3:-2]) == {"execute"}
-        for ex in kids[root.span_id][3:-2]:
-            assert [c.name for c in kids[ex.span_id]] == ["launch", "fetch"]
+        assert names == ["dedup", "cache_get", "plan", "execute",
+                         "cache_put", "scatter"]
+        plan, ex = kids[root.span_id][2:4]
+        k = plan.args["buckets"]
+        assert plan.args == {"misses": misses, "buckets": k, "op": "value"}
+        assert k >= 1
+        assert ex.args == {"buckets": k, "count": misses, "op": "value"}
+        assert [c.name for c in kids[ex.span_id]] == (
+            ["launch"] * k + ["fetch"] * k)
         dedup, get = kids[root.span_id][:2]
         unique = np.unique(np.stack([ls, rs]), axis=1).shape[1]
         assert dedup.args == {"queries": 350, "unique": unique}

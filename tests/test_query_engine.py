@@ -15,7 +15,8 @@ from _hypothesis_compat import given, settings, st
 
 from repro.core.api import RMQ
 from repro.core.query import rmq_index_batch, rmq_value_batch
-from repro.qe import LONG, MID, SHORT, QueryEngine, QueryPlanner, QueryService
+from repro.qe import (FUSED, LONG, MID, SHORT, QueryEngine, QueryPlanner,
+                     QueryService)
 from repro.qe.cache import ResultCache, pack_keys, unpack_keys
 
 
@@ -483,6 +484,82 @@ class TestPackedKeys:
         np.testing.assert_array_equal(
             p1[is_index],
             np.asarray(rmq_index_batch(rmq.hierarchy, lsj, rsj))[is_index])
+
+
+# ---------------------------------------------------------------------------
+# the routed bucket loop: every launch before the first fetch
+# ---------------------------------------------------------------------------
+def _oracle(x, ls, rs):
+    """Plain numpy answers: ``(values, leftmost positions)``."""
+    pos = np.array([l + int(np.argmin(x[l : r + 1]))
+                    for l, r in zip(ls, rs)], np.int32)
+    return x[pos], pos
+
+
+class TestLaunchOrder:
+    def test_every_bucket_launches_before_the_first_fetch(self,
+                                                          monkeypatch):
+        from repro.obs.trace import Tracer, use_tracer
+        from repro.qe import executors
+
+        n = 100_000
+        rng, x, rmq = _build(n, 128, 4, seed=7)
+        engine = QueryEngine(rmq, cache_size=0, max_bucket=16)
+        ls, rs = _mixed_queries(rng, n, 128, 240)
+        engine.query(ls, rs)                      # compiles every shape
+        uls, urs = unpack_keys(np.unique(pack_keys(ls, rs)))
+        planned = engine.planner.plan(uls, urs)
+        assert {b.cls for b in planned} == {SHORT, MID, LONG}
+        assert len(planned) >= 3 * 4
+
+        tr = Tracer()
+        copies = []                 # fetch spans closed at each copy start
+        copy_back = executors._copy_back
+
+        def spy(res):
+            copies.append(sum(s.name == "fetch" for s in tr.spans()))
+            return copy_back(res)
+
+        monkeypatch.setattr(executors, "_copy_back", spy)
+        with use_tracer(tr):
+            engine.query(ls, rs)
+        spans = tr.spans()
+        (ex,) = [s for s in spans if s.name == "execute"]
+        (plan,) = [s for s in spans if s.name == "plan"]
+        kids = sorted((s for s in spans if s.parent_id == ex.span_id),
+                      key=lambda s: s.span_id)
+        k = len(planned)
+        assert [s.name for s in kids] == ["launch"] * k + ["fetch"] * k
+        assert kids[k - 1].end <= kids[k].start
+        assert copies == [0] * k
+        assert ex.args == {"buckets": k, "count": uls.shape[0],
+                           "op": "value"}
+        assert plan.args["buckets"] == k
+        rows = [s.args for s in kids[:k]]
+        assert [(a["cls"], a["count"], a["shape"]) for a in rows] == [
+            (b.cls, b.count, b.shape) for b in planned]
+        assert all(a["op"] == "value" for a in rows)
+        assert all(("row_levels" in a) == (a["cls"] == LONG) for a in rows)
+
+    @pytest.mark.parametrize("backend", ["jax", "pallas", "fused"])
+    def test_many_buckets_match_the_oracle(self, backend):
+        n = 20_000
+        rng, x, rmq = _build(n, 128, 4, seed=8)
+        engine = QueryEngine(rmq, backend=backend, interpret=True,
+                             cache_size=0, max_bucket=16)
+        ls, rs = _mixed_queries(rng, n, 128, 150)
+        want_v, want_p = _oracle(x, ls, rs)
+        np.testing.assert_array_equal(_bits(engine.query(ls, rs)),
+                                      _bits(want_v))
+        np.testing.assert_array_equal(
+            np.asarray(engine.query_index(ls, rs)), want_p)
+        is_index = rng.random(ls.shape[0]) < 0.5
+        v, p = engine.query_mixed(ls, rs, is_index)
+        np.testing.assert_array_equal(_bits(v[~is_index]),
+                                      _bits(want_v[~is_index]))
+        np.testing.assert_array_equal(p[is_index], want_p[is_index])
+        assert engine.stats()["executors"][
+            FUSED if backend == "fused" else MID]["calls"] > 3
 
 
 # ---------------------------------------------------------------------------
